@@ -30,23 +30,27 @@ import jax
 jax.config.update("jax_enable_x64", True)
 
 # Persistent XLA compilation cache: SQL plans compile to large monolithic
-# programs (tens of seconds for multi-join queries); caching the compiled
-# executables on disk makes repeat processes (test suite, bench driver)
-# pay the compile once per program. Opt out with PRESTO_TPU_XLA_CACHE="".
-_cache_dir = os.environ.get(
-    "PRESTO_TPU_XLA_CACHE",
-    os.path.join(os.path.dirname(__file__), os.pardir, ".xla_cache"))
-if _cache_dir:
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.abspath(_cache_dir))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    # pin the entry codec to zlib: the zstandard one-shot C compressor
-    # segfaults on the multi-hundred-MB serialized executables long
-    # pytest sessions produce (observed deterministically ~60 compiled
-    # programs in); zlib is slower but never crashes the process
-    from jax._src import compilation_cache as _jcc
-    _jcc.zstd = None
-    _jcc.zstandard = None
+# programs (minutes for multi-join queries on the TPU compiler); caching
+# the compiled executables on disk makes repeat processes pay the compile
+# once per program. Where JAX_COMPILATION_CACHE_DIR is set JAX reads it
+# itself and no directory is set in code; otherwise the cache lives at a
+# fixed path inside the checkout (the path is part of the cache key, so
+# it must not move). Switch it off with JAX's own
+# JAX_ENABLE_COMPILATION_CACHE=false / jax_enable_compilation_cache.
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update(
+        "jax_compilation_cache_dir",
+        os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                     os.pardir, ".xla_cache")))
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+# pin the entry codec to zlib: the zstandard one-shot C compressor
+# segfaulted on the multi-hundred-MB serialized executables long
+# sessions produce; zlib is slower but never crashed the process.
+# Private attributes, checked against jax 0.9.0: compress/decompress
+# consult `zstd`, then `zstandard`, then fall back to zlib.
+from jax._src import compilation_cache as _jcc  # noqa: E402
+_jcc.zstd = None
+_jcc.zstandard = None
 
 from presto_tpu.types import (  # noqa: E402
     BIGINT,

@@ -13,19 +13,26 @@ from __future__ import annotations
 import numpy as np
 
 from presto_tpu import types as T
-from presto_tpu.block import Table
+from presto_tpu.block import Table, column_from_numpy
 from presto_tpu.connectors.base import Connector, TableStats
 
 
 def _make_table(schema: dict, rows: list[tuple]) -> Table:
+    """Rows of Python values as a Table; a None in a non-string
+    column is SQL NULL."""
     cols = {}
     for i, (name, dtype) in enumerate(schema.items()):
         vals = [r[i] for r in rows]
         if isinstance(dtype, T.VarcharType):
-            cols[name] = np.array(vals, dtype=object)
-        else:
-            cols[name] = np.asarray(vals, dtype=dtype.physical_dtype)
-    return Table.from_numpy(schema, cols)
+            cols[name] = column_from_numpy(
+                dtype, np.array(vals, dtype=object))
+            continue
+        valid = np.array([v is not None for v in vals], dtype=bool)
+        data = np.asarray([0 if v is None else v for v in vals],
+                          dtype=dtype.physical_dtype)
+        cols[name] = column_from_numpy(
+            dtype, data, None if valid.all() else valid)
+    return Table(cols, len(rows))
 
 
 class _ReflectiveConnector(Connector):
@@ -155,7 +162,8 @@ class SystemConnector(_ReflectiveConnector):
             # device-cost attribution (obs/devprof.py): the program's
             # XLA cost_analysis/memory_analysis split across its plan
             # nodes, plus arithmetic intensity (flops/byte) and the
-            # roofline ratio against PRESTO_TPU_DEVICE_PEAK_FLOPS/_BW
+            # roofline ratio against the device's peaks (NULL when the
+            # device_kind is not in devprof.DEVICE_PEAKS)
             "flops": T.BIGINT, "hbm_bytes": T.BIGINT,
             "intensity": T.DOUBLE, "roofline": T.DOUBLE,
         },
@@ -313,6 +321,6 @@ class SystemConnector(_ReflectiveConnector):
              int(op.get("wallMillis") or 0),
              int(op.get("flops") or 0), int(op.get("hbmBytes") or 0),
              float(op.get("intensity") or 0.0),
-             float(op.get("roofline") or 0.0))
+             op.get("roofline"))
             for qid, stage, t in self._stage_tasks()
             for op in t["operators"]]

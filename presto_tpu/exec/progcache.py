@@ -252,11 +252,20 @@ PROGRAM_FORMAT = "cost1"
 
 
 @functools.lru_cache(maxsize=32)
+def mesh_key(mesh) -> tuple:
+    """What tells one mesh's programs from another's: shape, axis
+    names and the ids of its devices in mesh order (an executable is
+    bound to the devices it was compiled for, not to the first N)."""
+    return (tuple(mesh.devices.shape), tuple(mesh.axis_names),
+            tuple(int(d.id) for d in mesh.devices.flat))
+
+
+@functools.lru_cache(maxsize=32)
 def platform_fingerprint(mesh_shape: tuple | None = None) -> tuple:
     """What a serialized executable is only valid for: jax/jaxlib
     versions, backend kind, device kind and count, x64 mode, the
     engine's traced-program output format, and (for shard_map
-    programs) the mesh shape."""
+    programs) the mesh (:func:`mesh_key`)."""
     import jax
     import jaxlib
 
@@ -266,10 +275,10 @@ def platform_fingerprint(mesh_shape: tuple | None = None) -> tuple:
             jax.default_backend(), len(devs),
             getattr(devs[0], "device_kind", "?"),
             bool(jax.config.jax_enable_x64), PROGRAM_FORMAT,
-            # what kernel_backend=auto resolves to here: a persisted
-            # entry from a TPU process (pallas kernels inside) must
-            # not be loaded by a CPU process expecting XLA bodies
-            f"kernels-{K.default_backend()}",
+            # the kernels kernel_backend=auto runs as Pallas here: a
+            # persisted entry from a process whose auto set differed
+            # (other platform, other build) holds other kernel bodies
+            "kernels-" + (",".join(K.auto_pallas_here()) or "xla"),
             mesh_shape)
 
 
@@ -354,10 +363,13 @@ class ProgramCache:
 
     # -- lookups ------------------------------------------------------------
 
-    def lookup(self, key, fingerprint: tuple | None = None):
+    def lookup(self, key, fingerprint: tuple | None = None,
+               devices=None):
         """(compiled, meta) for ``key`` or None. Memory tier first,
         then the disk store (deserialized entries are promoted into
-        memory). Counts one hit (labeled by tier) or one miss."""
+        memory). Counts one hit (labeled by tier) or one miss.
+        ``devices`` are the ones a disk entry is loaded onto: the
+        mesh's for a shard_map program, None for the default device."""
         with self._lock:
             ent = self._entries.pop(key, None)
             if ent is not None:
@@ -365,7 +377,7 @@ class ProgramCache:
         if ent is not None:
             _HITS.inc(tier="memory")
             return ent[0], ent[1]
-        loaded = self._disk_load(key, fingerprint)
+        loaded = self._disk_load(key, fingerprint, devices)
         if loaded is not None:
             compiled, meta, nbytes = loaded
             self._remember(key, compiled, meta, nbytes)
@@ -437,7 +449,7 @@ class ProgramCache:
     def _path(self, digest: str, suffix: str) -> str:
         return os.path.join(self.disk_dir, digest + suffix)
 
-    def _disk_load(self, key, fingerprint):
+    def _disk_load(self, key, fingerprint, devices=None):
         """(compiled, meta, nbytes) deserialized from the store, or
         None on any failure (missing file, corrupt pickle, backend
         refusal) — the caller falls back to a live compile. A failing
@@ -456,9 +468,16 @@ class ProgramCache:
                 blob = pickle.load(f)
             if blob.get("key") != repr(key):
                 raise ValueError("digest collision / stale entry")
+            import jax
             from jax.experimental import serialize_executable as _se
+            # jax 0.9.0 loads onto EVERY device of the backend unless
+            # told otherwise, and a one-device program then "expects
+            # N shards": name the devices the caller runs on
+            if devices is None:
+                devices = jax.devices()[:1]
             compiled = _se.deserialize_and_load(
-                blob["payload"], blob["in_tree"], blob["out_tree"])
+                blob["payload"], blob["in_tree"], blob["out_tree"],
+                execution_devices=list(devices))
             _LOAD_SECONDS.observe(time.perf_counter() - t0)
             return compiled, blob["meta"], len(blob["payload"])
         except Exception:  # noqa: BLE001 - corrupt/incompatible entry

@@ -18,6 +18,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from presto_tpu import types as T
 from presto_tpu.expr import ir
@@ -354,7 +355,9 @@ def prepare_arg2(fn: str, data, arg2_type: T.DataType | None):
     return data
 
 
-_U64_MAX = jnp.uint64(0xFFFFFFFFFFFFFFFF)
+# NumPy scalar: a module-level jnp scalar would initialise the backend
+# at import (see ops/hash._EMPTY)
+_U64_MAX = np.uint64(0xFFFFFFFFFFFFFFFF)
 
 
 def _bitlen(x):

@@ -25,16 +25,20 @@ XLA path — registered beside it in :data:`KERNELS` (the
 ``kernel-parity`` lint rule keeps the table total). Selection is the
 ``kernel_backend`` session property:
 
-- ``auto`` (default): Pallas on TPU, XLA elsewhere;
-- ``pallas``: force the kernels; off-TPU they run under
+- ``auto`` (default): per kernel — Pallas on a TPU for the kernels
+  named in :data:`AUTO_PALLAS` (those that passed on the chip), the
+  XLA twin for every other kernel and on every other platform;
+- ``pallas``: force the kernels. On the CPU platform they run under
   ``pl.pallas_call(interpret=True)`` so the CPU test tier executes
-  the real kernel bodies;
+  the real kernel bodies; on any other platform they compile, and a
+  kernel the compiler refuses fails the query with the compiler's
+  message — there is no trace-time swap to XLA;
 - ``xla``: force the fallbacks.
 
-The resolved backend is installed as an ambient context for the
+The session's setting is installed as an ambient context for the
 duration of one plan trace (both interpreters wrap ``interp.run``),
 rides the program-cache key (``kernel_backend`` is in
-TRACE_RELEVANT_PROPERTIES and the resolved default rides the
+TRACE_RELEVANT_PROPERTIES and what ``auto`` selects here rides the
 platform fingerprint), and every dispatch is noted against the plan
 node being traced so ``system.operator_stats`` can name the kernel
 and split execute wall per operator.
@@ -77,33 +81,54 @@ KERNELS: dict[str, dict[str, object]] = {
 }
 
 
-def default_backend() -> str:
-    """What ``auto`` resolves to on this process' platform."""
+# Kernels ``auto`` runs as Pallas on a TPU. A kernel is listed here only
+# after it compiled under Mosaic on the chip (not interpreted), returned
+# its XLA twin's answer at the shapes TPC-H SF1 produces, and ran within
+# about 2x of the twin (chip_smoke.py's kernel leg prints the table).
+# First v5e run (PR 21, one chip, SF1, 6.0M rows, 6 segments; the table
+# is in CHANGES.md): agg_sum compiled and matched but took 133.6 ms
+# against the twin's 2.4 ms, agg_max/agg_min 141.6 ms against 1.4 ms —
+# a per-row loop on the scalar core loses to the MXU/broadcast bodies
+# by 55-100x; join_lookup, multijoin and compact do per-row scalar
+# read-modify-write on VMEM-resident tables, which Pallas refuses before
+# Mosaic sees them ("ValueError: Cannot store scalars to VMEM"). All six
+# need a vectorised redesign (ROADMAP S1(b)/D2), so the set is empty and
+# ``auto`` is the XLA bodies everywhere — what every chip record ran.
+AUTO_PALLAS: frozenset[str] = frozenset()
+
+
+def auto_backend(name: str) -> str:
+    """What ``auto`` resolves kernel ``name`` to on this process'
+    platform."""
     import jax
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    on_tpu = name in AUTO_PALLAS and jax.default_backend() == "tpu"
+    return "pallas" if on_tpu else "xla"
+
+
+def auto_pallas_here() -> list[str]:
+    """The kernels ``auto`` runs as Pallas on this process' platform,
+    sorted (the platform fingerprint and reports name this set)."""
+    return sorted(k for k in KERNELS if auto_backend(k) == "pallas")
 
 
 def interpret_mode() -> bool:
-    """Pallas kernels run interpreted off-TPU (forced ``pallas`` on a
-    CPU container is exactly how tier-1 exercises the kernel bodies)."""
+    """Pallas kernels run interpreted on the CPU platform only (forced
+    ``pallas`` on a CPU container is exactly how tier-1 exercises the
+    kernel bodies). On any other platform a Pallas call compiles, or
+    the query fails with the compiler's message."""
     import jax
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def resolve(session) -> str:
-    """Resolve the session's ``kernel_backend`` property to a concrete
-    backend for this trace."""
+    """The session's ``kernel_backend`` setting, normalised to one of
+    :data:`BACKENDS`. ``auto`` stays ``auto``: it resolves per kernel
+    at dispatch (:func:`backend_for`)."""
     try:
         value = str(session.get("kernel_backend") or "auto").lower()
     except Exception:  # noqa: BLE001 - sessionless callers get auto
         value = "auto"
-    if value == "auto":
-        return default_backend()
-    return value if value in ("pallas", "xla") else default_backend()
-
-
-def active_backend() -> str:
-    return _ACTIVE.get()
+    return value if value in BACKENDS else "auto"
 
 
 @contextlib.contextmanager
@@ -131,6 +156,13 @@ def collect():
         _USED.reset(tok)
 
 
+def backend_for(name: str) -> str:
+    """The concrete backend kernel ``name`` runs on under the active
+    trace's setting."""
+    backend = _ACTIVE.get()
+    return auto_backend(name) if backend == "auto" else backend
+
+
 def dispatch(name: str):
     """The active backend's implementation of kernel ``name``.
     Attribution is SELF-noted by the implementations (each function
@@ -138,9 +170,7 @@ def dispatch(name: str):
     pallas entry may still decline at its eligibility gate and run
     the XLA fallback, and a dispatch-time note would name a kernel
     that never ran."""
-    backend = _ACTIVE.get()
-    fns = KERNELS[name]
-    return fns.get(backend) or fns["xla"]
+    return KERNELS[name][backend_for(name)]
 
 
 def note(tag: str) -> None:

@@ -23,6 +23,11 @@ same property the hash-build kernel leans on. Appends past
 ``capacity`` drop; the caller computes the overflow flag from the
 live count (identical on both backends) and feeds the capacity retry
 ladder.
+
+Status on the chip (v5e, PR 21): the per-row ``dst[pos] = src[i]`` is
+a scalar store to a VMEM ref, which Pallas refuses ("Cannot store
+scalars to VMEM"); ``auto`` does not select this kernel
+(kernels/__init__.AUTO_PALLAS). It runs interpreted on the CPU only.
 """
 
 from __future__ import annotations

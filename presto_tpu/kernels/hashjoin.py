@@ -31,9 +31,13 @@ ladder rebuilds at a larger size (counted as
 ``presto_tpu_hash_probe_overflow_total``; the ladder's exhaustion
 raises ops/hash.HashChainOverflow) — never a silent wrong answer.
 
-On non-TPU backends the kernels run under ``interpret=True`` so the
+On the CPU platform the kernels run under ``interpret=True`` so the
 CPU test tier executes the real kernel bodies (the ``kernel_backend``
-session property's ``pallas`` setting forces exactly that).
+session property's ``pallas`` setting forces exactly that). On the
+TPU these bodies do not lower — the per-row table claims are scalar
+stores to VMEM refs, which Pallas refuses ("Cannot store scalars to
+VMEM", v5e, PR 21) — so ``auto`` never selects them and forcing
+``pallas`` there raises that error (kernels/__init__.AUTO_PALLAS).
 """
 
 from __future__ import annotations

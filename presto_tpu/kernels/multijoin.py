@@ -25,6 +25,10 @@ garbage their DEAD lanes carry — invisible to results.
 2-D LONG-decimal key, a key symbol that isn't a plain spine/build
 column): the caller then runs the XLA walk — dispatch-level parity
 is total either way.
+
+Status on the chip (v5e, PR 21): refused with hashjoin.build_table's
+"Cannot store scalars to VMEM"; ``auto`` does not select this kernel
+(kernels/__init__.AUTO_PALLAS). It runs interpreted on the CPU only.
 """
 
 from __future__ import annotations

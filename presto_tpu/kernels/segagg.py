@@ -3,12 +3,16 @@
 The XLA aggregation path pays for 64-bit scatters twice over: plain
 ``jax.ops.segment_sum`` costs ~500 ms per 6M-row call on v5e (emulated
 64-bit scatter-add), and the MXU workaround (ops/segred.py) pays 8
-one-hot matmuls per 256-row block. These kernels do what the hardware
-actually wants: accumulate per-segment partials in VMEM scratch while
-each HBM tile is resident, one pass, no scatter unit and no one-hot
-FLOPs. Totals live as two uint32 planes with explicit carry
-(kernels/u64.add64) — exact mod 2^64, i.e. bit-identical to the
-int64 scatter-add contract including wraparound.
+one-hot matmuls per 256-row block. These kernels accumulate
+per-segment partials on-chip while each HBM tile is resident, one
+pass, no scatter unit and no one-hot FLOPs. Totals live as two uint32
+planes with explicit carry (kernels/u64.add64) — exact mod 2^64, i.e.
+bit-identical to the int64 scatter-add contract including wraparound.
+
+The body is a per-row read-modify-write on the scalar core, so every
+ref it touches lives in SMEM: Mosaic has no scalar store to VMEM
+("Cannot store scalars to VMEM"). That compiles on the v5e; how it
+grades against the XLA bodies is in kernels/__init__.AUTO_PALLAS.
 
 Eligibility is integer-only on purpose: integer sums are
 order-independent mod 2^64 and min/max are order-independent always,
@@ -30,8 +34,15 @@ import jax.numpy as jnp
 
 from presto_tpu.kernels import u64
 
-TILE = 256
-# accumulator planes ([k] uint32 x 2) must stay VMEM-resident
+# 1-D 32-bit SMEM blocks must match XLA's T(1024) layout of the operand
+TILE = 1024
+# accumulator planes ([k] uint32 x 2) must stay SMEM-resident. The v5e
+# has 1.00 MB of SMEM: libtpu 0.0.34 compiles these kernels for it at
+# 1 << 16 segments (2 x 256 KB) and refuses 1 << 17 ("Ran out of memory
+# in memory space smem. Used 1.02M of 1.00M"; AOT against a v5e
+# topology). On the chip all three ran at the gate's 65,536 segments
+# over 1,048,576 rows and equalled their XLA twins (PR 21, one-off
+# probe, PERF.md section 5).
 PALLAS_MAX_SEGMENTS = 1 << 16
 
 # lint/kernels.py kernel-parity rule: *_pallas functions outside the
@@ -65,6 +76,39 @@ def _interpret_mode() -> bool:
     return K.interpret_mode()
 
 
+def _accumulate(kernel, planes, k: int):
+    """Run ``kernel(vh, vl, sid, acc_hi, acc_lo)`` over the row tiles
+    of ``planes`` (three [n] 32-bit arrays, n a multiple of TILE) with
+    the two [k] accumulator planes resident across the sequential
+    grid. Index maps and loop bounds are explicit int32: under
+    ``jax_enable_x64`` a Python int traces as i64, which Mosaic does
+    not legalise."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    ntiles = planes[0].shape[0] // TILE
+    return pl.pallas_call(
+        kernel,
+        grid=(ntiles,),
+        in_specs=[pl.BlockSpec((TILE,), lambda t: (t,),
+                               memory_space=pltpu.SMEM)] * 3,
+        out_specs=[pl.BlockSpec((k,), lambda t: (t * 0,),
+                                memory_space=pltpu.SMEM)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((k,), jnp.uint32)] * 2,
+        interpret=_interpret_mode(),
+    )(*planes)
+
+
+def _fill(refs, values, k: int) -> None:
+    """Scalar-loop fill of [k] SMEM planes (SMEM takes no vector
+    store)."""
+    def one(i, _):
+        for ref, v in zip(refs, values):
+            ref[i] = jnp.uint32(v)
+        return 0
+
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(k), one, 0)
+
+
 def segment_sum_pallas(data, segment_ids, num_segments: int, **_kw):
     """Per-segment wrapping 64-bit sum of an integer column (bool
     counts as int64, matching jax.ops/segred). Out-of-range segment
@@ -86,8 +130,7 @@ def segment_sum_pallas(data, segment_ids, num_segments: int, **_kw):
 
         @pl.when(t == 0)
         def _init():
-            ah_ref[...] = jnp.zeros((k,), jnp.uint32)
-            al_ref[...] = jnp.zeros((k,), jnp.uint32)
+            _fill((ah_ref, al_ref), (0, 0), k)
 
         def row(i, _):
             s = sid_ref[i]
@@ -101,17 +144,9 @@ def segment_sum_pallas(data, segment_ids, num_segments: int, **_kw):
 
             return 0
 
-        jax.lax.fori_loop(0, TILE, row, 0)
+        jax.lax.fori_loop(jnp.int32(0), jnp.int32(TILE), row, 0)
 
-    ntiles = v_hi.shape[0] // TILE
-    ah, al = pl.pallas_call(
-        kernel,
-        grid=(ntiles,),
-        in_specs=[pl.BlockSpec((TILE,), lambda t: (t,))] * 3,
-        out_specs=[pl.BlockSpec((k,), lambda t: (0,))] * 2,
-        out_shape=[jax.ShapeDtypeStruct((k,), jnp.uint32)] * 2,
-        interpret=_interpret_mode(),
-    )(v_hi, v_lo, sid)
+    ah, al = _accumulate(kernel, (v_hi, v_lo, sid), k)
     return u64.join(ah, al).astype(out_dtype)
 
 
@@ -146,8 +181,7 @@ def _cmp_pallas(data, segment_ids, num_segments: int, is_max: bool):
 
         @pl.when(t == 0)
         def _init():
-            ah_ref[...] = jnp.full((k,), id_hi, jnp.uint32)
-            al_ref[...] = jnp.full((k,), id_lo, jnp.uint32)
+            _fill((ah_ref, al_ref), (id_hi, id_lo), k)
 
         def row(i, _):
             s = sid_ref[i]
@@ -171,17 +205,9 @@ def _cmp_pallas(data, segment_ids, num_segments: int, is_max: bool):
 
             return 0
 
-        jax.lax.fori_loop(0, TILE, row, 0)
+        jax.lax.fori_loop(jnp.int32(0), jnp.int32(TILE), row, 0)
 
-    ntiles = v_hi.shape[0] // TILE
-    ah, al = pl.pallas_call(
-        kernel,
-        grid=(ntiles,),
-        in_specs=[pl.BlockSpec((TILE,), lambda t: (t,))] * 3,
-        out_specs=[pl.BlockSpec((k,), lambda t: (0,))] * 2,
-        out_shape=[jax.ShapeDtypeStruct((k,), jnp.uint32)] * 2,
-        interpret=_interpret_mode(),
-    )(v_hi, v_lo, sid)
+    ah, al = _accumulate(kernel, (v_hi, v_lo, sid), k)
     packed = u64.join(ah, al)
     if signed:
         packed = packed.astype(jnp.int64)

@@ -60,7 +60,7 @@ PlanSanityChecker pipeline, sql/planner/sanity/PlanSanityChecker.java):
   ``block_until_ready``, ``int()`` of a device scalar) must go through
   the counted ``exec/hostsync`` boundary or carry a justified
   ``DEVICE_SYNC_EXEMPT`` entry — one stray sync in a stage walk
-  serializes every dispatch behind a ~90ms round-trip.
+  serializes every dispatch behind a device round-trip.
 - **retrace hazards** (``lint/retrace.py``): data-dependent integers
   (``bincount().max()``, ``fetch_int`` readbacks) must pass through
   ``next_pow2``/``bucket_*`` before reaching a shape constructor, a

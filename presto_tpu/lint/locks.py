@@ -519,7 +519,7 @@ _BLOCKING_SCOPES = (
 )
 
 # call names that block for network/compile/device time: a lock held
-# across one stalls every thread contending for it (an ~90ms device
+# across one stalls every thread contending for it (a device
 # round-trip or a multi-second XLA compile inside a coordinator lock
 # turns the whole serve path lock-step)
 _BLOCKING_NAMES = {

@@ -16,10 +16,12 @@ rows-proportional wall split that let a cheap-wide scan absorb an
 expensive-narrow join's wall.
 
 Roofline ratios compare each node's arithmetic intensity (flops/byte)
-against the device balance point ``peak_flops / peak_bw``
-(``PRESTO_TPU_DEVICE_PEAK_FLOPS`` / ``PRESTO_TPU_DEVICE_PEAK_BW``,
-conservative host-CPU defaults): ratio >= 1 means compute-bound at
-peak, < 1 memory-bound.
+against the device balance point ``peak_flops / peak_bw`` of the
+device the process runs on (:data:`DEVICE_PEAKS`, keyed by
+``device_kind``; ``PRESTO_TPU_DEVICE_PEAK_FLOPS`` /
+``PRESTO_TPU_DEVICE_PEAK_BW`` override): ratio >= 1 means
+compute-bound at peak, < 1 memory-bound. A device that is not in the
+table gets no ratio — never one computed from another device's peaks.
 """
 
 from __future__ import annotations
@@ -36,10 +38,17 @@ ENV_PEAK_FLOPS = "PRESTO_TPU_DEVICE_PEAK_FLOPS"
 ENV_PEAK_BW = "PRESTO_TPU_DEVICE_PEAK_BW"
 ENV_PROFILE_DIR = "PRESTO_TPU_PROFILE_DIR"
 
-# Conservative single-socket host-CPU peaks (one AVX2 core feeding
-# from DRAM); override per deployment with the env vars above.
-_DEFAULT_PEAK_FLOPS = 5.0e10  # 50 GFLOP/s
-_DEFAULT_PEAK_BW = 2.0e10     # 20 GB/s
+# (peak FLOP/s, peak bytes/s) of one device, keyed by the
+# ``device_kind`` JAX reports.
+DEVICE_PEAKS: dict[str, tuple[float, float]] = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+    # 819 GB/s HBM per chip
+    "TPU v5 lite": (197e12, 819e9),
+    # an estimate, not a published figure (one AVX2 core feeding from
+    # DRAM: 50 GFLOP/s, 20 GB/s); a ratio against it is a CPU-run
+    # curiosity and never a device metric
+    "cpu": (5.0e10, 2.0e10),
+}
 
 _CAPTURES = REGISTRY.counter(
     "presto_tpu_profile_captures_total",
@@ -81,17 +90,28 @@ def harvest(compiled) -> dict | None:
     return out or None
 
 
-def device_peaks() -> tuple[float, float]:
-    """(peak_flops_per_s, peak_bytes_per_s) from the env overrides,
-    falling back to the host-CPU defaults on absence or garbage."""
-    def _env(name: str, default: float) -> float:
+def device_peaks() -> tuple[float, float] | None:
+    """(peak_flops_per_s, peak_bytes_per_s) of the process' device:
+    each env override where it parses to a positive number, else the
+    :data:`DEVICE_PEAKS` entry of ``jax.devices()[0].device_kind``.
+    None when a figure has neither — callers then report no roofline
+    value."""
+    import jax
+    table = DEVICE_PEAKS.get(jax.devices()[0].device_kind)
+
+    def _peak(name: str, index: int) -> float | None:
         try:
             v = float(os.environ.get(name, "") or 0.0)
         except ValueError:
-            return default
-        return v if v > 0 else default
-    return (_env(ENV_PEAK_FLOPS, _DEFAULT_PEAK_FLOPS),
-            _env(ENV_PEAK_BW, _DEFAULT_PEAK_BW))
+            v = 0.0
+        if v > 0:
+            return v
+        return table[index] if table else None
+
+    flops, bw = _peak(ENV_PEAK_FLOPS, 0), _peak(ENV_PEAK_BW, 1)
+    if flops is None or bw is None:
+        return None
+    return flops, bw
 
 
 # -- per-node attribution ----------------------------------------------------
@@ -135,7 +155,8 @@ def attribute(cost: dict | None,
     ``nodes`` is ``[(node_type, in_rows, out_rows, output_bytes)]`` in
     operator order. Returns ``(per_node, weights)``: ``per_node`` is a
     list of ``{"flops", "hbmBytes", "intensity", "roofline"}`` dicts
-    (empty dicts when no usable cost), ``weights`` the flops-share
+    (empty dicts when no usable cost; no ``roofline`` key when the
+    device's peaks are unknown), ``weights`` the flops-share
     wall-split weights (None when the caller should fall back to the
     rows-proportional split)."""
     if not nodes:
@@ -149,20 +170,22 @@ def attribute(cost: dict | None,
     # data movement tracks rows-through, without the kind factor
     bw = [float(max(0, i) + max(0, o) + 1) for _nt, i, o, _b in nodes]
     bw_sum = sum(bw) or 1.0
-    peak_flops, peak_bw = device_peaks()
-    ridge = peak_flops / peak_bw if peak_bw > 0 else 1.0
+    peaks = device_peaks()
     per_node: list[dict] = []
     for w, b in zip(fw, bw):
         flops = max(1, round(total_flops * w / fw_sum))
         nbytes = max(1, round(total_bytes * b / bw_sum)) \
             if total_bytes > 0 else 1
         intensity = flops / nbytes
-        per_node.append({
+        node = {
             "flops": int(flops),
             "hbmBytes": int(nbytes),
             "intensity": round(float(intensity), 4),
-            "roofline": round(float(intensity / ridge), 4),
-        })
+        }
+        if peaks is not None:
+            node["roofline"] = round(
+                float(intensity * peaks[1] / peaks[0]), 4)
+        per_node.append(node)
     return per_node, fw
 
 

@@ -33,8 +33,11 @@ import jax.numpy as jnp
 import numpy as np
 
 # Sentinel for an empty slot: max uint64. Real hashes are remapped off it.
-_EMPTY = jnp.uint64(0xFFFFFFFFFFFFFFFF)
-_NULL_KEY_HASH = jnp.uint64(0x9E3779B97F4A7C15)
+# NumPy scalars, not jnp: a module-level jnp scalar initialises the JAX
+# backend at import, and a process that imports this module would then
+# hold the chip (one process per TPU).
+_EMPTY = np.uint64(0xFFFFFFFFFFFFFFFF)
+_NULL_KEY_HASH = np.uint64(0x9E3779B97F4A7C15)
 
 
 class HashChainOverflow(RuntimeError):

@@ -116,14 +116,14 @@ def _sum_int64_like(data, segment_ids, num_segments: int, out_dtype):
 
 def _pallas_kernel(name: str, data, num_segments: int):
     """The Pallas segmented kernel for this call, or None. Consulted
-    FIRST by every public entry: under ``kernel_backend=pallas`` (or
-    auto on TPU) eligible folds accumulate per-tile in VMEM scratch
+    FIRST by every public entry: where the trace's backend for this
+    kernel is pallas, eligible folds accumulate per tile on-chip
     (presto_tpu/kernels/segagg.py) instead of paying the MXU one-hot
     matmuls / emulated scatters below. Integer-only on purpose — the
     sequential tile walk is bit-identical there; float sums would
     reassociate."""
     from presto_tpu import kernels as K
-    if K.active_backend() != "pallas":
+    if K.backend_for(name) != "pallas":
         return None
     from presto_tpu.kernels import segagg
     ok = (segagg.sum_eligible(data, num_segments) if name == "agg_sum"

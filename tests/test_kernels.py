@@ -250,14 +250,36 @@ def test_filter_compact_overflow_rows_drop():
 # -- backend resolution + dispatch ------------------------------------------
 
 
-def test_resolve_and_default_backend():
+def test_resolve_and_default_backend(monkeypatch):
     from presto_tpu.session import Session
     s = Session()
-    assert K.resolve(s) == K.default_backend()
+    # auto stays auto: it resolves per kernel at dispatch
+    assert K.resolve(s) == "auto"
     s.set("kernel_backend", "pallas")
     assert K.resolve(s) == "pallas"
     s.set("kernel_backend", "xla")
     assert K.resolve(s) == "xla"
+    # the literal set names registered kernels only, and off the TPU
+    # auto is the XLA twin for every kernel, listed or not
+    assert K.AUTO_PALLAS <= set(K.KERNELS)
+    monkeypatch.setattr(K, "AUTO_PALLAS", frozenset({"agg_sum"}))
+    with K.use_backend("auto"):
+        assert all(K.backend_for(n) == "xla" for n in K.KERNELS)
+        assert K.dispatch("agg_sum") is K.KERNELS["agg_sum"]["xla"]
+    # on a TPU auto picks Pallas for exactly the listed kernels
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with K.use_backend("auto"):
+        assert K.backend_for("agg_sum") == "pallas"
+        assert K.backend_for("compact") == "xla"
+    with K.use_backend("pallas"):
+        assert K.backend_for("compact") == "pallas"
+    # nothing but the CPU platform interprets
+    assert not K.interpret_mode()
+    monkeypatch.setattr(jax, "default_backend", lambda: "axelerator")
+    assert not K.interpret_mode()
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert K.interpret_mode()
 
 
 def test_kernel_attribution_reflects_what_ran(monkeypatch):

@@ -61,16 +61,34 @@ def test_harvest_live_compiled_program_and_pickles():
     assert devprof.harvest(object()) is None
 
 
-def test_device_peaks_env_override(monkeypatch):
+def test_device_peaks_table_and_env_override(monkeypatch, tpch_tiny):
     monkeypatch.delenv(devprof.ENV_PEAK_FLOPS, raising=False)
     monkeypatch.delenv(devprof.ENV_PEAK_BW, raising=False)
-    pf, pb = devprof.device_peaks()
-    assert pf > 0 and pb > 0
+    # the suite runs on the cpu platform: its (labelled) table entry
+    assert devprof.device_peaks() == devprof.DEVICE_PEAKS["cpu"]
+    assert devprof.DEVICE_PEAKS["TPU v5 lite"] == (197e12, 819e9)
+    pb = devprof.DEVICE_PEAKS["cpu"][1]
     monkeypatch.setenv(devprof.ENV_PEAK_FLOPS, "1e12")
     monkeypatch.setenv(devprof.ENV_PEAK_BW, "garbage")
-    pf2, pb2 = devprof.device_peaks()
-    assert pf2 == 1e12
-    assert pb2 == pb  # garbage falls back to the default
+    assert devprof.device_peaks() == (1e12, pb)  # garbage -> table
+    # a device that is not in the table has no peaks and no roofline:
+    # never a ratio computed from another device's figures
+    monkeypatch.setattr(devprof, "DEVICE_PEAKS", {})
+    assert devprof.device_peaks() is None
+    per_node, _w = devprof.attribute(
+        {"flops": 1e6, "bytes": 1e6}, [("Join", 10, 10, 80)])
+    assert per_node[0]["flops"] > 0 and "roofline" not in per_node[0]
+    # ... and SQL shows NULL for it, not a 0.0 ratio
+    engine = Engine()
+    engine.register_catalog("tpch", tpch_tiny)
+    stats = ("select query_id, roofline from system.operator_stats "
+             "where flops > 0")
+    earlier = {r[0] for r in engine.execute(stats)}  # process-wide table
+    engine.execute("select count(*) from region where r_regionkey < 3")
+    costed = [r for r in engine.execute(stats) if r[0] not in earlier]
+    assert costed and all(r[1] is None for r in costed), costed
+    monkeypatch.setenv(devprof.ENV_PEAK_BW, "2e10")  # both overridden
+    assert devprof.device_peaks() == (1e12, 2e10)
 
 
 def test_wall_split_regression_cheap_wide_vs_expensive_narrow():
@@ -261,7 +279,7 @@ def test_warm_fresh_process_q5_cost_columns(obs_cluster):
     assert [f for f in os.listdir(cache_dir) if f.endswith(".prog")]
     env = dict(os.environ,
                PRESTO_TPU_PROGRAM_CACHE_DIR=cache_dir,
-               PRESTO_TPU_XLA_CACHE="", JAX_PLATFORMS="cpu")
+               JAX_ENABLE_COMPILATION_CACHE="false", JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, "-c", _WARM_CHILD], capture_output=True,
         text=True, timeout=540, cwd=REPO, env=env)

@@ -156,8 +156,16 @@ def test_capacities_bucket_to_pow2():
 def test_digest_changes_with_platform_and_mesh():
     key = ("fp", (), ())
     local = PC.platform_fingerprint()
-    meshed = PC.platform_fingerprint(mesh_shape=((8,), ("d",)))
+    import jax
+    from jax.sharding import Mesh
+    devs = jax.devices()
+    meshed = PC.platform_fingerprint(
+        mesh_shape=PC.mesh_key(Mesh(np.array(devs[:4]), ("d",))))
     assert PC.entry_digest(key, local) != PC.entry_digest(key, meshed)
+    # same shape and axis names over other devices: another executable
+    other = PC.platform_fingerprint(
+        mesh_shape=PC.mesh_key(Mesh(np.array(devs[4:8]), ("d",))))
+    assert PC.entry_digest(key, meshed) != PC.entry_digest(key, other)
     other_ver = ("jax-9.9.9",) + tuple(local[1:])
     assert PC.entry_digest(key, local) != PC.entry_digest(
         key, other_ver)
@@ -240,7 +248,7 @@ print(json.dumps({
 def _run_child(cache_dir) -> dict:
     env = dict(os.environ,
                PRESTO_TPU_PROGRAM_CACHE_DIR=str(cache_dir),
-               PRESTO_TPU_XLA_CACHE="", JAX_PLATFORMS="cpu")
+               JAX_ENABLE_COMPILATION_CACHE="false", JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, "-c", _CHILD], capture_output=True,
         text=True, timeout=240, cwd=REPO, env=env)
@@ -260,6 +268,46 @@ def test_warm_process_compiles_nothing(tmp_path):
     assert warm["compiled"] == 0, warm
     assert warm["disk_hits"] >= 1
     assert warm["rows"] == cold["rows"]
+
+
+def test_disk_hit_runs_on_one_device_among_many(tmp_path, monkeypatch):
+    """jax 0.9.0's deserialize_and_load binds an executable to EVERY
+    device of the backend unless told which; a one-device program
+    loaded that way "expects 8 shards" in this 8-virtual-device suite
+    (and on a four-chip host). The store names the devices. A sortless
+    program, so the CPU client can serialise it at all."""
+    monkeypatch.setenv(PC.ENV_DIR, str(tmp_path))
+    sql = "select sum(v), count(*) from t where k < 5"
+    want = mem_engine().execute(sql)
+    assert [f for f in os.listdir(tmp_path) if f.endswith(".prog")]
+    d0, c0 = _HITS.value(tier="disk"), _COMPILED.value()
+    assert mem_engine().execute(sql) == want  # fresh memory tier
+    assert _HITS.value(tier="disk") - d0 >= 1
+    assert _COMPILED.value() == c0
+
+
+def test_mesh_disk_hit_loads_onto_the_mesh_devices(tmp_path,
+                                                  monkeypatch):
+    """A shard_map program is bound to its mesh's devices, whichever
+    they are: a mesh over devices 4..7 must disk-hit in a fresh memory
+    tier (loading onto the first four devices instead fails the load),
+    and a same-shaped mesh over devices 0..3 is another program."""
+    import jax
+    from jax.sharding import Mesh
+    monkeypatch.setenv(PC.ENV_DIR, str(tmp_path))
+    sql = "select sum(v), count(*) from t where k < 5"
+    upper = Mesh(np.array(jax.devices()[4:8]), ("d",))
+    lower = Mesh(np.array(jax.devices()[:4]), ("d",))
+    first = mem_engine()
+    want = first.execute(sql, mesh=upper)
+    d0, c0 = _HITS.value(tier="disk"), _COMPILED.value()
+    e0 = _DISK_ERRORS.value(op="load")
+    assert mem_engine().execute(sql, mesh=upper) == want
+    assert _HITS.value(tier="disk") - d0 == 1
+    assert _COMPILED.value() == c0
+    assert _DISK_ERRORS.value(op="load") == e0
+    assert first.execute(sql, mesh=lower) == want
+    assert _COMPILED.value() - c0 == 1  # neither tier served it
 
 
 def test_disk_hit_then_corruption_fallback(tmp_path, monkeypatch):
