@@ -1,0 +1,5 @@
+select sum(l_extendedprice * l_discount) as revenue
+from lineitem
+where l_shipdate >= date '{DATE}'
+  and l_shipdate < date '{DATE}' + interval '1' year
+  and l_discount between {DISC_LO} and {DISC_HI} and l_quantity < {QUANTITY}
