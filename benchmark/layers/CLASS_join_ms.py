@@ -1,0 +1,12 @@
+"""Device self time of the operations under ``Join#n`` and ``MultiJoin#n``
+scopes (``exec/executor.PlanInterpreter.run``) inside one statement of
+the class: median over the class's statements wholly inside the traced
+sub-window; ms; closed loops only. One reader for every
+``<class>_join_ms``: the harness hands it the class that the metric's
+name holds."""
+
+import opnames
+
+
+def read(ctx, cls):
+    return opnames.class_kind_ms(ctx, cls, ("Join", "MultiJoin"))

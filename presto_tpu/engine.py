@@ -141,7 +141,9 @@ class Engine:
             hit = self._dev_cache.get(id(a))
             if hit is not None and hit[0] is a:
                 return hit[1]
-        dev = jax.device_put(a)
+        # a miss only: the upload of a table version not seen before
+        with TRACER.span("pin", bytes=a.nbytes):
+            dev = jax.device_put(a)
         with self._dev_cache_lock:
             hit = self._dev_cache.get(id(a))
             if hit is not None and hit[0] is a:
@@ -208,10 +210,13 @@ class Engine:
 
         W.push(WC := W.WarningCollector())
         try:
-            stmt = rewrite_statement(parse_statement(sql), self)
-            if isinstance(stmt, A.ExecutePrepared):
-                sql = self._resolve_prepared(stmt)
+            # the served path has parsed this text before (the server,
+            # then plan_sql): the span shows what parsing it again costs
+            with TRACER.span("parse"):
                 stmt = rewrite_statement(parse_statement(sql), self)
+                if isinstance(stmt, A.ExecutePrepared):
+                    sql = self._resolve_prepared(stmt)
+                    stmt = rewrite_statement(parse_statement(sql), self)
             if not isinstance(stmt, A.QueryStatement):
                 raise ValueError("execute_table expects a SELECT query")
             preplanned = self.take_preplanned(sql)

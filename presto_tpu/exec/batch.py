@@ -39,8 +39,6 @@ batches the traffic that dominates repeats anyway.
 
 from __future__ import annotations
 
-import time
-
 import jax
 import numpy as np
 
@@ -151,7 +149,8 @@ def _run_batched(engine, templates: list, k: int, plan, n_params: int):
     from presto_tpu.exec import progcache as PC
     from presto_tpu.exec.cancel import checkpoint
     from presto_tpu.exec.executor import (RETRY_GROWTH, _cache_key,
-                                          collect_scans, make_traced)
+                                          collect_scans, compile_traced,
+                                          make_traced, program_name)
 
     scan_inputs = TPL.bucket_scans(engine,
                                    collect_scans(plan, engine))
@@ -194,15 +193,11 @@ def _run_batched(engine, templates: list, k: int, plan, n_params: int):
             batched_fn = jax.vmap(
                 traced_fn,
                 in_axes=(None,) * len(flat_arrays) + (0,) * n_params)
-            from presto_tpu.exec.executor import (_COMPILES,
-                                                  _COMPILE_SECONDS)
-            _t0 = time.perf_counter()
-            with TRACER.span("compile", attempt=_attempt,
-                             root=type(plan).__name__, batch=kp):
-                compiled = jax.jit(batched_fn).lower(
-                    *flat_arrays, *example).compile()
-            _COMPILES.inc()
-            _COMPILE_SECONDS.observe(time.perf_counter() - _t0)
+            batched_fn.__name__ = program_name(
+                plan, serial_key[0], prefix=f"batch{kp}_")
+            compiled = compile_traced(
+                batched_fn, [*flat_arrays, *example], attempt=_attempt,
+                root=type(plan).__name__, batch=kp)
             cache.insert((base_key, caps_key), compiled, meta, fpr,
                          persist=False)
             cache_hit = False

@@ -13,9 +13,8 @@ rehash (MultiChannelGroupByHash.java:140).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-import os
-import sys
 import time
 
 import jax
@@ -127,7 +126,11 @@ def collect_scans(plan: N.PlanNode, engine) -> list[ScanInput]:
         for s in node.sources():
             visit(s)
 
-    visit(plan)
+    with TRACER.span("scan-collect") as span:
+        visit(plan)
+        if span is not None:
+            span.attrs.update(tables=len(out),
+                              rows=sum(s.nrows for s in out))
     return out
 
 
@@ -137,6 +140,38 @@ def _df_hash(v: Val):
     if v.is_string:
         return H.hash_string_column(v.data, v.dictionary, v.valid)
     return H.hash_int_column(v.data, v.valid)
+
+
+def program_name(plan: N.PlanNode, template_fp: str | None = None,
+                 prefix: str = "") -> str:
+    """A stable name for the jitted program of ``plan``: its root
+    operator's kind and, for a templated plan, the first 8 hex digits
+    of the template's fingerprint (hoisted literals are out of it, so
+    literal variants share the name; a plan that still holds its
+    literals gets the kind alone). It names the XLA module, which is
+    how a profiler capture tells one program from another."""
+    name = prefix + type(plan).__name__.lower()
+    return f"{name}_{template_fp[:8]}" if template_fp else name
+
+
+@contextlib.contextmanager
+def compiling(**attrs):
+    """A ``compile`` span around the building of one program (trace,
+    lower, XLA compile), counted in ``programs_compiled_total`` and
+    ``compile_seconds``: the one way every path that builds a program
+    says so."""
+    t0 = time.perf_counter()
+    with TRACER.span("compile", **attrs):
+        yield
+    _COMPILES.inc()
+    _COMPILE_SECONDS.observe(time.perf_counter() - t0)
+
+
+def compile_traced(fn, args: list, **attrs):
+    """Explicit AOT lower+compile of ``fn`` for ``args`` (not a first
+    jit-wrapper call), so compile and execute attribute separately."""
+    with compiling(**attrs):
+        return jax.jit(fn).lower(*args).compile()
 
 
 def preorder_index(plan: N.PlanNode) -> dict[int, int]:
@@ -192,8 +227,14 @@ class PlanInterpreter:
 
     def run(self, node: N.PlanNode) -> DTable:
         from presto_tpu import kernels as K
-        m = getattr(self, "_r_" + type(node).__name__.lower())
-        with K.collect() as used:
+        kind = type(node).__name__
+        m = getattr(self, "_r_" + kind.lower())
+        # device operations carry the plan operator's name: run()
+        # recurses, so the scopes nest (TopN#1/Aggregate#2/Join#3/...)
+        # and an operation belongs to the innermost Kind#n of its path
+        pos = self.node_order.get(id(node))
+        scope = kind if pos is None else f"{kind}#{pos}"
+        with K.collect() as used, jax.named_scope(scope):
             dt = m(node)
         if used:
             self.kernel_used[
@@ -646,16 +687,25 @@ def prepare_plan(engine, plan: N.PlanNode, scan_inputs: list[ScanInput]):
     # tree shape is identical so preorder positions line up
     orig_plan = plan
     tpl = None
-    if TPL.enabled(engine.session):
-        scan_inputs = TPL.bucket_scans(engine, scan_inputs)
-        tpl = TPL.parameterize(plan)
-        if tpl is not None:
-            plan = tpl.plan
-    base_key, _ = _cache_key(engine, plan, scan_inputs, {})
-    known_caps = engine._caps_memory.get(base_key)
-    if known_caps is None:  # {} is a real answer: no overrides needed
-        known_caps = cache.load_caps(base_key, fpr)
-    capacities = dict(known_caps)
+    templated = TPL.enabled(engine.session)
+    if templated:
+        # padded_bytes stays 0 when every pad came from the pad cache
+        with TRACER.span("bucket-pad", padded_bytes=0) as span:
+            scan_inputs = TPL.bucket_scans(
+                engine, scan_inputs,
+                stats=span.attrs if span is not None else None)
+    # hoisting the literals, the program-cache key (plan fingerprint,
+    # shapes, dictionary digests) and the remembered capacities
+    with TRACER.span("program-lookup"):
+        if templated:
+            tpl = TPL.parameterize(plan)
+            if tpl is not None:
+                plan = tpl.plan
+        base_key, _ = _cache_key(engine, plan, scan_inputs, {})
+        known_caps = engine._caps_memory.get(base_key)
+        if known_caps is None:  # {} is a real answer: no overrides
+            known_caps = cache.load_caps(base_key, fpr)
+        capacities = dict(known_caps)
 
     from presto_tpu.exec.cancel import checkpoint
     for _attempt in range(6):
@@ -674,22 +724,18 @@ def prepare_plan(engine, plan: N.PlanNode, scan_inputs: list[ScanInput]):
             traced_fn, _host_arrays, meta = make_traced(
                 scan_inputs, plan, capacities, engine.session,
                 params=(pargs if tpl is not None else None))
+            traced_fn.__name__ = program_name(
+                plan, base_key[0] if tpl is not None else None)
             # compile-latency chaos point (ft/faults.py): lets the
             # chaos suite provoke slow compiles deterministically
             from presto_tpu.ft.faults import FAULTS
             FAULTS.delay("compile-slow", key=type(plan).__name__)
-            _t0 = time.perf_counter()
-            # explicit AOT lower+compile (not a first jit-wrapper call)
-            # so compile and execute attribute separately in spans;
             # meta fills during the trace lower() triggers
-            with TRACER.span("compile", attempt=_attempt,
-                             root=type(plan).__name__):
-                compiled = jax.jit(traced_fn).lower(
-                    *flat_arrays, *pargs).compile()
-            compile_s = time.perf_counter() - _t0
-            last_compile_s = compile_s
-            _COMPILES.inc()
-            _COMPILE_SECONDS.observe(compile_s)
+            _t0 = time.perf_counter()
+            compiled = compile_traced(
+                traced_fn, [*flat_arrays, *pargs], attempt=_attempt,
+                root=type(plan).__name__)
+            last_compile_s = time.perf_counter() - _t0
             # device-cost summary rides the meta into the program
             # cache (and its disk tier): warm hits in a fresh process
             # still attribute flops/bytes without a live Compiled
@@ -697,10 +743,6 @@ def prepare_plan(engine, plan: N.PlanNode, scan_inputs: list[ScanInput]):
             cost = devprof.harvest(compiled)
             if cost is not None:
                 meta["cost"] = cost
-            if os.environ.get("PRESTO_TPU_LOG_COMPILES"):
-                print(f"[compile] {compile_s:.1f}s "
-                      f"caps={dict(capacities)} "
-                      f"root={type(plan).__name__}", file=sys.stderr)
             # memory tier only for now: failed capacity-retry rungs
             # must not pay serialize+IO (and would pollute the store);
             # the disk persist happens below, on the successful attempt
@@ -739,10 +781,11 @@ def prepare_plan(engine, plan: N.PlanNode, scan_inputs: list[ScanInput]):
             engine._caps_memory[base_key] = dict(capacities)
             # fold this program into the ambient stats tree (no-op
             # outside a task/query recording scope)
-            QS.record_program(
-                engine, orig_plan, meta, counts, last_compile_s,
-                execute_s, cache_hit, template=tpl is not None,
-                template_hit=tpl is not None and cache_hit)
+            with TRACER.span("stats-record"):
+                QS.record_program(
+                    engine, orig_plan, meta, counts, last_compile_s,
+                    execute_s, cache_hit, template=tpl is not None,
+                    template_hit=tpl is not None and cache_hit)
             return compiled, flat_arrays, meta, (res, live, oks,
                                                  counts)
         if not cache_hit:

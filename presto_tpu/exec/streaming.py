@@ -25,6 +25,7 @@ import numpy as np
 
 from presto_tpu import types as T
 from presto_tpu.exec import hostsync as HS
+from presto_tpu.obs.trace import TRACER
 from presto_tpu.plan import nodes as N
 
 
@@ -77,7 +78,8 @@ def _replace_node(plan: N.PlanNode, target: N.PlanNode,
 def try_execute_streamed(engine, plan: N.PlanNode):
     """Execute ``plan`` block-streamed, or return None if inapplicable."""
     from presto_tpu.exec.executor import (
-        ScanInput, collect_scans, make_traced, run_plan)
+        ScanInput, collect_scans, compiling, make_traced, program_name,
+        run_plan)
 
     block = int(engine.session.get("scan_block_rows") or 0)
     if block <= 0:
@@ -116,9 +118,15 @@ def try_execute_streamed(engine, plan: N.PlanNode):
     meta = None
     for i in range(nblocks):
         checkpoint()
-        arrays = block_input(i)
+        with TRACER.span("block-input", block=i,
+                         rows=min((i + 1) * block, scan.nrows) - i * block):
+            arrays = block_input(i)
+        host_args = [arrays[sym] for sym in scan.arrays]
+        host_args.append(arrays["__live__"])
+        dev_args = None
         for _attempt in range(10):
-            if compiled is None:
+            fresh = compiled is None
+            if fresh:
                 block_scan = ScanInput(scan.node, arrays,
                                        scan.dictionaries, scan.types,
                                        block)
@@ -128,10 +136,41 @@ def try_execute_streamed(engine, plan: N.PlanNode):
                 traced_fn, _flat, meta = make_traced(
                     [block_scan], partial, capacities, engine.session,
                     collect_rows=False)
+                # the plan holds its literals (no template here), so
+                # the name is the root kind alone
+                traced_fn.__name__ = program_name(partial,
+                                                  prefix="stream_")
                 compiled = jax.jit(traced_fn)
-            res, live, oks = compiled(
-                *[arrays[sym] for sym in scan.arrays], arrays["__live__"])
-            oks_np = HS.fetch(oks, site="streaming-ok-ladder")
+            if dev_args is None:
+                # the host's share of the copy; what is still in
+                # flight when this returns falls into ``execute``
+                with TRACER.span("transfer", block=i,
+                                 bytes=sum(a.nbytes for a in host_args)):
+                    dev_args = jax.device_put(host_args)
+            if fresh:
+                # The first call of a fresh jit traces, lowers, compiles
+                # and dispatches, and returns before the device is done
+                # (dispatch is asynchronous), so the span holds the
+                # building of the program and ``execute`` below the
+                # waiting for this block. Not an AOT lower().compile()
+                # as in prepare_plan: in the served process that made
+                # every streamed statement at SF10 0.9-2.8 s slower on
+                # the chip (PERF.md section 6, PR 25), its lowering
+                # alone 1.8-3.0 s against 0.2 s on this path; meta
+                # fills during the trace.
+                with compiling(attempt=_attempt,
+                               root=type(partial).__name__, streamed=True):
+                    outs = compiled(*dev_args)
+            with TRACER.span("execute", block=i, streamed=True):
+                if not fresh:
+                    outs = compiled(*dev_args)
+                res, live, oks = outs
+                oks_np = HS.fetch(oks, site="streaming-ok-ladder")
+                if oks_np.all():
+                    # one batched transfer per block, not one per
+                    # output column
+                    res_np, live_np = HS.fetch((list(res), live),
+                                               site="streaming-demux")
             if oks_np.all():
                 break
             from presto_tpu.ops.hash import grow_overflowed
@@ -143,9 +182,6 @@ def try_execute_streamed(engine, plan: N.PlanNode):
             raise HashChainOverflow(
                 "hash table capacity retry limit exceeded")
         out_schema = meta["out"]
-        # one batched transfer per block, not one per output column
-        res_np, live_np = HS.fetch((list(res), live),
-                                   site="streaming-demux")
         partial_cols.append(res_np)
         partial_live.append(live_np)
 

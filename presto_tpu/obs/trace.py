@@ -32,6 +32,8 @@ import time
 import uuid
 from collections import OrderedDict
 
+from presto_tpu.obs.metrics import REGISTRY
+
 TRACE_HEADER = "X-Presto-TPU-Trace"
 
 _CURRENT: contextvars.ContextVar[tuple[str, str] | None] = \
@@ -46,6 +48,38 @@ _NODE: contextvars.ContextVar[str | None] = \
 MAX_TRACES = 256
 MAX_SPANS_PER_TRACE = 4096
 
+# Spans keep epoch seconds (the Chrome export, the worker hand-over and
+# the benchmark read them so), but are STAMPED from the monotonic
+# clock: the offset between the two is taken once, here, so a step of
+# the wall clock during a run moves no span against another, nor
+# against a profiler trace tied to time.monotonic().
+_EPOCH = time.time() - time.monotonic()
+
+# name prefix of the spans' twins in a profiler capture
+ANNOTATION_PREFIX = "pt:"
+
+_EVICTIONS = REGISTRY.counter(
+    "presto_tpu_trace_evictions_total",
+    "whole traces dropped from the span store to admit a new one (the "
+    "store keeps the last MAX_TRACES): zero means a reader of the "
+    "store saw every traced statement")
+
+
+def now() -> float:
+    """Epoch seconds on the monotonic clock: what every span's ``t0``
+    and ``t1`` are, and what a caller that hands ``add_span`` an
+    interval has to measure it with."""
+    return _EPOCH + time.monotonic()
+
+
+def _annotation(name: str):
+    """The span's twin in the host plane of a ``jax.profiler`` capture
+    (``/v1/profile``, the benchmark's traced run), so the program's
+    spans lie in the same ``.xplane.pb`` as the device operations.
+    While no profiler runs it is a flag test."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(ANNOTATION_PREFIX + name)
+
 
 def _new_span_id() -> str:
     return uuid.uuid4().hex[:16]
@@ -58,7 +92,7 @@ class Span:
     parent_id: str | None
     name: str
     attrs: dict
-    t0: float               # wall clock, seconds (time.time())
+    t0: float               # epoch seconds, from now()
     t1: float | None = None
 
     def to_dict(self) -> dict:
@@ -118,6 +152,7 @@ class Tracer:
             if spans is None:
                 while len(self._traces) >= self.max_traces:
                     self._traces.popitem(last=False)
+                    _EVICTIONS.inc()
                 spans = self._traces[span.trace_id] = []
             if len(spans) < self.max_spans:
                 spans.append(span)
@@ -131,14 +166,14 @@ class Tracer:
         attrs = dict(attrs)
         if "node" not in attrs and _NODE.get() is not None:
             attrs["node"] = _NODE.get()
-        span = Span(trace_id, _new_span_id(), None, name, attrs,
-                    time.time())
+        span = Span(trace_id, _new_span_id(), None, name, attrs, now())
         self._record(span)
         token = _CURRENT.set((trace_id, span.span_id))
         try:
-            yield span
+            with _annotation(name):
+                yield span
         finally:
-            span.t1 = time.time()
+            span.t1 = now()
             _CURRENT.reset(token)
 
     @contextlib.contextmanager
@@ -153,14 +188,15 @@ class Tracer:
         attrs = dict(attrs)
         if "node" not in attrs and _NODE.get() is not None:
             attrs["node"] = _NODE.get()
-        span = Span(trace_id, _new_span_id(), parent, name,
-                    attrs, time.time())
+        span = Span(trace_id, _new_span_id(), parent, name, attrs,
+                    now())
         self._record(span)
         token = _CURRENT.set((trace_id, span.span_id))
         try:
-            yield span
+            with _annotation(name):
+                yield span
         finally:
-            span.t1 = time.time()
+            span.t1 = now()
             _CURRENT.reset(token)
 
     @contextlib.contextmanager
@@ -195,14 +231,15 @@ class Tracer:
         attrs["instant"] = True
         if "node" not in attrs and _NODE.get() is not None:
             attrs["node"] = _NODE.get()
-        now = time.time()
+        at = now()
         self._record(Span(trace_id, _new_span_id(), None, name, attrs,
-                          now, now))
+                          at, at))
 
     def add_span(self, name: str, t0: float, t1: float,
                  **attrs) -> None:
         """Record an already-finished interval under the ambient
-        context (e.g. queue-admission wait measured retroactively)."""
+        context (e.g. queue-admission wait measured retroactively);
+        ``t0`` and ``t1`` are readings of :func:`now`."""
         ctx = _CURRENT.get()
         if ctx is None:
             return
@@ -253,7 +290,7 @@ class Tracer:
         process lane per ``node`` attr, plus span/parent ids in
         ``args`` so the tree survives the format."""
         spans = self.spans(trace_id)
-        now = time.time()
+        at = now()
         pids: dict[str, int] = {}
         events: list[dict] = []
         for s in spans:
@@ -283,7 +320,7 @@ class Tracer:
             events.append({
                 "name": s.name, "cat": "query", "ph": "X",
                 "ts": int(s.t0 * 1e6),
-                "dur": max(0, int(((s.t1 if s.t1 is not None else now)
+                "dur": max(0, int(((s.t1 if s.t1 is not None else at)
                                    - s.t0) * 1e6)),
                 "pid": pid, "tid": 0, "args": args})
         return {"traceEvents": events, "displayTimeUnit": "ms"}

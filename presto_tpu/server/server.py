@@ -32,6 +32,7 @@ from presto_tpu.obs import qstats as QS
 from presto_tpu.obs.jsonlog import LOG
 from presto_tpu.obs.metrics import REGISTRY
 from presto_tpu.obs.trace import TRACER
+from presto_tpu.obs.trace import now as trace_now
 from presto_tpu.server.httpbase import HttpService, JsonHandler
 from presto_tpu.server.results import (ResultAbandoned, ResultQueue,
                                        compact_table, json_rows,
@@ -90,9 +91,9 @@ class QueryInfo:
     # "json" | "arrow" — from the X-Presto-TPU-Result request header
     result_format: str = "json"
     created: float = dataclasses.field(default_factory=time.monotonic)
-    # wall-clock twin of ``created`` for the trace timeline (spans use
-    # wall time; ``created`` stays monotonic for duration math)
-    created_wall: float = dataclasses.field(default_factory=time.time)
+    # ``created`` on the spans' clock (obs/trace.now: epoch seconds
+    # stamped from the monotonic clock), the start of ``admission``
+    created_wall: float = dataclasses.field(default_factory=trace_now)
     started: float | None = None
     finished: float | None = None
     rows_sent: int = 0
@@ -353,8 +354,8 @@ class QueryManager:
                     TRACER.trace(q.query_id, "query", user=q.user,
                                  sql=q.sql[:200],
                                  node="coordinator") as root:
-                TRACER.add_span("admission", q.created_wall,
-                                time.time())
+                # ends where ``query`` starts, on the same clock
+                TRACER.add_span("admission", q.created_wall, root.t0)
                 # terminal transitions only fire from RUNNING: the
                 # reaper/canceller owns any state it already set (the
                 # orphaned run thread must not overwrite FAILED)
@@ -428,7 +429,8 @@ class QueryManager:
         from presto_tpu.sql.parser import parse_statement
 
         sql = q.sql
-        stmt = parse_statement(sql)
+        with TRACER.span("parse"):
+            stmt = parse_statement(sql)
         if isinstance(stmt, A.ExecutePrepared):
             # splice literals over the stored text's ? markers and run
             # the result through the normal pipeline — every variant
@@ -536,19 +538,24 @@ class QueryManager:
         start = 0
         while start < total:
             stop = min(start + PAGE_ROWS, total)
-            page = page_slice(cols, start, stop)
-            if q.result_format == "arrow":
-                # narrow each page's varchar dictionary to the codes
-                # it references: slicing keeps the FULL dictionary,
-                # and shipping it whole per page would scale bytes
-                # (and the queue's buffered memory) by the page count
-                payload: object = wire.columns_to_bytes(
-                    wire.compact_page_dictionaries(page),
-                    codec=wire.WIRE_ARROW)
-            else:
-                payload = json_rows(page, stop - start)
+            with TRACER.span("encode", rows=stop - start,
+                             format=q.result_format):
+                page = page_slice(cols, start, stop)
+                if q.result_format == "arrow":
+                    # narrow each page's varchar dictionary to the
+                    # codes it references: slicing keeps the FULL
+                    # dictionary, and shipping it whole per page would
+                    # scale bytes (and the queue's buffered memory) by
+                    # the page count
+                    payload: object = wire.columns_to_bytes(
+                        wire.compact_page_dictionaries(page),
+                        codec=wire.WIRE_ARROW)
+                else:
+                    payload = json_rows(page, stop - start)
             _RESULT_ROWS.inc(stop - start)
-            queue.put(payload, stop - start)
+            # blocks while the client lags RESULT_QUEUE_PAGES behind
+            with TRACER.span("page-wait"):
+                queue.put(payload, stop - start)
             start = stop
         queue.close()
 

@@ -49,6 +49,7 @@ import time
 import numpy as np
 
 from presto_tpu.obs.metrics import REGISTRY
+from presto_tpu.obs.trace import TRACER
 from presto_tpu.plan import nodes as N
 
 _CACHE_HITS = REGISTRY.counter(
@@ -64,6 +65,17 @@ _CACHE_INVALIDATIONS = REGISTRY.counter(
 _DEDUPED = REGISTRY.counter(
     "presto_tpu_deduped_queries_total",
     "queries that awaited an in-flight duplicate instead of executing")
+# the fast path runs on the HTTP handler thread before any trace
+# exists, so it is timed by histograms (no labels) and not by spans
+_FAST_HIT_SECONDS = REGISTRY.histogram(
+    "presto_tpu_fast_hit_seconds",
+    "handler-thread time of a try_fast_hit call that ended in a "
+    "result-cache hit")
+_FAST_PLAN_SECONDS = REGISTRY.histogram(
+    "presto_tpu_fast_path_plan_seconds",
+    "handler-thread time try_fast_hit spent parsing and planning a "
+    "text it found no memo for (every text, after each write), "
+    "whatever the call's outcome")
 
 
 def _table_nbytes(table) -> int:
@@ -294,77 +306,90 @@ class ServingLayer:
         engine = self.engine
         overrides = dict(q.session_properties)
         with engine.session.as_user(q.user, overrides):
-            sess = engine.session
-            if not bool(sess.get("result_cache")):
+            if not bool(engine.session.get("result_cache")):
+                return False  # nothing is observed with the cache off
+            t0 = time.perf_counter()
+            hit = self._fast_hit(q, overrides)
+            if hit:
+                _FAST_HIT_SECONDS.observe(time.perf_counter() - t0)
+            return hit
+
+    def _fast_hit(self, q, overrides: dict) -> bool:
+        """``try_fast_hit`` under the user's session, cache on."""
+        engine = self.engine
+        sess = engine.session
+        from presto_tpu.exec.progcache import trace_session_key
+        mkey = (q.sql, sess.catalog,
+                tuple(sorted((k, repr(v))
+                             for k, v in overrides.items())))
+        with self._lock:
+            memo = self._memo.get(mkey)
+        if memo is None:
+            t0 = time.perf_counter()
+            memo = self._plan_for_memo(q, mkey)
+            _FAST_PLAN_SECONDS.observe(time.perf_counter() - t0)
+        if memo is None or memo is _MEMO_NEG:
+            return False
+        fingerprint, tables = memo
+        # the memo shortcut skips plan_sql, which is where the
+        # planner authorizes each table scan — re-enforce it here
+        # or a cached result would leak to a denied user. Denials
+        # fall to the full path, which raises them classified.
+        from presto_tpu.security import AccessDeniedError
+        try:
+            for catalog, tname in tables:
+                engine.access_control.check_can_select(
+                    q.user, catalog, tname)
+        except AccessDeniedError:
+            return False
+        versions = []
+        for catalog, tname in tables:
+            conn = engine.catalogs.get(catalog)
+            version = (conn.table_version(tname)
+                       if conn is not None else None)
+            if version is None:
                 return False
-            from presto_tpu.exec.progcache import trace_session_key
-            mkey = (q.sql, sess.catalog,
-                    tuple(sorted((k, repr(v))
-                                 for k, v in overrides.items())))
-            with self._lock:
-                memo = self._memo.get(mkey)
-            if memo is _MEMO_NEG:
-                return False
-            if memo is None:
-                from presto_tpu.plan.fingerprint import plan_fingerprint
-                from presto_tpu.sql import ast as A
-                from presto_tpu.sql.parser import parse_statement
-                try:
-                    stmt = parse_statement(q.sql)
-                except Exception:  # noqa: BLE001 - full path reports it
-                    return False
-                if not isinstance(stmt, A.QueryStatement):
-                    with self._lock:
-                        if len(self._memo) >= _MEMO_MAX:
-                            self._memo.clear()
-                        self._memo[mkey] = _MEMO_NEG
-                    return False
-                try:
-                    plan, _ = engine.plan_sql(q.sql)
-                except Exception:  # noqa: BLE001 - full path reports it
-                    return False
+            versions.append((catalog, tname, version))
+        key = (fingerprint, tuple(sorted(set(versions))),
+               trace_session_key(sess))
+        entry = self.cache.lookup(key)
+        if entry is None:
+            return False
+        rows = entry.json_rows
+        if rows is None:
+            from presto_tpu.server.results import (compact_table,
+                                                   json_rows)
+            cols, total = compact_table(entry.table)
+            rows = json_rows(cols, total)
+            entry.json_rows = rows  # atomic publish; idempotent
+        q.columns = list(entry.columns)
+        q.rows = rows
+        q.cache_hit = True
+        return True
+
+    def _plan_for_memo(self, q, mkey):
+        """Parse and plan a text the memo does not hold and remember
+        (fingerprint, scanned tables) for it, or ``_MEMO_NEG`` for
+        what is no plain SELECT; None where parse or plan fails (the
+        full path reports the error)."""
+        from presto_tpu.plan.fingerprint import plan_fingerprint
+        from presto_tpu.sql import ast as A
+        from presto_tpu.sql.parser import parse_statement
+        try:
+            stmt = parse_statement(q.sql)
+            if not isinstance(stmt, A.QueryStatement):
+                memo = _MEMO_NEG
+            else:
+                plan, _ = self.engine.plan_sql(q.sql)
                 memo = (plan_fingerprint(plan),
                         tuple(self._scan_tables(plan)))
-                with self._lock:
-                    if len(self._memo) >= _MEMO_MAX:
-                        self._memo.clear()
-                    self._memo[mkey] = memo
-            fingerprint, tables = memo
-            # the memo shortcut skips plan_sql, which is where the
-            # planner authorizes each table scan — re-enforce it here
-            # or a cached result would leak to a denied user. Denials
-            # fall to the full path, which raises them classified.
-            from presto_tpu.security import AccessDeniedError
-            try:
-                for catalog, tname in tables:
-                    engine.access_control.check_can_select(
-                        q.user, catalog, tname)
-            except AccessDeniedError:
-                return False
-            versions = []
-            for catalog, tname in tables:
-                conn = engine.catalogs.get(catalog)
-                version = (conn.table_version(tname)
-                           if conn is not None else None)
-                if version is None:
-                    return False
-                versions.append((catalog, tname, version))
-            key = (fingerprint, tuple(sorted(set(versions))),
-                   trace_session_key(sess))
-            entry = self.cache.lookup(key)
-            if entry is None:
-                return False
-            rows = entry.json_rows
-            if rows is None:
-                from presto_tpu.server.results import (compact_table,
-                                                       json_rows)
-                cols, total = compact_table(entry.table)
-                rows = json_rows(cols, total)
-                entry.json_rows = rows  # atomic publish; idempotent
-            q.columns = list(entry.columns)
-            q.rows = rows
-            q.cache_hit = True
-            return True
+        except Exception:  # noqa: BLE001 - full path reports it
+            return None
+        with self._lock:
+            if len(self._memo) >= _MEMO_MAX:
+                self._memo.clear()
+            self._memo[mkey] = memo
+        return memo
 
     def _scan_tables(self, plan) -> list[tuple]:
         """(catalog, table) per TableScan, duplicates preserved."""
@@ -397,8 +422,9 @@ class ServingLayer:
         # one key serves both rungs (dedup shares the cache's
         # versioned-tables soundness requirement); either toggle
         # alone still derives it
-        key = (self._cache_key(plan) if (use_cache or use_dedup)
-               else None)
+        with TRACER.span("serving-key"):
+            key = (self._cache_key(plan) if (use_cache or use_dedup)
+                   else None)
         if use_cache and key is not None:
             entry = self.cache.lookup(key)
             if entry is not None:
@@ -447,7 +473,8 @@ class ServingLayer:
             if leader:
                 flight = self._inflight[key] = _Inflight()
         if not leader:
-            self._await(q, flight.event)
+            with TRACER.span("dedup-wait"):
+                self._await(q, flight.event)
             if flight.table is not None:
                 q.deduped = True
                 _DEDUPED.inc()

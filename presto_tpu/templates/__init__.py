@@ -52,21 +52,17 @@ def shape_bucketing(session) -> bool:
         return False
 
 
-def bucket_scans(engine, scan_inputs: list) -> list:
-    """Apply pow2 shape bucketing when the session asks for it."""
+def bucket_scans(engine, scan_inputs: list,
+                 stats: dict | None = None) -> list:
+    """Apply pow2 shape bucketing when the session asks for it.
+    ``stats["padded_bytes"]`` grows by the bytes of every padded copy
+    made now (a pad found in the cache adds nothing)."""
     if not shape_bucketing(engine.session):
         return scan_inputs
-    return bucket_scan_inputs(engine, scan_inputs)
+    return bucket_scan_inputs(engine, scan_inputs, stats)
 
 
 def note_lookup(hit: bool, params: int) -> None:
-    """Record one templated program-cache lookup (+ a template-hit
-    span in the active query trace)."""
+    """Record one templated program-cache lookup."""
     _TPL_PARAMS.set(params)
-    if hit:
-        _TPL_HITS.inc()
-        from presto_tpu.obs.trace import TRACER
-        with TRACER.span("template-hit", params=params):
-            pass
-    else:
-        _TPL_MISSES.inc()
+    (_TPL_HITS if hit else _TPL_MISSES).inc()

@@ -20,6 +20,7 @@ its HBM hit rate; per-execution temporaries pad without caching.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 
 import numpy as np
@@ -45,17 +46,23 @@ def invalidate_pad_cache(engine) -> None:
             del _PAD_CACHE[key]
 
 
-def _pad_rows(a: np.ndarray, cap: int) -> np.ndarray:
-    return np.pad(a, [(0, cap - a.shape[0])] + [(0, 0)] * (a.ndim - 1))
+def _pad_rows(a: np.ndarray, cap: int,
+              stats: dict | None = None) -> np.ndarray:
+    padded = np.pad(a, [(0, cap - a.shape[0])] + [(0, 0)] * (a.ndim - 1))
+    if stats is not None:
+        stats["padded_bytes"] = (stats.get("padded_bytes", 0)
+                                 + padded.nbytes)
+    return padded
 
 
-def _cached_pad(engine, a: np.ndarray, cap: int) -> np.ndarray:
+def _cached_pad(engine, a: np.ndarray, cap: int,
+                stats: dict | None = None) -> np.ndarray:
     key = (id(engine), id(a), cap)
     with _PAD_LOCK:
         hit = _PAD_CACHE.get(key)
         if hit is not None and hit[0] is a:
             return hit[1]
-    padded = _pad_rows(a, cap)
+    padded = _pad_rows(a, cap, stats)
     with _PAD_LOCK:
         if len(_PAD_CACHE) >= _PAD_CACHE_MAX_ARRAYS:
             _PAD_CACHE.clear()
@@ -63,12 +70,14 @@ def _cached_pad(engine, a: np.ndarray, cap: int) -> np.ndarray:
     return padded
 
 
-def bucket_scan_inputs(engine, scan_inputs: list) -> list:
+def bucket_scan_inputs(engine, scan_inputs: list,
+                       stats: dict | None = None) -> list:
     """ScanInputs with every host (numpy) scan padded to a pow2 row
     bucket, dead pad rows masked via ``__live__``. Device-resident
     inputs (segment carriers — already pow2-compacted by
     device_outputs) and empty or already-bucketed scans pass through
-    untouched."""
+    untouched. ``stats["padded_bytes"]`` counts the bytes of the
+    copies made by this call (cached pads add nothing)."""
     out = []
     for scan in scan_inputs:
         arrays = scan.arrays
@@ -82,17 +91,16 @@ def bucket_scan_inputs(engine, scan_inputs: list) -> list:
         if cap <= n:
             out.append(scan)
             continue
-        cached = bool(getattr(scan, "cache_device", False))
+        pad = (functools.partial(_cached_pad, engine)
+               if getattr(scan, "cache_device", False) else _pad_rows)
         padded: dict = {}
         for sym, a in arrays.items():
             if sym == "__live__":
                 continue
-            padded[sym] = (_cached_pad(engine, a, cap) if cached
-                           else _pad_rows(a, cap))
+            padded[sym] = pad(a, cap, stats)
         base_live = arrays.get("__live__")
         if base_live is not None:
-            live = (_cached_pad(engine, np.asarray(base_live), cap)
-                    if cached else _pad_rows(np.asarray(base_live), cap))
+            live = pad(np.asarray(base_live), cap, stats)
         else:
             live = _live_mask(n, cap)
         padded["__live__"] = live
