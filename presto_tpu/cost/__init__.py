@@ -45,12 +45,13 @@ def _fmt(v: float) -> str:
     return f"{v:.0f}"
 
 
-def explain_estimates(plan, engine) -> dict[int, str]:
+def explain_estimates(plan, engine, nshards: int) -> dict[int, str]:
     """id(node) -> 'Estimates: {...}' detail line for EXPLAIN output
-    (reference planprinter/PlanPrinter.formatEstimates). Never raises:
-    a node whose stats blow up is simply left unannotated."""
+    (reference planprinter/PlanPrinter.formatEstimates), priced for the
+    ``nshards`` devices the plan was optimized for. Never raises: a
+    node whose stats blow up is simply left unannotated."""
     stats = StatsCalculator(engine)
-    cost = CostCalculator()
+    cost = CostCalculator(nshards)
     out: dict[int, str] = {}
 
     def visit(node) -> None:

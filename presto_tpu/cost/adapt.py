@@ -27,8 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 
-from presto_tpu.cost.model import (DEFAULT_MESH_SHARDS,
-                                   decide_join_distribution)
+from presto_tpu.cost.model import decide_join_distribution
 from presto_tpu.cost.skew import decide_skew
 from presto_tpu.cost.stats import PlanNodeStatsEstimate, StatsCalculator
 from presto_tpu.ops.hash import next_pow2
@@ -83,7 +82,7 @@ def _has_partitioned_carrier(node: N.PlanNode,
 
 
 def reannotate(plan: N.PlanNode, engine, stats: OverlayStats,
-               exchange_sources: dict | None = None,
+               nshards: int, exchange_sources: dict | None = None,
                note=None) -> N.PlanNode:
     """Re-run the physical-choice annotations over a remainder plan
     with actuals substituted (the mid-flight twin of
@@ -145,7 +144,7 @@ def reannotate(plan: N.PlanNode, engine, stats: OverlayStats,
                 d = decide_skew(p_est, b_est, node.criteria,
                                 node.build_unique,
                                 join_type_inner=True,
-                                nshards=DEFAULT_MESH_SHARDS,
+                                nshards=nshards,
                                 hot_threshold=hot_threshold,
                                 max_salt=max_salt)
                 if d.active:

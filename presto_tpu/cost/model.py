@@ -33,10 +33,6 @@ from presto_tpu.plan import nodes as N
 # DetermineJoinDistributionType AUTOMATIC cutoff)
 DEFAULT_BROADCAST_ROWS = 1 << 20
 
-# mesh size assumed when pricing plans before a mesh exists (EXPLAIN,
-# plan-time reordering); the driver's standard test mesh
-DEFAULT_MESH_SHARDS = 8
-
 # widest direct-address table the executor will allocate (slots), and
 # the widest relative to the build side — a 16M-slot table for a
 # 100-row build wastes HBM for no probe savings (moved here from
@@ -129,22 +125,40 @@ class PlanCostEstimate:
 ZERO_COST = PlanCostEstimate()
 
 
+def join_kind(node: N.Join) -> str:
+    """The physical join exec/executor._r_join runs for ``node``:
+    "dense" (unique build, direct-address table: one scatter, one
+    gather), "lookup" (unique build, sorted/hashed lookup) or
+    "expanding" (co-sort of both sides plus the expansion;
+    exec/operators.apply_expand_join, which FULL always takes)."""
+    if not node.build_unique or node.join_type == N.JoinType.FULL:
+        return "expanding"
+    return "dense" if node.dense_key is not None else "lookup"
+
+
 class CostCalculator:
     """Local (non-cumulative) cost of each plan node, given a
-    StatsCalculator for its inputs. ``nshards`` is the mesh size the
-    network model assumes; plan-time consumers use the default."""
+    StatsCalculator for its inputs. ``nshards`` is the number of
+    devices the plan will execute on (the mesh's size, the HTTP tier's
+    live workers, 1 without a mesh): the network model prices exactly
+    that many, so on one chip nothing crosses a link."""
 
-    def __init__(self, nshards: int = DEFAULT_MESH_SHARDS,
+    def __init__(self, nshards: int,
                  broadcast_threshold: int | None = None):
         self.nshards = max(int(nshards), 1)
         self.broadcast_threshold = broadcast_threshold
 
     def join_cost(self, probe, build, out_rows: float,
                   build_types, probe_types,
-                  distribution: str = "automatic") -> PlanCostEstimate:
-        """Price one hash join from its side estimates: probe+build+
-        output row-ops, the build hash table resident in HBM, and the
-        distribution's ICI traffic."""
+                  distribution: str = "automatic",
+                  build_unique: bool = True) -> PlanCostEstimate:
+        """Price one join from its side estimates: the row-ops of the
+        physical join the executor will run, the build table resident
+        in HBM, and the distribution's ICI traffic. A unique build
+        (direct-address or lookup probe) is linear in probe+build+
+        output; an expanding join co-sorts both sides and binary-
+        searches once per output slot, priced in the Sort rule's
+        n*log2(n) units."""
         build_bytes = build.output_bytes(build_types)
         probe_bytes = probe.output_bytes(probe_types)
         dist = decide_join_distribution(
@@ -157,7 +171,12 @@ class CostCalculator:
             net = partitioned_net_bytes(probe_bytes, build_bytes,
                                         self.nshards)
             mem = build_bytes / self.nshards
-        cpu = probe.row_count + 2.0 * build.row_count + out_rows
+        if build_unique:
+            cpu = probe.row_count + 2.0 * build.row_count + out_rows
+        else:
+            both = max(probe.row_count + build.row_count, 2.0)
+            cpu = (both * math.log2(both)
+                   + out_rows * math.log2(max(probe.row_count, 2.0)))
         return PlanCostEstimate(cpu, mem, net)
 
     def cost(self, node: N.PlanNode, stats) -> PlanCostEstimate:
@@ -172,7 +191,8 @@ class CostCalculator:
             return self.join_cost(probe, build, est.row_count,
                                   node.right.output_types(),
                                   node.left.output_types(),
-                                  node.distribution)
+                                  node.distribution,
+                                  join_kind(node) != "expanding")
         if isinstance(node, N.MultiJoin):
             # fused star chain: each build priced like the binary join
             # it replaced (its own distribution), the probe estimate

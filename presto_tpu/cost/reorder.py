@@ -36,7 +36,7 @@ from __future__ import annotations
 
 import dataclasses
 
-from presto_tpu.cost.model import (CostCalculator, DEFAULT_MESH_SHARDS,
+from presto_tpu.cost.model import (CostCalculator,
                                    decide_join_distribution)
 from presto_tpu.cost.skew import decide_skew
 from presto_tpu.cost.stats import StatsCalculator
@@ -48,8 +48,9 @@ from presto_tpu.plan import nodes as N
 MAX_DP_RELATIONS = 8
 
 
-def reorder_joins(plan: N.PlanNode, engine) -> N.PlanNode:
-    """Entry point, wired into plan/optimizer.optimize."""
+def reorder_joins(plan: N.PlanNode, engine, nshards: int) -> N.PlanNode:
+    """Entry point, wired into plan/optimizer.optimize. ``nshards`` is
+    the number of devices the plan will execute on."""
     session = getattr(engine, "session", None)
     strategy = "AUTOMATIC"
     if session is not None:
@@ -57,7 +58,7 @@ def reorder_joins(plan: N.PlanNode, engine) -> N.PlanNode:
         strategy = str(raw or "AUTOMATIC").upper()
     if strategy == "NONE":
         return plan
-    ctx = _Ctx(engine, strategy)
+    ctx = _Ctx(engine, strategy, nshards)
     return ctx.walk(plan)
 
 
@@ -71,9 +72,10 @@ def _is_region_join(node: N.PlanNode) -> bool:
 
 
 class _Ctx:
-    def __init__(self, engine, strategy: str):
+    def __init__(self, engine, strategy: str, nshards: int):
         self.engine = engine
         self.strategy = strategy
+        self.nshards = nshards
         self.stats = StatsCalculator(engine)
         session = getattr(engine, "session", None)
         self.mode = "automatic"
@@ -89,7 +91,7 @@ class _Ctx:
                 "skew_hot_key_threshold") or 0)
             self.max_salt = int(session.get("join_salting") or 0)
         self.cost = CostCalculator(
-            broadcast_threshold=self.threshold)
+            nshards, broadcast_threshold=self.threshold)
 
     def _skewed(self, dist: str, probe_est, build_est, criteria,
                 build_unique: bool) -> tuple[str, int | None, int | None]:
@@ -100,7 +102,7 @@ class _Ctx:
             return dist, None, None
         d = decide_skew(probe_est, build_est, criteria, build_unique,
                         join_type_inner=True,
-                        nshards=DEFAULT_MESH_SHARDS,
+                        nshards=self.nshards,
                         hot_threshold=self.hot_threshold,
                         max_salt=self.max_salt)
         if not d.active:
@@ -222,7 +224,7 @@ class _Ctx:
         local = self.cost.join_cost(
             self.stats.stats(probe_node), self.stats.stats(build_node),
             est.row_count, build_node.output_types(),
-            probe_node.output_types(), eff_dist)
+            probe_node.output_types(), eff_dist, join.build_unique)
         return join, probe_cost + build_cost + local.scalar()
 
     # -- enumeration ---------------------------------------------------------

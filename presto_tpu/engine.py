@@ -219,7 +219,7 @@ class Engine:
                     stmt = rewrite_statement(parse_statement(sql), self)
             if not isinstance(stmt, A.QueryStatement):
                 raise ValueError("execute_table expects a SELECT query")
-            preplanned = self.take_preplanned(sql)
+            preplanned = self.take_preplanned(sql, _mesh_shards(mesh))
             with self._cancel_scope(cancel_token):
                 return monitored(
                     self, sql,
@@ -253,7 +253,10 @@ class Engine:
 
         return scope()
 
-    def plan_sql(self, sql: str, enable_latemat: bool | None = None):
+    def plan_sql(self, sql: str, enable_latemat: bool | None = None,
+                 nshards: int = 1):
+        """Parse, analyze, plan and optimize ``sql`` for execution on
+        ``nshards`` devices (1 = this process's one chip, no mesh)."""
         from presto_tpu.sql.parser import parse_statement
         from presto_tpu.sql.analyzer import Analyzer
         from presto_tpu.plan.planner import LogicalPlanner
@@ -262,30 +265,35 @@ class Engine:
         import time as _time
 
         t0 = _time.monotonic()
-        with TRACER.span("plan"):
+        with TRACER.span("plan") as span:
             stmt = parse_statement(sql)
             analysis = Analyzer(self).analyze(stmt)
             self._planning_checkpoint(t0)
             plan = LogicalPlanner(self, analysis).plan(stmt)
             self._planning_checkpoint(t0)
-            plan = optimize(plan, self, enable_latemat=enable_latemat)
+            plan = optimize(plan, self, nshards,
+                            enable_latemat=enable_latemat, span=span)
             self._planning_checkpoint(t0)
         return plan, analysis
 
-    def offer_preplanned(self, sql: str, plan) -> None:
+    def offer_preplanned(self, sql: str, plan, nshards: int = 1) -> None:
         """Hand a just-built plan for ``sql`` to THIS THREAD's next
         execution of the same statement (the admission layer plans to
         size its reservation; replanning identical SQL under the same
         session on the same thread would double the planning cost).
-        One-shot: consumed by the next take_preplanned, and cleared by
+        ``nshards`` is the shard count the plan was priced for. One-
+        shot: consumed by the next take_preplanned, and cleared by
         clear_preplanned when the offering scope exits."""
-        self._preplanned_tl.value = (sql, plan)
+        self._preplanned_tl.value = (sql, plan, nshards)
 
-    def take_preplanned(self, sql: str):
-        """Consume the thread's offered plan if it matches ``sql``."""
+    def take_preplanned(self, sql: str, nshards: int = 1):
+        """Consume the thread's offered plan if it matches ``sql`` and
+        was priced for the ``nshards`` devices it is about to run on
+        (a plan priced for one chip never reaches a mesh)."""
         offered = getattr(self._preplanned_tl, "value", None)
         self._preplanned_tl.value = None
-        if offered is not None and offered[0] == sql:
+        if offered is not None and offered[0] == sql \
+                and offered[2] == nshards:
             return offered[1]
         return None
 
@@ -319,11 +327,11 @@ class Engine:
         from presto_tpu.plan.printer import format_plan
         plan, _ = self.plan_sql(sql)
         return format_plan(plan,
-                           estimates=explain_estimates(plan, self))
+                           estimates=explain_estimates(plan, self, 1))
 
     # -- internals ----------------------------------------------------------
 
-    def _plan_query(self, query, preplanned=None):
+    def _plan_query(self, query, preplanned=None, nshards: int = 1):
         from presto_tpu.plan.optimizer import optimize
         from presto_tpu.plan.planner import LogicalPlanner
         from presto_tpu.sql import ast as A
@@ -339,11 +347,11 @@ class Engine:
             validate_plan(preplanned)
             return preplanned
         t0 = _time.monotonic()
-        with TRACER.span("plan"):
+        with TRACER.span("plan") as span:
             planner = LogicalPlanner(self, None)
             plan = planner.plan(A.QueryStatement(query))
             self._planning_checkpoint(t0)
-            plan = optimize(plan, self)
+            plan = optimize(plan, self, nshards, span=span)
             self._planning_checkpoint(t0)
             # invariant validation before execution (reference
             # PlanSanityChecker runs after every optimizer stage)
@@ -352,7 +360,8 @@ class Engine:
 
     def _execute_query(self, query, mesh=None, preplanned=None) -> Table:
         self.last_spill = None
-        plan = self._plan_query(query, preplanned=preplanned)
+        plan = self._plan_query(query, preplanned=preplanned,
+                                nshards=_mesh_shards(mesh))
         if mesh is not None:
             from presto_tpu.parallel.executor import (
                 execute_plan_distributed)
@@ -396,7 +405,8 @@ class Engine:
                 inner = stmt.statement
                 if not isinstance(inner, A.QueryStatement):
                     raise ValueError("EXPLAIN ANALYZE expects a query")
-                plan = self._plan_query(inner.query)
+                plan = self._plan_query(inner.query,
+                                        nshards=_mesh_shards(mesh))
                 if mesh is not None:
                     return [(explain_analyze_distributed(
                         self, plan, mesh),)]
@@ -404,9 +414,11 @@ class Engine:
             inner = stmt.statement
             if isinstance(inner, A.QueryStatement):
                 from presto_tpu.cost import explain_estimates
-                plan = self._plan_query(inner.query)
+                nshards = _mesh_shards(mesh)
+                plan = self._plan_query(inner.query, nshards=nshards)
                 return [(format_plan(
-                    plan, estimates=explain_estimates(plan, self)),)]
+                    plan, estimates=explain_estimates(
+                        plan, self, nshards)),)]
             raise ValueError("EXPLAIN of non-query statements unsupported")
 
         if isinstance(stmt, A.StartTransaction):
@@ -567,6 +579,12 @@ class Engine:
         if len(parts) == 1:
             return self.session.catalog, parts[0]
         return parts[0], parts[-1]
+
+
+def _mesh_shards(mesh) -> int:
+    """Devices a statement executes on: the mesh it was handed, or the
+    process's one chip without one."""
+    return 1 if mesh is None else int(mesh.devices.size)
 
 
 def _literal_value(e):

@@ -236,7 +236,8 @@ class AdaptiveController:
         # markers claiming strategy flips that never took effect
         buffered: list[tuple] = []
         remainder = reannotate(
-            remainder, self.engine, stats, exchange_sources=sources,
+            remainder, self.engine, stats, self.nworkers,
+            exchange_sources=sources,
             note=lambda *args: buffered.append(args))
         remainder = refuse_multiway(remainder, self.engine)
         if not buffered:

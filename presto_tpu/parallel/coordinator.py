@@ -328,6 +328,11 @@ class ClusterCoordinator:
     def live_workers(self) -> list[RemoteWorker]:
         return [w for w in self.workers if w.schedulable]
 
+    def plan_shards(self) -> int:
+        """Shard count the cost model prices a statement for: the live
+        workers its fragments would run on (1 when it runs here)."""
+        return max(len(self.live_workers()), 1)
+
     # -- session-configured fault-tolerance knobs (ft/retry.py) ----------
 
     def _retry_policy(self) -> str:
@@ -411,10 +416,12 @@ class ClusterCoordinator:
         # plan with late materialization off: its rewritten shape
         # (dimension re-join above the aggregate) is a single-chip
         # width optimization the fragmenter cannot stage
-        plan = self.engine.take_preplanned(sql)
-        if plan is None:
-            plan, _ = self.engine.plan_sql(sql, enable_latemat=False)
         workers = self.live_workers()
+        nshards = max(len(workers), 1)
+        plan = self.engine.take_preplanned(sql, nshards)
+        if plan is None:
+            plan, _ = self.engine.plan_sql(sql, enable_latemat=False,
+                                           nshards=nshards)
         require = bool(self.engine.session.get("require_distribution"))
         allow_fb = bool(self.engine.session.get("allow_local_fallback"))
 

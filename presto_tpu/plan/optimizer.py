@@ -18,11 +18,23 @@ from __future__ import annotations
 import dataclasses
 
 from presto_tpu.expr import ir
+from presto_tpu.obs.metrics import REGISTRY
 from presto_tpu.plan import nodes as N
 
+_JOINS_PLANNED = REGISTRY.counter(
+    "presto_tpu_joins_planned_total",
+    "Joins in optimized plans by the physical join the executor runs "
+    "(dense | lookup | expanding)")
 
-def optimize(plan: N.PlanNode, engine,
-             enable_latemat: bool | None = None) -> N.PlanNode:
+
+def optimize(plan: N.PlanNode, engine, nshards: int,
+             enable_latemat: bool | None = None,
+             span=None) -> N.PlanNode:
+    """Run the optimizer passes over a finished logical plan.
+    ``nshards`` is the number of devices the plan will execute on (the
+    mesh's size, the HTTP tier's live workers, 1 without a mesh): the
+    join enumerator prices exactly that many. ``span`` is the caller's
+    open ``plan`` span (or None), which gains what was planned for."""
     from presto_tpu.cost.reorder import reorder_joins
     from presto_tpu.plan.dense import annotate_dense
     from presto_tpu.plan.latemat import late_materialize
@@ -35,7 +47,7 @@ def optimize(plan: N.PlanNode, engine,
     # scan-filter pushdown so connector stats still see plain table
     # names, and before dense/latemat so their annotations apply to
     # the final join order
-    plan = reorder_joins(plan, engine)
+    plan = reorder_joins(plan, engine, nshards)
     # star-schema fusion over the reordered spine (session
     # multiway_join; AUTOMATIC reordering only — NONE means "leave
     # plans exactly as planned" and ELIMINATE_CROSS_JOINS promises the
@@ -57,7 +69,33 @@ def optimize(plan: N.PlanNode, engine,
         plan = prune_columns(lm)
         plan = inline_trivial_projects(plan)
         plan = annotate_dense(plan, engine)
+    kinds = joins_by_kind(plan)
+    for kind, n in kinds.items():
+        if n:
+            _JOINS_PLANNED.inc(n, kind=kind)
+    if span is not None:
+        span.attrs["nshards"] = nshards
+        span.attrs["joins"] = ",".join(
+            f"{kind}:{n}" for kind, n in kinds.items())
     return plan
+
+
+def joins_by_kind(plan: N.PlanNode) -> dict[str, int]:
+    """Joins of a finished plan by the physical join the executor will
+    run (cost/model.join_kind); a MultiJoin leg is a sorted lookup."""
+    from presto_tpu.cost.model import join_kind
+    kinds = {"dense": 0, "lookup": 0, "expanding": 0}
+
+    def visit(node: N.PlanNode) -> None:
+        if isinstance(node, N.Join):
+            kinds[join_kind(node)] += 1
+        elif isinstance(node, N.MultiJoin):
+            kinds["lookup"] += len(node.builds)
+        for s in node.sources():
+            visit(s)
+
+    visit(plan)
+    return kinds
 
 
 # ---------------------------------------------------------------------------

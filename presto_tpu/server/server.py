@@ -586,9 +586,11 @@ class QueryManager:
             # its planning pass; the handoff stays thread-local and is
             # consumed under the SAME session scope on this thread
             if self.cluster is not None:
-                plan, _ = self.engine.plan_sql(sql,
-                                               enable_latemat=False)
+                nshards = self.cluster.plan_shards()
+                plan, _ = self.engine.plan_sql(
+                    sql, enable_latemat=False, nshards=nshards)
             else:
+                nshards = 1  # no mesh: this process's one chip
                 plan, _ = self.engine.plan_sql(sql)
             est, _per_node = estimate_plan_memory(plan, self.engine)
         charge = max(int(est), 1)
@@ -600,7 +602,7 @@ class QueryManager:
                 kill_after_s=self.limit_of(
                     q, "low_memory_killer_delay_s"),
                 owner=q.cancel_token)
-        self.engine.offer_preplanned(sql, plan)
+        self.engine.offer_preplanned(sql, plan, nshards)
         try:
             yield
         finally:

@@ -369,7 +369,7 @@ def test_reannotate_rewrites_only_material_changes(tpch_tiny):
     e.session.set("broadcast_join_threshold_rows", 64)
     try:
         out = reannotate(
-            poisoned, e, stats,
+            poisoned, e, stats, 8,
             note=lambda kind, node, est, actual, old, new:
             notes.append((kind, old, new)))
     finally:
@@ -381,7 +381,7 @@ def test_reannotate_rewrites_only_material_changes(tpch_tiny):
     # a <4x wobble is NOT material: the node (and its cache-keyed
     # annotations) must come back untouched
     stats2 = OverlayStats(e, {"side1": CarrierStats(20)})
-    out2 = reannotate(poisoned, e, stats2, note=None)
+    out2 = reannotate(poisoned, e, stats2, 8, note=None)
     assert out2.build_rows == 16 and out2.capacity == 32
 
 

@@ -74,7 +74,8 @@ def test_q9_all_joins_unique_build(tpch_tiny):
 
 def test_flipped_stats_change_join_order():
     """The ordering is driven by stats, not table names: shrinking one
-    side's row counts flips which leg becomes the fact table."""
+    side's row counts flips which leg becomes the fact table (priced
+    for an 8-shard mesh, where the big side must not replicate)."""
     import numpy as np
     from presto_tpu.connectors.memory import MemoryConnector
     from presto_tpu import types as T
@@ -92,7 +93,7 @@ def test_flipped_stats_change_join_order():
         eng.register_catalog("mem", mem)
         eng.session.catalog = "mem"
         plan, _ = eng.plan_sql(
-            "select count(*) from a, b where a_id = b_id")
+            "select count(*) from a, b where a_id = b_id", nshards=8)
         return _joins(plan)[0]
 
     j_big_left = build(True)

@@ -166,12 +166,16 @@ def test_dp_smallest_build_side_innermost():
 
 
 def test_probe_side_is_larger_relation():
-    """Two-way join: the DP must keep the big side as probe (left)
-    whichever order stats imply (the test_cost.py flipped-stats
-    property, re-checked through the cost pass)."""
+    """Two-way join priced for an 8-shard mesh: the DP must keep the
+    big side as probe (left) whichever order stats imply (the
+    test_cost.py flipped-stats property, re-checked through the cost
+    pass) — replicating it to seven peers is what the network term
+    refuses. (The memory connector proves no key unique, so the join
+    expands; on one chip the co-sort is symmetric and the expansion
+    binary-searches the probe side, so there the small side probes.)"""
     eng = _chain_engine(50_000, 100, 10)
     plan, _ = eng.plan_sql(
-        "select count(*) from mid, big where b_id = m_id")
+        "select count(*) from mid, big where b_id = m_id", nshards=8)
     j = _joins(plan)[0]
     assert any(s.startswith("b_") for s in j.left.output_types())
 

@@ -136,7 +136,7 @@ def test_multijoin_distributed_zipf(tpch_zipf, zipf_oracle, mesh):
     sql = QUERIES["q05"]
     eng = make_engine(tpch_zipf)
     got = eng.execute(sql, mesh=mesh)
-    assert _nodes(eng.plan_sql(sql)[0], N.MultiJoin)
+    assert _nodes(eng.plan_sql(sql, nshards=8)[0], N.MultiJoin)
     want = zipf_oracle.query(to_sqlite(parse_statement(sql)))
     ok, msg = rows_equal(got, want, ordered=True)
     assert ok, msg
@@ -152,7 +152,7 @@ def test_hybrid_planned_and_oracle_zipf(tpch_zipf, zipf_oracle, mesh):
     exists for: hot keys broadcast, cold tail partitions)."""
     eng = make_engine(tpch_zipf, multiway_join=False, **SKEW_PROPS)
     sql = QUERIES["q03"]
-    plan, _ = eng.plan_sql(sql)
+    plan, _ = eng.plan_sql(sql, nshards=8)
     dists = [j.distribution for j in _nodes(plan, N.Join)]
     assert "hybrid" in dists, dists
     got = eng.execute(sql, mesh=mesh)
@@ -170,7 +170,7 @@ def test_hybrid_empty_hot_key_set(tpch_tiny, mesh):
                       broadcast_join_threshold_rows=64,
                       skew_hot_key_threshold=256)
     sql = QUERIES["q03"]
-    plan, _ = eng.plan_sql(sql)
+    plan, _ = eng.plan_sql(sql, nshards=8)
     assert "hybrid" in [j.distribution
                         for j in _nodes(plan, N.Join)]
     got = eng.execute(sql, mesh=mesh)
@@ -213,7 +213,7 @@ def test_salted_unique_join(tpch_zipf, mesh):
     unchanged."""
     eng = make_engine(tpch_zipf, multiway_join=False,
                       skew_hot_key_threshold=0)
-    plan, _ = eng.plan_sql(QUERIES["q03"])
+    plan, _ = eng.plan_sql(QUERIES["q03"], nshards=8)
     t = execute_plan_distributed(eng, _force_salt(plan, 4), mesh)
     got = [tuple(r) for r in t.to_pylist()]
     want = make_engine(tpch_zipf).execute(QUERIES["q03"])
@@ -246,7 +246,7 @@ def test_salted_expanding_join(mesh):
     eng.session.catalog = "mem"
     sql = ("select k, count(*) as c, sum(w) as s "
            "from f join d on f.k = d.dk group by k order by k")
-    plan, _ = eng.plan_sql(sql)
+    plan, _ = eng.plan_sql(sql, nshards=8)
     joins = _nodes(plan, N.Join)
     assert joins and not all(j.build_unique for j in joins)
     t = execute_plan_distributed(eng, _force_salt(plan, 4), mesh)
