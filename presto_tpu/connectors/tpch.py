@@ -14,6 +14,8 @@ presto_tpu.types.DecimalType.
 
 from __future__ import annotations
 
+from collections.abc import Sequence
+
 import numpy as np
 
 from presto_tpu import types as T
@@ -447,13 +449,20 @@ class TpchGenerator:
 class TpchConnector(Connector):
     """Catalog `tpch` with one schema per scale factor (tiny = 0.01).
     ``skew`` = None (spec-uniform) or "zipf:<s>" / float s — Zipf-skew
-    the FK columns (see TpchGenerator)."""
+    the FK columns (see TpchGenerator). ``tables`` = None (all eight)
+    or the tables this deployment holds: a statement over another is
+    refused as one over an unknown table."""
 
     name = "tpch"
 
     def __init__(self, scale: float = 0.01, seed: int = 19920101,
-                 skew: str | float | None = None):
+                 skew: str | float | None = None,
+                 tables: Sequence[str] | None = None):
         self.scale = scale
+        unknown = sorted(set(tables or ()) - set(SCHEMAS))
+        if unknown:
+            raise ValueError(f"no TPC-H table(s) {unknown}")
+        self._names = [n for n in SCHEMAS if tables is None or n in tables]
         zipf = None
         if isinstance(skew, str) and skew:
             kind, _, arg = skew.partition(":")
@@ -467,7 +476,7 @@ class TpchConnector(Connector):
         self._tables: dict[str, Table] = {}
 
     def table_names(self) -> list[str]:
-        return list(SCHEMAS.keys())
+        return list(self._names)
 
     def table_schema(self, name: str):
         return SCHEMAS[name]

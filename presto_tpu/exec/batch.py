@@ -174,7 +174,7 @@ def _run_batched(engine, templates: list, k: int, plan, n_params: int):
     # example args (placeholder string codes) carry the exact shapes
     # and dtypes the real bind will, so lowering on them is sound
     example = _stack_params(
-        _pad([t.example_args() for t in templates], kp))
+        _pad([t.example_args(scan_inputs) for t in templates], kp))
 
     for _attempt in range(_MAX_ATTEMPTS):
         checkpoint()
@@ -187,7 +187,7 @@ def _run_batched(engine, templates: list, k: int, plan, n_params: int):
         if entry is None:
             traced_fn, _host_arrays, meta = make_traced(
                 scan_inputs, plan, capacities, engine.session,
-                params=templates[0].example_args())
+                params=templates[0].example_args(scan_inputs))
             # scans broadcast (uploaded once), parameters map: the
             # whole operator chain vectorizes over the query axis
             batched_fn = jax.vmap(

@@ -719,7 +719,8 @@ def prepare_plan(engine, plan: N.PlanNode, scan_inputs: list[ScanInput]):
             engine.device_array(scan.arrays[sym])
             if getattr(scan, "cache_device", False) else scan.arrays[sym]
             for scan in scan_inputs for sym in scan.arrays]
-        pargs = tpl.example_args() if tpl is not None else []
+        pargs = (tpl.example_args(scan_inputs)
+                 if tpl is not None and entry is None else [])
         if entry is None:
             traced_fn, _host_arrays, meta = make_traced(
                 scan_inputs, plan, capacities, engine.session,
@@ -756,7 +757,8 @@ def prepare_plan(engine, plan: N.PlanNode, scan_inputs: list[ScanInput]):
         if tpl is not None:
             # bind THIS query's literal values (string parameters
             # resolve through the dictionaries the trace recorded —
-            # carried in meta, so disk-tier hits bind too)
+            # carried in meta, so disk-tier hits bind too; a LIKE
+            # pattern runs over its dictionary here: span dict-mask)
             pargs = tpl.bind(meta.get("param_bindings"))
         _t1 = time.perf_counter()
         with TRACER.span("execute", cache_hit=cache_hit):
@@ -955,7 +957,8 @@ def _compact_kernel(live, data, cap: int):
 _compact_jit = jax.jit(_compact_kernel, static_argnames=("cap",))
 
 
-def device_outputs(meta, res, live, cap_floor: int | None = None):
+def device_outputs(meta, res, live, cap_floor: int | None = None,
+                   stats: dict | None = None):
     """Unpack one program's (meta, res, live) into segment-carrier form
     (arrays incl. $valid/__live__, dicts, types, n). Outputs compact to
     pow2(live count) when that at least halves the buffer, so later
@@ -973,7 +976,11 @@ def device_outputs(meta, res, live, cap_floor: int | None = None):
     when the count overflows the memory does it grow, with a 2x
     margin (the RETRY_GROWTH idea applied to widths) so nearby
     variants land in one bucket and outliers converge after a single
-    recompile."""
+    recompile.
+
+    ``stats`` (the ``segment`` span's attributes) gains ``width``, the
+    rows of the buffer handed over, and ``live_rows``, how many of
+    them are live."""
     arrays: dict = {}
     dicts: dict = {}
     types: dict = {}
@@ -1003,18 +1010,21 @@ def device_outputs(meta, res, live, cap_floor: int | None = None):
         arrays, live = _compact_jit(live, arrays, cap=cap)
         n = cap
     arrays["__live__"] = live
+    if stats is not None:
+        stats.update(width=n, live_rows=cnt)
     return arrays, dicts, types, n
 
 
 def run_plan_device(engine, plan: N.PlanNode,
                     scan_inputs: list["ScanInput"],
-                    cap_floor: int | None = None):
+                    cap_floor: int | None = None,
+                    stats: dict | None = None):
     """Like run_plan but keeps results as DEVICE arrays (segment
     handoff); see device_outputs. Returns (arrays, dicts, types, n,
     per-node rows=None) — the runner contract of _segment_carriers."""
     _c, _f, meta, (res, live, _oks, _counts) = prepare_plan(
         engine, plan, scan_inputs)
-    return device_outputs(meta, res, live, cap_floor) + (None,)
+    return device_outputs(meta, res, live, cap_floor, stats) + (None,)
 
 
 def _pool_wait(engine) -> tuple[float, float]:
@@ -1053,8 +1063,8 @@ def _segment_carriers(engine, plan: N.PlanNode, pool_tag: str,
     split that scans a same-wave carrier closes the wave — dependency
     order between waves is preserved exactly as the old serial loop.
 
-    ``runner(engine, mat, scans, cap_floor=None) -> (arrays, dicts,
-    types, n, node_rows)`` substitutes the per-segment executor
+    ``runner(engine, mat, scans, cap_floor=None, stats=None) ->
+    (arrays, dicts, types, n, node_rows)`` substitutes the per-segment executor
     (EXPLAIN ANALYZE passes a profiling runner); ``observer(seg, mat,
     arrays, n, wall_s, node_rows)`` fires per materialized segment, in
     segment order.
@@ -1139,10 +1149,11 @@ def _segment_carriers(engine, plan: N.PlanNode, pool_tag: str,
             _t0 = time.perf_counter()
             with TRACER.attach(_ctx), \
                     TRACER.span("segment", index=seg + idx,
-                                wave_width=len(wave)):
+                                wave_width=len(wave)) as span:
                 floor = (carrier_caps.get((tfp, seg + idx), 0)
                          if tpl_mode else None)
-                out = run(engine, mat, scans, cap_floor=floor)
+                out = run(engine, mat, scans, cap_floor=floor,
+                          stats=None if span is None else span.attrs)
             if pool is not None:
                 # reserve inside the job, as the serial loop did: an
                 # over-budget pipeline must raise MemoryLimitExceeded

@@ -669,43 +669,51 @@ def apply_multi_join(spine: DTable, builds: list[DTable],
         spine.cols, spine.live_mask(), spine.n,
         [(b.cols, b.live_mask(), b.n) for b in builds],
         node.criteria, growth)
+    # one scope per build under the node's own (MultiJoin#n/build<k>,
+    # in plan order), so a device trace splits the probes
     if fused is not None:
         gathers, live, ok = fused
         out = dict(spine.cols)
-        for bdt, gather in zip(builds, gathers):
-            for sym, v in bdt.cols.items():
-                out[sym] = Val(
-                    v.dtype, v.data[gather],
-                    None if v.valid is None else v.valid[gather],
-                    v.dictionary)
+        for k, (bdt, gather) in enumerate(zip(builds, gathers)):
+            with jax.named_scope(f"build{k}"):
+                for sym, v in bdt.cols.items():
+                    out[sym] = Val(
+                        v.dtype, v.data[gather],
+                        None if v.valid is None else v.valid[gather],
+                        v.dictionary)
         return DTable(out, live, spine.n), ok
     K.note("xla:multijoin")
     out = dict(spine.cols)
     live = spine.live_mask()
     width = spine.n
-    for bdt, crit in zip(builds, node.criteria):
-        lkeys = [lk for lk, _ in crit]
-        rkeys = [rk for _, rk in crit]
-        acc = DTable(out, live, width)
-        build_live = _and_key_valid(bdt, rkeys, bdt.live_mask())
-        probe_live = _and_key_valid(acc, lkeys, live)
-        rh = _row_hash(bdt, rkeys)
-        _bsh, bsidx = H.sort_build_side(rh, build_live)
-        ph = _row_hash(acc, lkeys)
-        lo, count, found = H.probe_runs(rh, build_live, ph, probe_live)
-        build_row = jnp.where(
-            found, bsidx[jnp.clip(lo + count - 1, 0, bdt.n - 1)], -1)
-        gather = jnp.clip(build_row, 0, bdt.n - 1)
-        verify = _verify_keys(acc, bdt, crit, None, gather)
-        if verify is not True:
-            found = found & verify
-        for sym, v in bdt.cols.items():
-            # INNER: unmatched rows die via the live mask, so the found
-            # mask is redundant as per-column validity (see apply_join)
-            out[sym] = Val(v.dtype, v.data[gather],
-                           None if v.valid is None else v.valid[gather],
-                           v.dictionary)
-        live = probe_live & found
+    for k, (bdt, crit) in enumerate(zip(builds, node.criteria)):
+        with jax.named_scope(f"build{k}"):
+            lkeys = [lk for lk, _ in crit]
+            rkeys = [rk for _, rk in crit]
+            acc = DTable(out, live, width)
+            build_live = _and_key_valid(bdt, rkeys, bdt.live_mask())
+            probe_live = _and_key_valid(acc, lkeys, live)
+            rh = _row_hash(bdt, rkeys)
+            _bsh, bsidx = H.sort_build_side(rh, build_live)
+            ph = _row_hash(acc, lkeys)
+            lo, count, found = H.probe_runs(rh, build_live, ph,
+                                            probe_live)
+            build_row = jnp.where(
+                found, bsidx[jnp.clip(lo + count - 1, 0, bdt.n - 1)],
+                -1)
+            gather = jnp.clip(build_row, 0, bdt.n - 1)
+            verify = _verify_keys(acc, bdt, crit, None, gather)
+            if verify is not True:
+                found = found & verify
+            for sym, v in bdt.cols.items():
+                # INNER: unmatched rows die via the live mask, so the
+                # found mask is redundant as per-column validity (see
+                # apply_join)
+                out[sym] = Val(
+                    v.dtype, v.data[gather],
+                    None if v.valid is None else v.valid[gather],
+                    v.dictionary)
+            live = probe_live & found
     return DTable(out, live, width), jnp.asarray(True)
 
 

@@ -202,6 +202,16 @@ def _like_regex(pattern: str, escape: str | None = None) -> re.Pattern:
     return re.compile("".join(out), re.DOTALL)
 
 
+def like_mask(dictionary, pattern: str,
+              escape: str | None = None) -> np.ndarray:
+    """LIKE over every entry of a dictionary, on the host: the boolean
+    table the codes index on the device. One match an entry."""
+    match = _like_regex(pattern, escape).fullmatch
+    entries = np.asarray(dictionary).tolist()
+    return np.fromiter((match(str(s)) is not None for s in entries),
+                       np.bool_, len(entries))
+
+
 def _align_strings(a: Val, b: Val) -> tuple[object, object]:
     """Return comparable code arrays for two string Vals.
 
@@ -878,12 +888,25 @@ def _not(e, args):
 
 @scalar("like")
 def _like(e, args):
+    from presto_tpu.templates.runtime import (ParamDictionary,
+                                              TemplateError, mask_length)
     col, pat = args[0], args[1]
+    if isinstance(pat.dictionary, ParamDictionary):
+        # hoisted pattern (templates/analysis.LikePattern): the traced
+        # value is the pattern's mask over col's dictionary, bound on
+        # the host for every execution against the dictionary recorded
+        # here, so every pattern shares this program
+        pat.dictionary.bind(col.dictionary)
+        if pat.data.shape[-1] != mask_length(col.dictionary):
+            raise TemplateError(
+                f"LIKE mask of {pat.data.shape[-1]} entries over a "
+                f"dictionary of {len(col.dictionary)}")
+        return _bool(pat.data[col.data], col.valid)
+    # a plan that was not templated (plan_templates off, EXPLAIN
+    # ANALYZE, the streamed scan) bakes every literal, this one too
     escape = str(args[2].dictionary[0]) if len(args) > 2 else None
-    pattern = str(pat.dictionary[0])
-    rx = _like_regex(pattern, escape)
-    return _dict_predicate(
-        col, lambda d: np.array([rx.fullmatch(s) is not None for s in d]))
+    mask = like_mask(col.dictionary, str(pat.dictionary[0]), escape)
+    return _bool(jnp.asarray(mask)[col.data], col.valid)
 
 
 @scalar("regexp_like")

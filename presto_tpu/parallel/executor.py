@@ -1029,7 +1029,8 @@ def execute_plan_distributed(engine, plan: N.PlanNode,
         if tpl is not None and _attempt == 0:
             TPL.note_lookup(hit=entry is not None,
                             params=len(tpl.params))
-        pargs = tpl.example_args() if tpl is not None else []
+        pargs = (tpl.example_args(scan_inputs)
+                 if tpl is not None else [])
         lowered = None
         cache_hit = entry is not None
         if entry is not None:

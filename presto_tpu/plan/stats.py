@@ -112,15 +112,62 @@ def selectivity_informed(expr: ir.Expr, ndv: dict,
     return informed(expr)
 
 
+def _one_sided(expr: ir.Expr, ranges):
+    """(column, "lo" | "hi", fraction) of a comparison of a column with
+    a literal that bounds the column from one side and that the ranges
+    can interpolate; else None."""
+    if not (isinstance(expr, ir.Call) and len(expr.args) == 2
+            and expr.fn in ("lt", "lte", "gt", "gte")):
+        return None
+    col, lit, swapped = _col_and_lit(expr.args)
+    if col is None:
+        return None
+    upper = (expr.fn in ("lt", "lte")) != swapped
+    f = _range_fraction(col.name, lit, "lt" if upper else "gt", ranges)
+    if f is None:
+        return None
+    return col.name, "hi" if upper else "lo", max(min(f, 1.0), 0.0)
+
+
+def _sel_and(expr: ir.Call, ndv, ranges) -> float:
+    """A conjunction: independent conjuncts multiply, but a lower and
+    an upper bound on the SAME column are one range, not two
+    independent events (``d >= a and d < b`` keeps (b - a) / span of
+    the rows, BETWEEN's rule). The product of the two one-sided
+    fractions is a parabola in the range's position, which gave
+    TPC-H Q5's five one-year ranges two different pow2 buckets of
+    estimated build rows, so two plan templates."""
+    conjuncts, stack = [], list(expr.args)
+    while stack:
+        e = stack.pop()
+        if isinstance(e, ir.Call) and e.fn == "and":
+            stack.extend(e.args)
+        else:
+            conjuncts.append(e)
+    out = 1.0
+    bounds: dict[str, dict[str, float]] = {}
+    for e in conjuncts:
+        side = _one_sided(e, ranges)
+        if side is None:
+            out *= _sel(e, ndv, ranges)
+        else:
+            col, which, f = side
+            seen = bounds.setdefault(col, {})
+            seen[which] = min(f, seen.get(which, 1.0))
+    for seen in bounds.values():
+        if len(seen) == 2:
+            out *= max(seen["lo"] + seen["hi"] - 1.0, 0.0)
+        else:
+            out *= next(iter(seen.values()))
+    return out
+
+
 def _sel(expr: ir.Expr, ndv, ranges) -> float:
     if not isinstance(expr, ir.Call):
         return UNKNOWN_FILTER_COEFFICIENT
     fn = expr.fn
     if fn == "and":
-        out = 1.0
-        for a in expr.args:
-            out *= _sel(a, ndv, ranges)
-        return out
+        return _sel_and(expr, ndv, ranges)
     if fn == "or":
         out = 0.0
         for a in expr.args:

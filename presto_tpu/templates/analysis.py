@@ -13,10 +13,19 @@ literal occurrence:
   eq/neq comparisons (hoisted as a dictionary code resolved at bind
   time, templates/runtime.py).
 
+* **Dictionary masks** — ``col LIKE <literal>`` (with or without
+  ``ESCAPE``; ``NOT LIKE`` is a ``not`` above it) where ``col`` is a
+  scanned, dictionary-coded column: the pattern becomes a parameter
+  whose value at execute time is its boolean mask over ``col``'s
+  dictionary, computed on the host at bind time and indexed by the
+  codes on the device (templates/runtime.py). TPC-H Q9's 92 colors
+  share one program.
+
 * **Structural** — everything else stays baked: literals the compiler
-  reads host-side at trace time (LIKE/regexp patterns, substring
-  bounds, date_trunc units — any scalar that reads ``e.args`` instead
-  of compiled values; drift-guarded by tests/test_templates.py),
+  reads host-side at trace time (regexp patterns, a LIKE over a
+  computed string or with a non-literal pattern, substring bounds,
+  date_trunc units — any scalar that reads ``e.args`` instead of
+  compiled values; drift-guarded by tests/test_templates.py),
   LIMIT/TopN counts (plan-node ints, hashed by the plan fingerprint),
   IN-list values (the list shapes the trace), CASE/CAST/lambda
   internals, NULL literals (validity shape), and decimal *types*
@@ -65,6 +74,18 @@ _HOISTABLE_VALUE_TYPES = (
 
 
 @dataclasses.dataclass(frozen=True)
+class LikePattern:
+    """The value of a hoisted LIKE pattern: bound at execute time to
+    the pattern's boolean mask over the dictionary of ``column`` (a
+    TableScan symbol), so the mask is an argument of the program and
+    not a constant in it."""
+
+    column: str
+    pattern: str
+    escape: str | None = None
+
+
+@dataclasses.dataclass(frozen=True)
 class ParamSpec:
     """One hoisted literal: its declared type and this query's value
     (the value rides OUTSIDE the template fingerprint)."""
@@ -84,11 +105,13 @@ class Template:
         from presto_tpu.plan.fingerprint import plan_fingerprint
         return plan_fingerprint(self.plan)
 
-    def example_args(self) -> list:
+    def example_args(self, scan_inputs=()) -> list:
         """Physical placeholder args for tracing (VARCHAR codes bind
-        for real only after the trace records their dictionaries)."""
-        from presto_tpu.templates.runtime import bind_values
-        return bind_values(self.params, {})
+        for real only after the trace records their dictionaries; a
+        LIKE pattern's mask takes its length from the scanned column's
+        dictionary in ``scan_inputs``)."""
+        from presto_tpu.templates.runtime import example_values
+        return example_values(self.params, scan_inputs)
 
     def bind(self, bindings: dict | None) -> list:
         """Physical args for one execution, string codes resolved
@@ -111,9 +134,40 @@ def _hoistable(lit: ir.Literal, call: ir.Call) -> bool:
     return call.fn in HOISTABLE_CALL_FNS
 
 
+def _scan_symbols(node: N.PlanNode, out: set[str]) -> set[str]:
+    if isinstance(node, N.TableScan):
+        out.update(node.assignments)
+    for s in node.sources():
+        _scan_symbols(s, out)
+    return out
+
+
+def _varchar_literal(e: ir.Expr) -> bool:
+    return (isinstance(e, ir.Literal) and e.value is not None
+            and isinstance(e.dtype, T.VarcharType))
+
+
 class _Rewriter:
-    def __init__(self):
+    def __init__(self, scan_symbols: set[str]):
         self.params: list[ParamSpec] = []
+        self.scan_symbols = scan_symbols
+
+    def _like(self, e: ir.Call) -> ir.Expr | None:
+        """``like(col, pattern[, escape])`` with the pattern hoisted,
+        or None where it stays structural: ``col`` has to be a scanned
+        column (its dictionary is then known before the trace, which
+        the mask's shape needs) and pattern and escape literals."""
+        col = e.args[0]
+        if not (isinstance(col, ir.ColumnRef)
+                and isinstance(col.dtype, T.VarcharType)
+                and col.name in self.scan_symbols
+                and all(_varchar_literal(a) for a in e.args[1:])):
+            return None
+        escape = e.args[2].value if len(e.args) > 2 else None
+        self.params.append(ParamSpec(T.VARCHAR, LikePattern(
+            col.name, e.args[1].value, escape)))
+        return ir.Call(e.dtype, "like", (col, ir.Parameter(
+            T.VARCHAR, len(self.params) - 1)))
 
     # -- expressions --------------------------------------------------------
 
@@ -126,6 +180,9 @@ class _Rewriter:
                 return ir.Parameter(e.dtype, len(self.params) - 1)
             return e
         if isinstance(e, ir.Call):
+            hoisted = self._like(e) if e.fn == "like" else None
+            if hoisted is not None:
+                return hoisted
             ctx = e if e.fn in HOISTABLE_CALL_FNS else None
             args = tuple(self.expr(a, ctx) for a in e.args)
             if args == e.args:
@@ -200,7 +257,7 @@ def parameterize(plan: N.PlanNode) -> Template | None:
     regions (MATCH_RECOGNIZE defines run outside the trace)."""
     if _has_match_recognize(plan):
         return None
-    rw = _Rewriter()
+    rw = _Rewriter(_scan_symbols(plan, set()))
     tplan = rw.node(plan)
     if not rw.params:
         return None

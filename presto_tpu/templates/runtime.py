@@ -20,6 +20,13 @@ row, exactly the baked-literal semantics). The bindings ride in the
 program-cache ``meta`` so disk-tier hits in a fresh process can still
 bind.
 
+A LIKE pattern over a scanned column (analysis.LikePattern) binds the
+same way: its traced value is a boolean mask over the column's
+dictionary, ``expr/compile._like`` records that dictionary, and
+:func:`bind_values` runs the pattern over it on the host (the
+``dict-mask`` span) for every execution; the codes index the mask on
+the device.
+
 State is strictly per-trace and confined to the tracing thread
 (``threading.local``): parallel segment compilation traces concurrent
 programs, each under its own installed context.
@@ -33,6 +40,8 @@ import threading
 import numpy as np
 
 from presto_tpu import types as T
+from presto_tpu.obs.trace import TRACER
+from presto_tpu.templates.analysis import LikePattern
 
 _TLS = threading.local()
 
@@ -50,7 +59,8 @@ class ParamDictionary:
     with the dictionary of the other side, recording where the
     parameter's runtime code must be resolved. Any other dictionary
     operation on a parameter is a bug: the analysis only hoists VARCHAR
-    literals into eq/neq comparisons."""
+    literals into eq/neq comparisons, and LIKE patterns (whose traced
+    value is a mask over the dictionary bound here)."""
 
     __slots__ = ("index", "_params")
 
@@ -64,7 +74,8 @@ class ParamDictionary:
     def __getattr__(self, name):  # astype/__len__/searchsorted/...
         raise TemplateError(
             "VARCHAR template parameter used outside an eq/neq "
-            "comparison (templates/analysis.py must not hoist here)")
+            "comparison or a LIKE (templates/analysis.py must not "
+            "hoist here)")
 
 
 class TraceParams:
@@ -116,9 +127,40 @@ def _long_limbs(value: int) -> np.ndarray:
     return _lit128_np(int(value))
 
 
+def mask_length(dictionary) -> int:
+    """Entries of a LIKE mask over ``dictionary``: the next power of
+    two, not the dictionary's own length. The codes never reach the
+    padding, and the program's shape then does not follow the exact
+    count of distinct strings (1,999,647 of TPC-H SF10's 2,000,000
+    ``p_name`` under one seed, another count under the next), as the
+    scans' pow2 row buckets keep it from following the row count."""
+    from presto_tpu.ops.hash import next_pow2
+    return next_pow2(max(len(dictionary), 1))
+
+
+def _dict_mask(value, dictionary) -> np.ndarray:
+    """A LIKE pattern over every entry of the dictionary it was traced
+    against: host work per execution, one regular-expression match an
+    entry (2,000,000 for TPC-H SF10's ``p_name``)."""
+    from presto_tpu.expr.compile import like_mask
+    if dictionary is None:
+        raise TemplateError(
+            f"LIKE parameter over {value.column!r}: the trace recorded "
+            f"no dictionary to bind it against")
+    with TRACER.span("dict-mask", entries=len(dictionary)) as span:
+        mask = np.zeros(mask_length(dictionary), np.bool_)
+        mask[:len(dictionary)] = like_mask(
+            dictionary, value.pattern, value.escape)
+        if span is not None:
+            span.attrs["matched"] = int(mask.sum())
+    return mask
+
+
 def physical_value(dtype, value, dictionary=None) -> np.ndarray:
     """Host physical encoding of one parameter value, matching what
     expr/compile._c_literal would bake for the same literal."""
+    if isinstance(value, LikePattern):
+        return _dict_mask(value, dictionary)
     if isinstance(dtype, T.VarcharType):
         if dictionary is None or value is None:
             return np.int32(-1)  # matches no code
@@ -137,3 +179,24 @@ def bind_values(specs, bindings: dict | None) -> list:
     bindings = bindings or {}
     return [physical_value(s.dtype, s.value, bindings.get(i))
             for i, s in enumerate(specs)]
+
+
+def example_values(specs, scan_inputs) -> list:
+    """Placeholder argument vector with the shapes and dtypes a bind
+    will have, for lowering: string codes -1, and for a LIKE pattern an
+    all-false mask of :func:`mask_length` of the scanned column's
+    dictionary (``exec/executor.ScanInput.dictionaries``)."""
+    dicts = {sym: d for scan in scan_inputs
+             for sym, d in scan.dictionaries.items() if d is not None}
+    out = []
+    for s in specs:
+        if not isinstance(s.value, LikePattern):
+            out.append(physical_value(s.dtype, s.value))
+        elif s.value.column in dicts:
+            out.append(np.zeros(mask_length(dicts[s.value.column]),
+                                np.bool_))
+        else:
+            raise TemplateError(
+                f"LIKE parameter over {s.value.column!r}: no scan "
+                f"input carries its dictionary")
+    return out

@@ -62,23 +62,28 @@ def test_parameterize_hoists_varchar_equality(tpch_tiny):
 
 
 def test_structural_literals_stay_baked(tpch_tiny):
-    """LIKE patterns are host-evaluated over the dictionary at trace
-    time; their literals must never hoist."""
+    """What the compiler still reads host-side at trace time must never
+    hoist: regexp patterns, substring bounds, and a LIKE over a
+    computed string. (A LIKE pattern over a scanned dictionary column
+    is no longer among them: it binds as a mask over the dictionary,
+    tests/test_like_binds.py.)"""
+    from presto_tpu.plan.fingerprint import plan_fingerprint
     e = tpch_engine(tpch_tiny)
-    p1, _ = e.plan_sql("select count(*) from region "
-                       "where r_name like 'A%'")
-    p2, _ = e.plan_sql("select count(*) from region "
-                       "where r_name like 'E%'")
-    t1, t2 = parameterize(p1), parameterize(p2)
-    fp1 = (t1.fingerprint() if t1 is not None
-           else __import__("presto_tpu.plan.fingerprint",
-                           fromlist=["plan_fingerprint"])
-           .plan_fingerprint(p1))
-    fp2 = (t2.fingerprint() if t2 is not None
-           else __import__("presto_tpu.plan.fingerprint",
-                           fromlist=["plan_fingerprint"])
-           .plan_fingerprint(p2))
-    assert fp1 != fp2  # pattern is structural: different templates
+
+    def fp(sql):
+        plan, _ = e.plan_sql(sql)
+        t = parameterize(plan)
+        return t.fingerprint() if t is not None else plan_fingerprint(plan)
+
+    text = "select count(*) from region where {}"
+    for a, b in (("regexp_like(r_name, '^A')", "regexp_like(r_name, '^E')"),
+                 ("substring(r_name, 1, 2) = 'AS'",
+                  "substring(r_name, 2, 2) = 'AS'"),
+                 ("lower(r_name) like 'a%'", "lower(r_name) like 'e%'")):
+        assert fp(text.format(a)) != fp(text.format(b)), (a, b)
+    # the control: a LIKE over the scanned column itself does hoist
+    assert (fp(text.format("r_name like 'A%'"))
+            == fp(text.format("r_name like 'E%'")))
 
 
 # -- end-to-end variant correctness + zero compiles --------------------------
