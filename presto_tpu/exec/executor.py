@@ -54,7 +54,9 @@ class ScanInput:
     """Host-side arrays + metadata for one TableScan."""
 
     node: N.TableScan
-    arrays: dict[str, np.ndarray]  # symbol -> physical data
+    # symbol -> physical data (exec/streaming.py traces its block
+    # program from shapes alone: jax.ShapeDtypeStruct in their place)
+    arrays: dict[str, np.ndarray]
     dictionaries: dict[str, np.ndarray | None]
     types: dict[str, T.DataType]
     nrows: int

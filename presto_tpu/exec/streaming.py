@@ -10,6 +10,14 @@ partial-aggregate kernel, accumulate the per-block partial states
 rest of the plan over the merged partials. HBM holds one block at a
 time, so tables larger than device memory stream through.
 
+The block program is a plan template like a resident program
+(exec/executor.prepare_plan): with session ``plan_templates`` on, the
+literals of the partial-aggregate sub-plan leave it before the program
+cache is keyed, and reach the program as trailing device scalars with
+every block. A statement whose literals the server has not met runs
+the program an earlier variant compiled; only ``plan_templates=false``
+still builds one per statement.
+
 Shape requirements (else the whole-table path runs): exactly one
 TableScan; only Filter/Project between it and a single-step Aggregate;
 anything above the Aggregate (sort/limit/output operate on the small
@@ -77,9 +85,11 @@ def _replace_node(plan: N.PlanNode, target: N.PlanNode,
 
 def try_execute_streamed(engine, plan: N.PlanNode):
     """Execute ``plan`` block-streamed, or return None if inapplicable."""
+    from presto_tpu import templates as TPL
+    from presto_tpu.exec import progcache as PC
     from presto_tpu.exec.executor import (
-        ScanInput, collect_scans, compiling, make_traced, program_name,
-        run_plan)
+        ScanInput, _cache_key, collect_scans, compiling, make_traced,
+        program_name, run_plan)
 
     block = int(engine.session.get("scan_block_rows") or 0)
     if block <= 0:
@@ -96,10 +106,40 @@ def try_execute_streamed(engine, plan: N.PlanNode):
     # -- phase 1: one compiled partial-aggregate program, run per block --
     partial = dataclasses.replace(agg, step=N.AggStep.PARTIAL)
     nblocks = -(-scan.nrows // block)
-    capacities: dict[tuple, int] = {}
     partial_cols: list[list[np.ndarray]] = []
     partial_live: list[np.ndarray] = []
     out_schema = None
+
+    # what the trace needs of a block is its shapes: a cached program
+    # outlives the statement, and a block's arrays are views of (the
+    # last block: a padded copy of) the table's columns
+    shapes = {sym: jax.ShapeDtypeStruct((block,) + a.shape[1:], a.dtype)
+              for sym, a in scan.arrays.items()}
+    shapes["__live__"] = jax.ShapeDtypeStruct((block,), np.bool_)
+    block_scan = ScanInput(scan.node, shapes, scan.dictionaries,
+                           scan.types, block)
+
+    # as prepare_plan: hoist the literals, key the program cache on the
+    # template (a sub-plan with nothing to hoist keys it as it is: a
+    # replay hits, a variant compiles) and start from the capacities
+    # that passed the ok-ladder last time
+    templated = TPL.enabled(engine.session)
+    cache = engine._program_cache
+    fpr = PC.platform_fingerprint()
+    tpl = None
+    base_key = None
+    capacities: dict[tuple, int] = {}
+    if templated:
+        cache.configure(engine.session)
+        with TRACER.span("program-lookup"):
+            tpl = TPL.parameterize(partial)
+            if tpl is not None:
+                partial = tpl.plan
+            # the block program returns no row counts (collect_rows
+            # off), so it never shares an entry with a resident one
+            base_key = (*_cache_key(engine, partial, [block_scan], {})[0],
+                        "stream")
+            capacities = dict(engine._caps_memory.get(base_key) or {})
 
     def block_input(i: int) -> dict[str, np.ndarray]:
         lo, hi = i * block, min((i + 1) * block, scan.nrows)
@@ -116,6 +156,8 @@ def try_execute_streamed(engine, plan: N.PlanNode):
     from presto_tpu.exec.cancel import checkpoint
     compiled = None
     meta = None
+    caps_key = None
+    pargs: list = []
     for i in range(nblocks):
         checkpoint()
         with TRACER.span("block-input", block=i,
@@ -126,20 +168,35 @@ def try_execute_streamed(engine, plan: N.PlanNode):
         dev_args = None
         for _attempt in range(10):
             fresh = compiled is None
+            if fresh and templated:
+                caps_key = PC.bucket_capacities(capacities)
+                entry = cache.lookup((base_key, caps_key), fpr)
+                if tpl is not None and i == 0 and _attempt == 0:
+                    # once a statement, as prepare_plan counts it
+                    TPL.note_lookup(hit=entry is not None,
+                                    params=len(tpl.params))
+                if entry is not None:
+                    # a hit: no compile span, nothing compiles, and
+                    # this statement's literals ride with every block
+                    compiled, meta = entry
+                    fresh = False
+                    if tpl is not None:
+                        pargs = tpl.bind(meta.get("param_bindings"))
             if fresh:
-                block_scan = ScanInput(scan.node, arrays,
-                                       scan.dictionaries, scan.types,
-                                       block)
                 # collect_rows off: the block program replays per
                 # block; run_plan over the concatenated partials (the
                 # final program) still records its stats normally
+                if tpl is not None:
+                    pargs = tpl.example_args([block_scan])
                 traced_fn, _flat, meta = make_traced(
                     [block_scan], partial, capacities, engine.session,
+                    params=pargs if tpl is not None else None,
                     collect_rows=False)
-                # the plan holds its literals (no template here), so
-                # the name is the root kind alone
-                traced_fn.__name__ = program_name(partial,
-                                                  prefix="stream_")
+                # a template's fingerprint holds no literal, so the
+                # variants share the name as they share the program
+                traced_fn.__name__ = program_name(
+                    partial, base_key[0] if tpl is not None else None,
+                    prefix="stream_")
                 compiled = jax.jit(traced_fn)
             if dev_args is None:
                 # the host's share of the copy; what is still in
@@ -147,6 +204,7 @@ def try_execute_streamed(engine, plan: N.PlanNode):
                 with TRACER.span("transfer", block=i,
                                  bytes=sum(a.nbytes for a in host_args)):
                     dev_args = jax.device_put(host_args)
+            outs = None
             if fresh:
                 # The first call of a fresh jit traces, lowers, compiles
                 # and dispatches, and returns before the device is done
@@ -157,13 +215,25 @@ def try_execute_streamed(engine, plan: N.PlanNode):
                 # every streamed statement at SF10 0.9-2.8 s slower on
                 # the chip (PERF.md section 6, PR 25), its lowering
                 # alone 1.8-3.0 s against 0.2 s on this path; meta
-                # fills during the trace.
+                # fills during the trace. The example arguments it
+                # runs on are this statement's own values, but for a
+                # string parameter: the dictionary that gives its code
+                # is known only once the trace has recorded it, and
+                # the block then runs again below, bound.
                 with compiling(attempt=_attempt,
                                root=type(partial).__name__, streamed=True):
-                    outs = compiled(*dev_args)
+                    outs = compiled(*dev_args, *pargs)
+                if tpl is not None and meta.get("param_bindings"):
+                    pargs = tpl.bind(meta["param_bindings"])
+                    outs = None
+                if templated:
+                    # the memory tier only: the disk tier stores AOT
+                    # executables, and this is the jit wrapper
+                    cache.insert((base_key, caps_key), compiled, meta,
+                                 fpr, persist=False)
             with TRACER.span("execute", block=i, streamed=True):
-                if not fresh:
-                    outs = compiled(*dev_args)
+                if outs is None:
+                    outs = compiled(*dev_args, *pargs)
                 res, live, oks = outs
                 oks_np = HS.fetch(oks, site="streaming-ok-ladder")
                 if oks_np.all():
@@ -173,10 +243,14 @@ def try_execute_streamed(engine, plan: N.PlanNode):
                                                site="streaming-demux")
             if oks_np.all():
                 break
+            if templated:
+                # the remembered capacities only grow, so no statement
+                # comes back to this rung: dead weight in the LRU
+                cache.discard((base_key, caps_key))
             from presto_tpu.ops.hash import grow_overflowed
             grow_overflowed(capacities, meta["ok_keys"], oks_np,
                             meta["used_capacity"])
-            compiled = None  # recompile with grown capacity
+            compiled = None  # the program of the grown capacities
         else:
             from presto_tpu.ops.hash import HashChainOverflow
             raise HashChainOverflow(
@@ -184,6 +258,9 @@ def try_execute_streamed(engine, plan: N.PlanNode):
         out_schema = meta["out"]
         partial_cols.append(res_np)
         partial_live.append(live_np)
+    if templated:
+        # the next statement of this shape starts on the rung that held
+        engine._caps_memory[base_key] = dict(capacities)
 
     # -- phase 2: rest of the plan over the concatenated partials --------
     carrier_syms = [sym for sym, _t, _d, _v in out_schema]

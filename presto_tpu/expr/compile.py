@@ -903,7 +903,7 @@ def _like(e, args):
                 f"dictionary of {len(col.dictionary)}")
         return _bool(pat.data[col.data], col.valid)
     # a plan that was not templated (plan_templates off, EXPLAIN
-    # ANALYZE, the streamed scan) bakes every literal, this one too
+    # ANALYZE) bakes every literal, this one too
     escape = str(args[2].dictionary[0]) if len(args) > 2 else None
     mask = like_mask(col.dictionary, str(pat.dictionary[0]), escape)
     return _bool(jnp.asarray(mask)[col.data], col.valid)

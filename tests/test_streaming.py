@@ -4,10 +4,19 @@ compiled partial-aggregate kernel; device memory holds one block, not
 the table. Reference: split/SplitManager.java,
 plugin/trino-tpch/.../TpchSplitManager.java:55."""
 
+import numpy as np
 import pytest
 
 from presto_tpu import Engine
+from presto_tpu import types as T
+from presto_tpu.connectors.memory import MemoryConnector
+from presto_tpu.obs.metrics import REGISTRY
 from presto_tpu.testing.oracle import rows_equal
+
+_COMPILED = REGISTRY.counter("presto_tpu_programs_compiled_total")
+_TPL_HITS = REGISTRY.counter("presto_tpu_template_cache_hits_total")
+_TPL_MISSES = REGISTRY.counter(
+    "presto_tpu_template_cache_misses_total")
 
 
 def make_engine(tpch_tiny, block_rows: int) -> Engine:
@@ -45,15 +54,18 @@ def test_streamed_matches_whole_table(sql, tpch_tiny):
     assert got == whole.execute(sql)
 
 
-def test_streamed_matches_oracle(tpch_tiny, oracle):
+def _assert_oracle(oracle, sql, got, ordered):
     from presto_tpu.sql.parser import parse_statement
     from presto_tpu.sql.sqlite_dialect import to_sqlite
 
-    e = make_engine(tpch_tiny, 7000)
-    got = e.execute(Q1)
-    want = oracle.query(to_sqlite(parse_statement(Q1)))
-    ok, msg = rows_equal(got, want, ordered=True)
+    want = oracle.query(to_sqlite(parse_statement(sql)))
+    ok, msg = rows_equal(got, want, ordered=ordered)
     assert ok, msg
+
+
+def test_streamed_matches_oracle(tpch_tiny, oracle):
+    e = make_engine(tpch_tiny, 7000)
+    _assert_oracle(oracle, Q1, e.execute(Q1), ordered=True)
 
 
 def test_join_plan_does_not_stream(tpch_tiny):
@@ -70,3 +82,211 @@ def test_small_scan_does_not_stream(tpch_tiny):
     e.last_streamed_blocks = 0
     e.execute(Q6)
     assert e.last_streamed_blocks == 0
+
+
+# -- the block program is a plan template -----------------------------------
+#
+# A streamed statement is two programs: the partial aggregate that runs
+# over every block, and the rest of the plan over the merged partials
+# (run_plan, templated since PR 7). The cases below are about the first.
+
+Q1_VARIANT = Q1.replace("1998-09-02", "1996-06-01")
+Q6_VARIANT = (Q6.replace("1994-01-01", "1996-01-01")
+              .replace("1995-01-01", "1997-01-01")
+              .replace("0.05 and 0.07", "0.02 and 0.04")
+              .replace("< 24", "< 25"))
+BY_MODE = ("select l_shipmode, count(*) as c from lineitem "
+           "where l_shipmode = '{}' group by l_shipmode")
+LIKE = "select count(*) from lineitem where l_shipinstruct like '{}'"
+
+
+@pytest.mark.parametrize(
+    "first, variant, ordered",
+    [(Q6, Q6_VARIANT, False), (Q1, Q1_VARIANT, True),
+     (BY_MODE.format("AIR"), BY_MODE.format("RAIL"), False),
+     (LIKE.format("%BACK%"), LIKE.format("DELIVER%"), False)],
+    ids=["q6", "q1", "varchar_equality", "like_pattern"])
+def test_literal_variant_compiles_nothing(first, variant, ordered,
+                                          tpch_tiny, oracle):
+    """Other literals on a shape the engine has streamed before: the
+    block program is a template hit, nothing compiles, and the answer
+    is the whole-table path's and the oracle's. The two string cases
+    also cover the first statement's second run of its first block,
+    bound to the dictionary the trace recorded."""
+    whole = make_engine(tpch_tiny, 0)
+    e = make_engine(tpch_tiny, 7000)
+    c0, m0 = _COMPILED.value(), _TPL_MISSES.value()
+    got_first = e.execute(first)
+    assert _COMPILED.value() > c0  # the block program, at least
+    assert _TPL_MISSES.value() == m0 + 1
+    assert got_first == whole.execute(first)
+    _assert_oracle(oracle, first, got_first, ordered)
+
+    c0, h0, m0 = _COMPILED.value(), _TPL_HITS.value(), _TPL_MISSES.value()
+    e.last_streamed_blocks = 0
+    got = e.execute(variant)
+    assert e.last_streamed_blocks >= 8
+    assert _COMPILED.value() == c0, "a literal variant compiled"
+    assert _TPL_HITS.value() == h0 + 1
+    assert _TPL_MISSES.value() == m0
+    assert got != got_first
+    assert got == whole.execute(variant)
+    _assert_oracle(oracle, variant, got, ordered)
+
+
+def test_absent_string_literal_hits_and_matches_no_row(tpch_tiny):
+    e = make_engine(tpch_tiny, 7000)
+    assert e.execute(BY_MODE.format("AIR"))
+    c0 = _COMPILED.value()
+    assert e.execute(BY_MODE.format("no such mode")) == []
+    assert _COMPILED.value() == c0
+
+
+def test_overflowed_capacities_are_remembered(tpch_tiny):
+    """HIGH_CARD has no literal to hoist, so its sub-plan keys the
+    cache as it is. With a first rung far too small for a block's
+    groups the first statement climbs the ok-ladder; the second starts
+    on the rung that held and compiles nothing."""
+    whole = make_engine(tpch_tiny, 0)
+    e = make_engine(tpch_tiny, 7000)
+    e.session.set("groupby_table_size", 512)
+    c0 = _COMPILED.value()
+    got = e.execute(HIGH_CARD)
+    # two rungs or more of the block program, and the final program
+    assert _COMPILED.value() - c0 >= 3
+    stream_caps = [caps for key, caps in e._caps_memory.items()
+                   if key[-1] == "stream"]
+    assert len(stream_caps) == 1
+    assert all(cap > 512 for cap in stream_caps[0].values())
+    # the rungs that overflowed left the cache with their programs
+    assert sum(key[0][-1] == "stream"
+               for key in e._program_cache._entries) == 1
+    c0 = _COMPILED.value()
+    assert e.execute(HIGH_CARD) == got
+    assert _COMPILED.value() == c0, "the ladder was climbed again"
+    assert got == whole.execute(HIGH_CARD)
+
+
+def test_plan_templates_off_compiles_per_statement(tpch_tiny):
+    """The master switch keeps the behaviour from before the template:
+    a block program per statement, even for the same text, and none of
+    them in the cache."""
+    on = make_engine(tpch_tiny, 7000)
+    off = make_engine(tpch_tiny, 7000)
+    off.session.set("plan_templates", False)
+    want = on.execute(Q6), on.execute(Q6_VARIANT)
+    h0, m0 = _TPL_HITS.value(), _TPL_MISSES.value()
+    for sql, rows in zip((Q6, Q6_VARIANT, Q6), (*want, want[0])):
+        c0 = _COMPILED.value()
+        assert off.execute(sql) == rows
+        assert _COMPILED.value() > c0
+    assert (_TPL_HITS.value(), _TPL_MISSES.value()) == (h0, m0)
+    assert not any(key[0][-1] == "stream"
+                   for key in off._program_cache._entries)
+
+
+def test_insert_that_changes_a_dictionary_misses():
+    """The block's shape never changes (it is scan_block_rows), so
+    what keeps a program off data it was not traced for is the
+    dictionary digest in the key: an INSERT that brings a new string
+    misses, compiles and answers with it; one that brings none hits."""
+    n = 3000
+    conn = MemoryConnector()
+    conn.create_table(
+        "t", {"s": T.VARCHAR, "x": T.BIGINT},
+        {"s": np.array(["a", "b", "c"], dtype=object)[np.arange(n) % 3],
+         "x": np.arange(n)})
+    e = Engine()
+    e.register_catalog("mem", conn)
+    e.session.catalog = "mem"
+    e.session.set("scan_block_rows", 1000)
+    sql = ("select s, count(*) as c, sum(x) as sx from t "
+           "where x >= {} group by s order by s")
+
+    def want(lo, extra=()):
+        rows = [(s, x) for s, x in zip("abc" * (n // 3), range(n))]
+        rows += list(extra)
+        out = {}
+        for s, x in rows:
+            if x >= lo:
+                c, sx = out.get(s, (0, 0))
+                out[s] = (c + 1, sx + x)
+        return [(s, c, sx) for s, (c, sx) in sorted(out.items())]
+
+    assert e.execute(sql.format(10)) == want(10)
+    assert e.last_streamed_blocks == 3
+    c0 = _COMPILED.value()
+    assert e.execute(sql.format(500)) == want(500)
+    assert _COMPILED.value() == c0
+
+    e.execute("insert into t select 'b', 7000")  # no new string
+    c0 = _COMPILED.value()
+    assert e.execute(sql.format(20)) == want(20, [("b", 7000)])
+    assert e.last_streamed_blocks == 4
+    assert _COMPILED.value() == c0, "same dictionary, same shapes"
+
+    e.execute("insert into t select 'zz', 8000")  # the dictionary grows
+    c0, m0 = _COMPILED.value(), _TPL_MISSES.value()
+    assert (e.execute(sql.format(30))
+            == want(30, [("b", 7000), ("zz", 8000)]))
+    assert _COMPILED.value() > c0
+    assert _TPL_MISSES.value() > m0
+
+
+def _reachable_arrays(root):
+    """Every ndarray ``root`` keeps alive through what a cached
+    program is made of: a jit wrapper's function, closure cells and
+    defaults, containers, and the attributes of plain objects (plan
+    nodes, ScanInput, the session). Modules and classes are not
+    followed: they are alive anyway."""
+    import types
+
+    seen, stack, found = set(), [root], []
+    while stack:
+        obj = stack.pop()
+        if id(obj) in seen or isinstance(obj, (type, types.ModuleType)):
+            continue
+        seen.add(id(obj))
+        if isinstance(obj, np.ndarray):
+            found.append(obj)
+            if obj.base is not None:
+                stack.append(obj.base)
+        elif isinstance(obj, dict):
+            stack.extend(obj.keys())
+            stack.extend(obj.values())
+        elif isinstance(obj, (list, tuple, set, frozenset)):
+            stack.extend(obj)
+        elif isinstance(obj, types.FunctionType):
+            stack.extend(c.cell_contents for c in obj.__closure__ or ())
+            stack.extend(obj.__defaults__ or ())
+            stack.append(getattr(obj, "__wrapped__", None))
+        else:
+            stack.append(getattr(obj, "__wrapped__", None))
+            stack.append(getattr(obj, "__dict__", None))
+            stack.extend(getattr(obj, s, None)
+                         for s in getattr(type(obj), "__slots__", ()))
+    return found
+
+
+def test_cached_program_holds_no_block_of_the_table(tpch_tiny):
+    """The cache outlives the statement; a block's arrays are views of
+    the table's columns or, for the last block, padded copies of them
+    (0.5-0.75 GB at SF10). The trace gets shapes, so after a hit
+    nothing the cache holds reaches an array of a block's rows or a
+    column of the table."""
+    block = 7000
+    e = make_engine(tpch_tiny, block)
+    e.execute(Q1)
+    e.execute(Q1_VARIANT)  # a hit
+    entries = [ent for key, ent in e._program_cache._entries.items()
+               if key[0][-1] == "stream"]
+    assert len(entries) == 1
+    compiled, meta, _nbytes = entries[0]
+    assert getattr(compiled, "__wrapped__", None) is not None
+    nrows = tpch_tiny.table("lineitem").nrows
+    arrays = _reachable_arrays((compiled, meta))
+    # the walk did reach the closure: the dictionaries are in it
+    assert any(a.dtype == object for a in arrays)
+    held = [a.shape for a in arrays
+            if a.ndim and a.shape[0] in (block, nrows)]
+    assert not held, held
