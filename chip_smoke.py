@@ -10,11 +10,10 @@ variant of each (which must compile nothing), a CTAS into the memory
 catalog read back, and an INSERT after which the same SELECT must
 answer differently. Every answer is compared EXACTLY with plain NumPy
 over the same generated columns (int64 scaled decimals, written here,
-independent of presto_tpu/exec). Then each Pallas kernel of the
-dispatch table is compiled (never interpreted) and compared with its
-XLA twin, and with four or more devices Q1 and the engine's all_to_all
-exchange step run over a four-device mesh (Q3 over the mesh is owed:
-its one program compiles for longer than this script may run).
+independent of presto_tpu/exec). With four or more devices Q1 and the
+engine's all_to_all exchange step then run over a four-device mesh (Q3
+over the mesh is owed: its one program compiles for longer than this
+script may run).
 
 There is no CPU fallback: the script exits non-zero unless
 ``jax.default_backend()`` is ``tpu``, and any step that fails raises.
@@ -30,8 +29,6 @@ import argparse
 import json
 import sys
 import time
-import types
-import urllib.request
 
 import numpy as np
 
@@ -295,10 +292,8 @@ def served_leg(engine, conn) -> None:
     server = CoordinatorServer(engine).start()
     try:
         client = Client(server.uri)
-        labels: dict[str, str] = {}
 
         def run(label: str, sql: str, expect_compiles: str) -> list:
-            labels.setdefault(" ".join(sql.split()), label)
             c0, s0 = compiled.value(), compile_s.sum()
             t = time.perf_counter()
             _cols, rows = client.execute(sql)
@@ -346,31 +341,6 @@ def served_leg(engine, conn) -> None:
         check(want["select after insert"] != want["select after ctas"],
               "insert does not change the reference answer")
         run("select after insert", SMOKE_SELECT, "any")
-
-        # which kernel each operator resolved to, from the engine's
-        # own stats table over the same protocol; /v1/query maps the
-        # coordinator's query ids back to the statements
-        req = urllib.request.Request(
-            f"{server.uri}/v1/query",
-            headers={"X-Trino-User": client.user})
-        with urllib.request.urlopen(req, timeout=60) as resp:
-            queries = [(q["queryId"], q["query"])
-                       for q in json.load(resp)]
-        _c, ops = client.execute(
-            "select query_id, node_type, kernel "
-            "from system.operator_stats")
-        by_id = {qid: labels.get(" ".join(sql.split()))
-                 for qid, sql in queries}
-        seen: dict[str, list[str]] = {}
-        for qid, node_type, kernel in ops:
-            label = by_id.get(qid)
-            if label:
-                seen.setdefault(label, []).append(
-                    f"{node_type}[{kernel or '-'}]")
-        for label, tags in seen.items():
-            say(f"[kernels] {label}: {' '.join(tags)}")
-        check(bool(seen), "system.operator_stats shows no operator "
-                          "of the smoke's queries")
     finally:
         server.stop()
 
@@ -386,157 +356,6 @@ def check_device_memory(conn) -> None:
         f"{pinned}")
     check(peak >= pinned, f"peak device memory {peak} is below the "
                           f"{pinned} bytes of pinned lineitem columns")
-
-
-# -- kernel leg ---------------------------------------------------------------
-
-def _kernel_cases(conn) -> dict:
-    """name -> (fn(backend, *args), args) at shapes this scale produces:
-    Q1's fold over lineitem, Q3's customer build probed by orders and
-    the compaction behind it, and an orders -> customer -> nation star
-    walk. Arrays go in as arguments, never as program constants."""
-    import jax.numpy as jnp
-
-    from presto_tpu import kernels as K
-    from presto_tpu import types as T
-    from presto_tpu.exec import operators as OP
-    from presto_tpu.expr.compile import Val
-    from presto_tpu.ops import hash as H
-
-    price = _col(conn, "lineitem", "l_extendedprice")
-    nls = len(_dictionary(conn, "lineitem", "l_linestatus"))
-    nseg = len(_dictionary(conn, "lineitem", "l_returnflag")) * nls
-    sid = (_col(conn, "lineitem", "l_returnflag").astype(np.int32) * nls
-           + _col(conn, "lineitem", "l_linestatus").astype(np.int32))
-
-    seg = _col(conn, "customer", "c_mktsegment")
-    seg_d = _dictionary(conn, "customer", "c_mktsegment")
-    c_live = seg == int(np.flatnonzero(seg_d == "BUILDING")[0])
-    c_key = _col(conn, "customer", "c_custkey")
-    c_nat = _col(conn, "customer", "c_nationkey")
-    o_cust = _col(conn, "orders", "o_custkey")
-    o_key = _col(conn, "orders", "o_orderkey")
-    o_date = _col(conn, "orders", "o_orderdate")
-    o_live = o_date < _days("1995-03-15")
-    n_key = _col(conn, "nation", "n_nationkey")
-    join_cap = H.next_pow2(2 * len(c_key))
-    o_joined = o_live & np.isin(o_cust, c_key[c_live])
-    n_joined = int(o_joined.sum())
-    compact_cap = H.next_pow2(max(n_joined, 1))
-
-    def fold(name):
-        return lambda backend, data, ids: (
-            K.KERNELS[name][backend](data, ids, nseg),)
-
-    def join(backend, ck, cl, oc, ol):
-        row, found, ok = K.KERNELS["join_lookup"][backend](
-            H.hash_int_column(ck), cl, H.hash_int_column(oc), ol,
-            join_cap)
-        return jnp.where(found, row, -1), found, ok
-
-    def compact(backend, live, k, d):
-        out = K.KERNELS["compact"][backend](
-            live, {"k": k, "d": d}, compact_cap)
-        # rows past the live count are dead on both backends
-        return out["k"][:n_joined], out["d"][:n_joined]
-
-    def multijoin(backend, oc, ok_, ol, ck, cn, cl, nk):
-        # through the operator: its inline walk IS the XLA twin
-        # (try_fused_xla is only a "not fused" sentinel)
-        spine = OP.DTable({"o_custkey": Val(T.BIGINT, oc),
-                           "o_orderkey": Val(T.BIGINT, ok_)},
-                          ol, len(o_cust))
-        builds = [
-            OP.DTable({"c_custkey": Val(T.BIGINT, ck),
-                       "c_nationkey": Val(T.BIGINT, cn)},
-                      cl, len(c_key)),
-            OP.DTable({"n_nationkey": Val(T.BIGINT, nk)},
-                      None, len(n_key))]
-        node = types.SimpleNamespace(criteria=[
-            [("o_custkey", "c_custkey")],
-            [("c_nationkey", "n_nationkey")]])
-        with K.use_backend(backend):
-            out, ok = OP.apply_multi_join(spine, builds, node)
-        live = out.live_mask()
-        return (live,
-                jnp.where(live, out.cols["c_nationkey"].data, -1),
-                jnp.where(live, out.cols["n_nationkey"].data, -1), ok)
-
-    return {
-        "join_lookup": (join, (c_key, c_live, o_cust, o_live)),
-        "agg_sum": (fold("agg_sum"), (price, sid)),
-        "agg_max": (fold("agg_max"), (price, sid)),
-        "agg_min": (fold("agg_min"), (price, sid)),
-        "compact": (compact, (o_joined, o_key, o_date)),
-        "multijoin": (multijoin, (o_cust, o_key, o_live, c_key, c_nat,
-                                  c_live, n_key)),
-    }
-
-
-def kernel_leg(conn) -> dict[str, str]:
-    """Compile each Pallas kernel of the dispatch table and compare it
-    with its XLA twin. Returns name -> outcome line. A refusal fails
-    the smoke only for a kernel that ``auto`` selects."""
-    import jax
-
-    from presto_tpu import kernels as K
-
-    mode = "interpreted" if K.interpret_mode() else "compiled"
-    cases = _kernel_cases(conn)
-    check(set(cases) == set(K.KERNELS),
-          "kernel leg does not cover the dispatch table")
-
-    def timed(fn, backend, args):
-        """(outputs on host, compile wall, best steady wall of 3,
-        kernel tags noted at trace)."""
-        t0 = time.perf_counter()
-        with K.collect() as used:
-            compiled = jax.jit(
-                lambda *a: fn(backend, *a)).lower(*args).compile()
-        t_compile = time.perf_counter() - t0
-        out = jax.block_until_ready(compiled(*args))
-        walls = []
-        for _ in range(3):
-            t0 = time.perf_counter()
-            jax.block_until_ready(compiled(*args))
-            walls.append(time.perf_counter() - t0)
-        return ([np.asarray(o) for o in out], t_compile, min(walls),
-                used)
-
-    outcome: dict[str, str] = {}
-    for name, (fn, host_args) in cases.items():
-        auto = K.auto_backend(name)
-        args = [jax.device_put(a) for a in host_args]
-        try:
-            got, t_compile, wall, used = timed(fn, "pallas", args)
-        except Exception as exc:  # noqa: BLE001 - the compiler's refusal
-            # is this leg's finding for a kernel auto does not select
-            if auto == "pallas":
-                raise
-            first = (str(exc).strip().splitlines() or [repr(exc)])[0]
-            outcome[name] = (f"refused: {type(exc).__name__}: "
-                             f"{first[:200]}")
-        else:
-            if not any(tag.startswith("pallas:") for tag in used):
-                # e.g. a build past PALLAS_MAX_TABLE at this scale
-                outcome[name] = (f"declined at its eligibility gate: "
-                                 f"ran={','.join(used) or '-'}")
-                say(f"[kernel] {name} (auto={auto}): {outcome[name]}")
-                continue
-            # the twin only where there is an answer to hold it to:
-            # its sort-based bodies are the slowest compiles here
-            twin, _tc, xla_wall, _u = timed(fn, "xla", args)
-            same = (len(got) == len(twin) and all(
-                np.array_equal(a, b) for a, b in zip(got, twin)))
-            check(same, f"kernel {name}: Pallas answer differs from "
-                        f"its XLA twin")
-            outcome[name] = (
-                f"{mode}: equals xla twin; ran={','.join(used) or '-'} "
-                f"compile={t_compile:.1f}s pallas={wall * 1e3:.2f}ms "
-                f"xla={xla_wall * 1e3:.2f}ms "
-                f"ratio={wall / max(xla_wall, 1e-9):.1f}x")
-        say(f"[kernel] {name} (auto={auto}): {outcome[name]}")
-    return outcome
 
 
 # -- mesh leg -----------------------------------------------------------------
@@ -619,9 +438,6 @@ def main(argv: list[str] | None = None) -> int:
     engine, conn = build_engine(args.sf, args.seed)
     served_leg(engine, conn)
     check_device_memory(conn)
-    from presto_tpu import kernels as K
-    check(not K.interpret_mode(), "Pallas would interpret on the chip")
-    kernel_leg(conn)
     if len(jax.devices()) >= 4:
         mesh_leg(engine, conn, jax.devices())
     else:

@@ -154,11 +154,10 @@ class SystemConnector(_ReflectiveConnector):
             "node_type": T.VARCHAR, "label": T.VARCHAR,
             "input_rows": T.BIGINT, "output_rows": T.BIGINT,
             "output_bytes": T.BIGINT, "est_rows": T.BIGINT,
-            # per-operator kernel attribution (presto_tpu/kernels/):
-            # which backend:kernel pairs the operator dispatched, and
-            # its cost-weighted share of the program's execute wall —
-            # "which operator dominates" is answerable from SQL
-            "kernel": T.VARCHAR, "wall_ms": T.BIGINT,
+            # the operator's cost-weighted share of the program's
+            # execute wall — "which operator dominates" is answerable
+            # from SQL
+            "wall_ms": T.BIGINT,
             # device-cost attribution (obs/devprof.py): the program's
             # XLA cost_analysis/memory_analysis split across its plan
             # nodes, plus arithmetic intensity (flops/byte) and the
@@ -317,8 +316,7 @@ class SystemConnector(_ReflectiveConnector):
             (qid, stage, t["taskId"], str(op["planNodeId"]),
              op["nodeType"], op["label"], int(op["inputRows"]),
              int(op["outputRows"]), int(op["outputBytes"]),
-             int(op["estRows"]), str(op.get("kernel") or ""),
-             int(op.get("wallMillis") or 0),
+             int(op["estRows"]), int(op.get("wallMillis") or 0),
              int(op.get("flops") or 0), int(op.get("hbmBytes") or 0),
              float(op.get("intensity") or 0.0),
              op.get("roofline"))

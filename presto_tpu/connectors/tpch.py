@@ -206,7 +206,6 @@ class TpchGenerator:
     that drive join distribution — lineitem's part keys (and through
     the spec's supplier formula, its supplier keys) and orders'
     customer keys follow a bounded Zipf(s) over the key space — so
-    skew-aware join benchmarks (bench.py PRESTO_TPU_BENCH_SKEW) and
     the hybrid-distribution oracle tests exercise heavy hitters on
     real TPC-H shapes. Primary keys, payload columns and row counts
     stay exactly the uniform generator's."""

@@ -205,12 +205,6 @@ class PlanInterpreter:
         self.ok_flags: list = []
         self.ok_keys: list[tuple] = []
         self.used_capacity: dict[tuple, int] = {}
-        # per-node kernel attribution (presto_tpu/kernels/): stable
-        # preorder position -> ["pallas:join_lookup", ...] noted by
-        # the dispatch table while this node's handler traced; rides
-        # meta into qstats so system.operator_stats names the kernel
-        # (and splits execute wall) per operator
-        self.kernel_used: dict[object, list[str]] = {}
         # always-on runtime stats (obs/qstats.py): live rows out of
         # EVERY plan node, keyed by stable preorder position so the
         # counts survive replans and ride program-cache entries across
@@ -228,7 +222,6 @@ class PlanInterpreter:
         self._df_applied: set[str] = set()
 
     def run(self, node: N.PlanNode) -> DTable:
-        from presto_tpu import kernels as K
         kind = type(node).__name__
         m = getattr(self, "_r_" + kind.lower())
         # device operations carry the plan operator's name: run()
@@ -236,11 +229,8 @@ class PlanInterpreter:
         # and an operation belongs to the innermost Kind#n of its path
         pos = self.node_order.get(id(node))
         scope = kind if pos is None else f"{kind}#{pos}"
-        with K.collect() as used, jax.named_scope(scope):
+        with jax.named_scope(scope):
             dt = m(node)
-        if used:
-            self.kernel_used[
-                self.node_order.get(id(node), id(node))] = list(used)
         if self.dyn_filters:
             dt = self._apply_dyn_filters(dt)
         if self.collect_rows:
@@ -393,10 +383,7 @@ class PlanInterpreter:
         """Fused star chain (plan/nodes.MultiJoin): trace every build
         first — registering each build's key set as a dynamic filter,
         so the spine scan prunes against ALL dimensions at once — then
-        run the probe walk (one Pallas kernel under
-        kernel_backend=pallas, the sequential sorted walk on XLA).
-        The Pallas tables can chain-overflow; the ok flag feeds the
-        capacity retry ladder like every other hash table."""
+        run the probe walk."""
         import types as _pytypes
         builds = []
         for bnode, crit in zip(node.builds, node.criteria):
@@ -409,11 +396,7 @@ class PlanInterpreter:
                 self._collect_dyn_filters(
                     _pytypes.SimpleNamespace(criteria=crit), bdt)
         spine = self.run(node.spine)
-        default = next_pow2(
-            2 * max(max((b.n for b in builds), default=1), 1))
-        cap = self._capacity(node, default)
-        out, ok = OP.apply_multi_join(spine, builds, node,
-                                      growth=max(1, cap // default))
+        out, ok = OP.apply_multi_join(spine, builds, node)
         self._note_ok(node, ok)
         return out
 
@@ -528,7 +511,6 @@ def make_traced(scan_inputs: list[ScanInput], plan: N.PlanNode,
     node_order = preorder_index(plan)
 
     def traced_fn(*args):
-        from presto_tpu import kernels as K
         it = iter(args)
         scans = {}
         for scan in scan_inputs:
@@ -537,26 +519,19 @@ def make_traced(scan_inputs: list[ScanInput], plan: N.PlanNode,
         interp = (interp_factory or PlanInterpreter)(
             scans, capacities, session, node_order)
         interp.collect_rows = collect_rows
-        # resolve + install the kernel backend for this trace
-        # (kernel_backend session property; ambient so operators and
-        # ops/segred dispatch without threading the session through)
-        backend = K.resolve(interp.session)
         if params is not None:
             from presto_tpu.templates import runtime as TR
             tp = TR.TraceParams(list(it))
-            with TR.active(tp), K.use_backend(backend):
+            with TR.active(tp):
                 out = interp.run(plan)
             meta["param_bindings"] = dict(tp.bindings)
         else:
-            with K.use_backend(backend):
-                out = interp.run(plan)
+            out = interp.run(plan)
         meta["out"] = [
             (sym, v.dtype, v.dictionary, v.valid is not None)
             for sym, v in out.cols.items()]
         meta["ok_keys"] = interp.ok_keys
         meta["used_capacity"] = interp.used_capacity
-        meta["kernel_backend"] = backend
-        meta["kernels"] = dict(getattr(interp, "kernel_used", {}))
         res = []
         for sym, v in out.cols.items():
             res.append(v.data)

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from presto_tpu import kernels as K
 from presto_tpu import types as T
 from presto_tpu.expr import aggregates as A
 from presto_tpu.expr import ir
@@ -579,7 +578,10 @@ def _verify_rest(left: DTable, right: DTable, node: N.Join,
 def apply_join(left: DTable, right: DTable, node: N.Join,
                capacity: int) -> tuple:
     """Hash join, probe side preserved (each probe row matches <= 1 build
-    row — FK->PK). Returns (DTable, ok)."""
+    row — FK->PK). Returns (DTable, ok). Neither lookup below builds a
+    table, so ``capacity`` sizes nothing and ``ok`` is constant: both
+    stay because the interpreters' stacked ok flags are an output of
+    every compiled join program (ROADMAP D13)."""
     lkeys = [lk for lk, _ in node.criteria]
     rkeys = [rk for _, rk in node.criteria]
     # SQL joins never match NULL keys: mask key-invalid rows out of both sides
@@ -589,21 +591,14 @@ def apply_join(left: DTable, right: DTable, node: N.Join,
     if node.dense_key is not None:
         build_row, found = _direct_probe(left, right, node,
                                          probe_live, build_live)
-        ok = jnp.asarray(True)
         gather = jnp.clip(build_row, 0, right.n - 1)
         verify = _verify_rest(left, right, node, None, gather)
         if verify is not True:
             found = found & verify
     else:
-        # backend-dispatched lookup (presto_tpu/kernels/): Pallas
-        # open-addressing build+probe on TPU (capacity-sized table,
-        # ok=False on chain overflow -> capacity retry ladder), the
-        # sorted-merge lookup as the XLA fallback (always ok)
         rh = _row_hash(right, rkeys)
         ph = _row_hash(left, lkeys)
-        build_row, found, ok = K.dispatch("join_lookup")(
-            rh, build_live, ph, probe_live, capacity)
-
+        build_row, found = H.lookup_join(rh, build_live, ph, probe_live)
         gather = jnp.clip(build_row, 0, right.n - 1)
         found = found & _verify_keys(left, right, node.criteria, None,
                                      gather)
@@ -640,11 +635,11 @@ def apply_join(left: DTable, right: DTable, node: N.Join,
                            v.dictionary)
     else:
         raise NotImplementedError(f"join type {node.join_type}")
-    return DTable(out, live, left.n), ok
+    return DTable(out, live, left.n), jnp.asarray(True)
 
 
 def apply_multi_join(spine: DTable, builds: list[DTable],
-                     node: "N.MultiJoin", growth: int = 1) -> tuple:
+                     node: "N.MultiJoin") -> tuple:
     """Fused multi-way INNER equi-join (plan/nodes.MultiJoin): one
     sequential probe walk over the spine's static width. Every build
     is unique (FK->PK) and residual-free by construction, so each step
@@ -654,35 +649,12 @@ def apply_multi_join(spine: DTable, builds: list[DTable],
     replaces materialized (and in segmented execution, compacted and
     re-uploaded) an intermediate DTable per join.
 
-    Backend-dispatched (presto_tpu/kernels/): under
-    ``kernel_backend=pallas`` the WHOLE chain runs as one Pallas
-    probe-walk kernel over per-build open-addressing tables
-    (kernels/multijoin.py — k probes while each spine tile is VMEM
-    resident, no sorts); the XLA walk below is the fallback, one
-    sorted lookup per step. ``growth`` scales every table capacity
-    (the retry ladder's knob on chain overflow). Returns
-    (DTable, ok) — ok is always True on the XLA path (sorted builds
-    cannot overflow)."""
-    # kernels self-note attribution: try_fused notes pallas only when
-    # it actually runs; a declined chain records the XLA walk
-    fused = K.dispatch("multijoin")(
-        spine.cols, spine.live_mask(), spine.n,
-        [(b.cols, b.live_mask(), b.n) for b in builds],
-        node.criteria, growth)
+    Each step is the sorted lookup of ops/hash.lookup_join, written
+    out here because the probe keys of step k are hashed after step
+    k-1's gather. Nothing can overflow: returns (DTable, ok) with ok
+    always True (kept for the same reason as apply_join's)."""
     # one scope per build under the node's own (MultiJoin#n/build<k>,
     # in plan order), so a device trace splits the probes
-    if fused is not None:
-        gathers, live, ok = fused
-        out = dict(spine.cols)
-        for k, (bdt, gather) in enumerate(zip(builds, gathers)):
-            with jax.named_scope(f"build{k}"):
-                for sym, v in bdt.cols.items():
-                    out[sym] = Val(
-                        v.dtype, v.data[gather],
-                        None if v.valid is None else v.valid[gather],
-                        v.dictionary)
-        return DTable(out, live, spine.n), ok
-    K.note("xla:multijoin")
     out = dict(spine.cols)
     live = spine.live_mask()
     width = spine.n
@@ -891,13 +863,10 @@ def apply_semijoin(dt: DTable, filt: DTable, node: N.SemiJoin,
         in_range = (pkey >= lo) & (pkey <= hi)
         found = probe_live & in_range & bits[
             jnp.clip(pkey - lo, 0, span - 1).astype(jnp.int32)]
-        ok = jnp.asarray(True)
     else:
-        # backend-dispatched lookup, same dispatch as apply_join
         fh = _row_hash(filt, node.filter_keys)
         sh = _row_hash(dt, node.source_keys)
-        build_row, found, ok = K.dispatch("join_lookup")(
-            fh, build_live, sh, probe_live, capacity)
+        build_row, found = H.lookup_join(fh, build_live, sh, probe_live)
         found = found & _verify_keys(
             dt, filt, list(zip(node.source_keys, node.filter_keys)),
             None, jnp.clip(build_row, 0, filt.n - 1))
@@ -917,31 +886,24 @@ def apply_semijoin(dt: DTable, filt: DTable, node: N.SemiJoin,
         set_empty = ~jnp.any(filt.live_mask())
         mark_valid = found | set_empty | (~probe_null & ~build_has_null)
     out[node.output] = Val(T.BOOLEAN, found, mark_valid)
-    return DTable(out, dt.live, dt.n), ok
+    # ok is constant, like apply_join's
+    return DTable(out, dt.live, dt.n), jnp.asarray(True)
 
 
 def compact_dtable(dt: DTable, capacity: int) -> tuple:
     """Gather live rows to the front of a ``capacity``-row DTable (the
     page-compaction analog inside a traced program). Returns
     (DTable [capacity], ok); ok is False when live rows overflow the
-    capacity (host retries with a grown capacity).
-
-    Backend-dispatched (presto_tpu/kernels/compact.py): the Pallas
-    kernel streams the mask + columns once, writing survivors densely
-    from a running VMEM count; the XLA fallback is the nonzero+gather
-    this always was. Stable order and the overflow flag are identical
-    on both backends."""
+    capacity (host retries with a grown capacity). Survivors keep
+    their order; rows past the live count replicate the last input row
+    and are dead."""
     live = dt.live_mask()
     cnt = jnp.sum(live.astype(jnp.int32))
     ok = cnt <= capacity
-    arrays: dict = {}
-    for sym, v in dt.cols.items():
-        arrays[f"{sym}!d"] = v.data
-        if v.valid is not None:
-            arrays[f"{sym}!v"] = v.valid
-    out = K.dispatch("compact")(live, arrays, capacity)
+    idx = jnp.nonzero(live, size=int(capacity), fill_value=dt.n - 1)[0]
     cols = {
-        sym: Val(v.dtype, out[f"{sym}!d"], out.get(f"{sym}!v"),
+        sym: Val(v.dtype, v.data[idx],
+                 None if v.valid is None else v.valid[idx],
                  v.dictionary)
         for sym, v in dt.cols.items()}
     return DTable(cols, jnp.arange(capacity) < cnt, capacity), ok

@@ -122,10 +122,6 @@ TRACE_RELEVANT_PROPERTIES = (
     "groupby_table_size",
     "join_distribution_type",
     "join_salting",
-    # kernel_backend selects the operator inner-loop implementation
-    # (presto_tpu/kernels/ dispatch) at trace time: pallas and xla
-    # traces are different programs and must not share an entry
-    "kernel_backend",
     "partial_aggregation",
     "partitioned_agg_min_groups",
     "skew_hot_key_threshold",
@@ -269,16 +265,11 @@ def platform_fingerprint(mesh_shape: tuple | None = None) -> tuple:
     import jax
     import jaxlib
 
-    from presto_tpu import kernels as K
     devs = jax.devices()
     return (jax.__version__, jaxlib.__version__,
             jax.default_backend(), len(devs),
             getattr(devs[0], "device_kind", "?"),
             bool(jax.config.jax_enable_x64), PROGRAM_FORMAT,
-            # the kernels kernel_backend=auto runs as Pallas here: a
-            # persisted entry from a process whose auto set differed
-            # (other platform, other build) holds other kernel bodies
-            "kernels-" + (",".join(K.auto_pallas_here()) or "xla"),
             mesh_shape)
 
 

@@ -1,6 +1,6 @@
 """Engine-specific static analysis (stdlib ``ast`` only).
 
-Thirteen rule families guard the places where this engine's bugs ship
+Twelve rule families guard the places where this engine's bugs ship
 silently (the reference defends the analogous seams with its
 PlanSanityChecker pipeline, sql/planner/sanity/PlanSanityChecker.java):
 
@@ -43,10 +43,6 @@ PlanSanityChecker pipeline, sql/planner/sanity/PlanSanityChecker.java):
   context, cancel token, stats recorder, session override) must hand
   the state over explicitly or document why the thread is
   context-free.
-- **kernel parity** (``lint/kernels.py``): every Pallas kernel is
-  registered in the ``kernel_backend`` dispatch table beside a real
-  XLA fallback — an unregistered kernel is unreachable from the
-  session property and invisible to parity testing.
 - **trace-key provenance** (``lint/tracekey.py``): every ambient
   input trace-reachable code reads (session property, env var,
   mutable module global — tracked across aliases, parameters, and
@@ -90,7 +86,6 @@ from presto_tpu.lint import pools as _pools  # noqa: E402,F401
 from presto_tpu.lint import spans as _spans  # noqa: E402,F401
 from presto_tpu.lint import races as _races  # noqa: E402,F401
 from presto_tpu.lint import handoff as _handoff  # noqa: E402,F401
-from presto_tpu.lint import kernels as _kernels  # noqa: E402,F401
 from presto_tpu.lint import tracekey as _tracekey  # noqa: E402,F401
 from presto_tpu.lint import devicesync as _devicesync  # noqa: E402,F401
 from presto_tpu.lint import retrace as _retrace  # noqa: E402,F401

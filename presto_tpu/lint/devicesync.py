@@ -41,9 +41,8 @@ Findings:
 
 Deliberate boundary reads are declared in
 ``exec/hostsync.DEVICE_SYNC_EXEMPT`` (id -> justification, id form
-``<relpath>:<dotted.unit>:<kind>``) with kernel-parity-style
-staleness enforcement: an entry matching no finding is itself a
-finding.
+``<relpath>:<dotted.unit>:<kind>``) with staleness enforcement: an
+entry matching no finding is itself a finding.
 """
 
 from __future__ import annotations
@@ -404,8 +403,7 @@ def device_sync(project: Project) -> list[Finding]:
             f"'{s.exempt_id}' in DEVICE_SYNC_EXEMPT with a "
             "justification"))
 
-    # exemption hygiene: the registry must not rot (kernel-parity's
-    # staleness discipline)
+    # exemption hygiene: the registry must not rot
     for eid, (reason, line) in sorted(exempt.items()):
         if eid not in used_exemptions:
             findings.append(Finding(

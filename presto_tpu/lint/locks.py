@@ -59,10 +59,6 @@ LOCK_SCOPES = (
     "presto_tpu/engine.py",
     # per-thread session overrides + the shared property dict
     "presto_tpu/session.py",
-    # kernel dispatch state (ambient backend + per-node collection)
-    # is read by concurrently-tracing queries; the package must obey
-    # the same discipline as the interpreters that install it
-    "presto_tpu/kernels/",
 )
 
 _LOCK_NAME_RE = re.compile(

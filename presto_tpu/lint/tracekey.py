@@ -15,9 +15,8 @@ rule machine-checks).
 The rule rides the jit-reachability call graph (lint/tracer.py
 ``CallGraph``) from the trace entry points — the
 ``PlanInterpreter``/``ShardedInterpreter`` ``_r_*`` dispatch, the
-``ExprCompiler`` ``_c_*`` dispatch, the ``kernels/`` package behind
-its dispatch table, ``templates/runtime.py``, and the jit/shard_map
-roots themselves — and reports three finding classes:
+``ExprCompiler`` ``_c_*`` dispatch, ``templates/runtime.py``, and the
+jit/shard_map roots themselves — and reports three finding classes:
 
 - **unsound-read**: a ``session.get``/``os.environ``/``os.getenv``
   read reachable from a trace entry whose key is not in
@@ -38,9 +37,9 @@ roots themselves — and reports three finding classes:
 
 Deliberate host-control-plane reads and content-derived memoization
 caches are declared in ``exec/progcache.TRACE_KEY_EXEMPT`` (id ->
-justification). Exemptions carry the same staleness enforcement as the
-kernel-parity registry: an entry that matches no finding this run is
-itself a finding, so the registry cannot rot into a blanket waiver.
+justification). Exemptions carry staleness enforcement: an entry that
+matches no finding this run is itself a finding, so the registry
+cannot rot into a blanket waiver.
 
 Exemption id forms: ``session:<property>``, ``env:<NAME>``,
 ``global:<relpath>:<NAME>``, ``key:<property>`` (stale-key-entry),
@@ -60,10 +59,9 @@ from presto_tpu.lint.tracer import (TRACE_SCOPES, CallGraph, _FnUnit,
 RULE = "tracekey"
 
 # where the trace-time code lives: the tracer family's scopes plus the
-# kernel bodies, the template runtime, and the cost helpers the
-# interpreters call mid-trace (cost/model.decide_join_distribution)
+# template runtime and the cost helpers the interpreters call
+# mid-trace (cost/model.decide_join_distribution)
 SCOPES = TRACE_SCOPES + (
-    "presto_tpu/kernels/",
     "presto_tpu/templates/",
     "presto_tpu/cost/",
 )
@@ -79,7 +77,7 @@ _MUTATORS = {"append", "extend", "insert", "remove", "pop", "popitem",
              "appendleft", "extendleft"}
 
 
-# -- registry parsing (static, like lint/kernels.py) ------------------------
+# -- registry parsing (static) ----------------------------------------------
 
 def _literal_tuple(mod: SourceModule, name: str
                    ) -> dict[str, int] | None:
@@ -111,9 +109,9 @@ def _trace_roots(graph: CallGraph) -> set[tuple]:
     """Entry points of trace-time execution: jit/shard_map roots (the
     traced closures), every method of a ``_r_*``/``_c_*`` dispatch
     class (the interpreter/compiler pattern: ``run``/``compile``
-    reaches handlers through getattr, so the whole class is live), the
-    whole kernels package (entered through its dispatch table), and
-    the template runtime (entered through ir.Parameter resolution)."""
+    reaches handlers through getattr, so the whole class is live),
+    and the template runtime (entered through ir.Parameter
+    resolution)."""
     roots, _statics = graph.find_roots()
     roots = set(roots)
     for (relpath, _cname), method_paths in graph.classes.items():
@@ -122,9 +120,7 @@ def _trace_roots(graph: CallGraph) -> set[tuple]:
                 if (relpath, p) in graph.units:
                     roots.add((relpath, p))
     for key, u in graph.units.items():
-        rp = u.mod.relpath
-        if rp.startswith("presto_tpu/kernels/") or \
-                rp == "presto_tpu/templates/runtime.py":
+        if u.mod.relpath == "presto_tpu/templates/runtime.py":
             roots.add(key)
     return roots
 
@@ -656,8 +652,7 @@ def tracekey(project: Project) -> list[Finding]:
             f"'global:{relpath}:{gname}' in TRACE_KEY_EXEMPT with a "
             "justification"))
 
-    # exemption hygiene: the registry must not rot (kernel-parity's
-    # staleness discipline)
+    # exemption hygiene: the registry must not rot
     for eid, (reason, line) in sorted(exempt.items()):
         if eid not in used_exemptions:
             findings.append(Finding(

@@ -275,8 +275,8 @@ class CallGraph:
             self.mod_by_name[m.modname] = m
             if m.modname.endswith(".__init__"):
                 # a package's functions are addressed through the
-                # package name (`from presto_tpu import kernels as K;
-                # K.dispatch(...)`), never through ``.__init__``
+                # package name (`from presto_tpu import lint`), never
+                # through ``.__init__``
                 self.mod_by_name[m.modname[:-len(".__init__")]] = m
         self.by_name: dict[tuple[str, str], list[_FnUnit]] = {}
         for u in self.units.values():

@@ -522,7 +522,6 @@ def _record_program(engine, rec: TaskRecorder, plan, meta, counts,
         # colliding planNodeIds
         program = rec.programs
         rec.programs += 1
-    kernels_by_pos = meta.get("kernels") or {}
     ops: list[dict] = []
     weights: list[int] = []
     node_shapes: list[tuple[str, int, int, int]] = []
@@ -546,7 +545,6 @@ def _record_program(engine, rec: TaskRecorder, plan, meta, counts,
             "inputRows": -1 if in_rows is None else int(in_rows),
             "outputRows": int(rows), "outputBytes": int(nbytes),
             "estRows": -1 if est is None else int(est),
-            "kernel": ",".join(kernels_by_pos.get(pos) or ()),
         })
         weights.append((0 if in_rows is None else int(in_rows))
                        + int(rows) + 1)

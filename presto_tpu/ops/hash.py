@@ -41,13 +41,12 @@ _NULL_KEY_HASH = np.uint64(0x9E3779B97F4A7C15)
 
 
 class HashChainOverflow(RuntimeError):
-    """A hash-table kernel gave up LOUDLY: a probe chain exceeded its
-    bound (Pallas open addressing, ``max_probes``) or a group count
-    exceeded every capacity the retry ladder was willing to try
-    (``max_rounds`` analog). Raised by the executor when the capacity
-    retry ladder exhausts — the in-kernel bound itself surfaces as a
-    failed ``ok`` flag that the ladder catches and retries at a
-    larger capacity, counted per occurrence in
+    """A hash table gave up LOUDLY: a group or build count exceeded
+    every capacity the retry ladder was willing to try (``max_rounds``
+    analog). Raised by the executor when the capacity retry ladder
+    exhausts — the in-program bound itself surfaces as a failed ``ok``
+    flag that the ladder catches and retries at a larger capacity,
+    counted per occurrence in
     ``presto_tpu_hash_probe_overflow_total``. Subclasses RuntimeError
     so callers matching the ladder's historical exception keep
     working."""
@@ -96,10 +95,9 @@ def note_capacity_retry(kind: str) -> None:
 
 
 def note_probe_overflow(count: int = 1) -> None:
-    """Count a kernel-reported hash-TABLE overflow — a bounded probe
-    chain giving up (Pallas open addressing) or a group/build count
-    exceeding its table capacity (the max_rounds analog). The loud
-    path of what used to be a silent give-up; output/compaction
+    """Count a program-reported hash-TABLE overflow — a group/build
+    count exceeding its table capacity (the max_rounds analog). The
+    loud path of what used to be a silent give-up; output/compaction
     capacity retries are deliberately NOT counted here."""
     from presto_tpu.obs.metrics import REGISTRY
     REGISTRY.counter(
@@ -401,6 +399,24 @@ def probe_runs(build_hash, build_live, probe_hash, probe_live):
     lo_p, cnt_p = lo_o[nb:], cnt_o[nb:]
     found = probe_live & (cnt_p > 0)
     return lo_p, jnp.where(found, cnt_p, 0), found
+
+
+def lookup_join(build_hash, build_live, probe_hash, probe_live):
+    """FK->PK join lookup by sorted merge: returns (build_row int32
+    [n_probe] (-1 = none), found bool [n_probe]). ``found`` = live
+    probe row whose 64-bit combined hash equals a live build row's;
+    on duplicate build keys the representative is the LARGEST build
+    row index (the last row of the run). Residual 64-bit collisions
+    are the caller's to verify by value
+    (exec/operators._verify_keys). No table, so nothing to size and
+    nothing that can overflow."""
+    nb = build_hash.shape[0]
+    _bsh, bsidx = sort_build_side(build_hash, build_live)
+    lo, count, found = probe_runs(build_hash, build_live,
+                                  probe_hash, probe_live)
+    build_row = jnp.where(
+        found, bsidx[jnp.clip(lo + count - 1, 0, nb - 1)], -1)
+    return build_row, found
 
 
 def _probe_sorted(table_hash, row_hash, live):

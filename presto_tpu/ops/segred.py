@@ -114,41 +114,7 @@ def _sum_int64_like(data, segment_ids, num_segments: int, out_dtype):
     return acc.astype(out_dtype)
 
 
-def _pallas_kernel(name: str, data, num_segments: int):
-    """The Pallas segmented kernel for this call, or None. Consulted
-    FIRST by every public entry: where the trace's backend for this
-    kernel is pallas, eligible folds accumulate per tile on-chip
-    (presto_tpu/kernels/segagg.py) instead of paying the MXU one-hot
-    matmuls / emulated scatters below. Integer-only on purpose — the
-    sequential tile walk is bit-identical there; float sums would
-    reassociate."""
-    from presto_tpu import kernels as K
-    if K.backend_for(name) != "pallas":
-        return None
-    from presto_tpu.kernels import segagg
-    ok = (segagg.sum_eligible(data, num_segments) if name == "agg_sum"
-          else segagg.cmp_eligible(data, num_segments))
-    return K.dispatch(name) if ok else None
-
-
-def _note_xla(name: str) -> None:
-    """Attribute an XLA-path fold against the tracing plan node (the
-    Pallas kernels self-note; the direct paths below must too, or
-    Aggregate operators would show empty kernel columns exactly on
-    the backend comparisons the attribution exists for)."""
-    from presto_tpu import kernels as K
-    K.note(f"xla:{name}")
-
-
 def segment_sum(data, segment_ids, num_segments: int, **kwargs):
-    fn = _pallas_kernel("agg_sum", data, num_segments)
-    if fn is not None:
-        return fn(data, segment_ids, num_segments)
-    _note_xla("agg_sum")
-    return xla_segment_sum(data, segment_ids, num_segments, **kwargs)
-
-
-def xla_segment_sum(data, segment_ids, num_segments: int, **kwargs):
     dt = data.dtype
     if _use_fast_path(data, num_segments, MAX_MATMUL_K) and (
             jnp.issubdtype(dt, jnp.integer) or dt == jnp.bool_):
@@ -201,14 +167,6 @@ def _cmp_eligible(data, num_segments: int) -> bool:
 
 
 def segment_max(data, segment_ids, num_segments: int, **kwargs):
-    fn = _pallas_kernel("agg_max", data, num_segments)
-    if fn is not None:
-        return fn(data, segment_ids, num_segments)
-    _note_xla("agg_max")
-    return xla_segment_max(data, segment_ids, num_segments, **kwargs)
-
-
-def xla_segment_max(data, segment_ids, num_segments: int, **kwargs):
     if _cmp_eligible(data, num_segments):
         return _cmp_reduce(data, segment_ids, num_segments, True)
     return jax.ops.segment_max(data, segment_ids,
@@ -216,14 +174,6 @@ def xla_segment_max(data, segment_ids, num_segments: int, **kwargs):
 
 
 def segment_min(data, segment_ids, num_segments: int, **kwargs):
-    fn = _pallas_kernel("agg_min", data, num_segments)
-    if fn is not None:
-        return fn(data, segment_ids, num_segments)
-    _note_xla("agg_min")
-    return xla_segment_min(data, segment_ids, num_segments, **kwargs)
-
-
-def xla_segment_min(data, segment_ids, num_segments: int, **kwargs):
     if _cmp_eligible(data, num_segments):
         return _cmp_reduce(data, segment_ids, num_segments, False)
     return jax.ops.segment_min(data, segment_ids,

@@ -112,9 +112,6 @@ class ShardedInterpreter:
         # psum per node; EXPLAIN ANALYZE reads the same outputs)
         self.collect_counts = True
         self.row_counts: list[tuple[object, object, str]] = []
-        # per-node kernel attribution (presto_tpu/kernels/), mirrors
-        # PlanInterpreter.kernel_used
-        self.kernel_used: dict[object, list[str]] = {}
 
     # -- plumbing shared with the local interpreter -------------------------
 
@@ -156,13 +153,8 @@ class ShardedInterpreter:
         self.ok_keys.append(self._node_key(node, kind))
 
     def run(self, node: N.PlanNode) -> DistTable:
-        from presto_tpu import kernels as K
         m = getattr(self, "_r_" + type(node).__name__.lower())
-        with K.collect() as used:
-            out = m(node)
-        if used:
-            self.kernel_used[
-                self.node_order.get(id(node), id(node))] = list(used)
+        out = m(node)
         if self.dyn_filters:
             dt = PlanInterpreter._apply_dyn_filters(self, out.dt)
             if dt is not out.dt:
@@ -675,11 +667,7 @@ class ShardedInterpreter:
             else:
                 build_dts.append(b.dt if b.dist == REPLICATED
                                  else _gather(b.dt, self.nshards))
-        default = next_pow2(
-            2 * max(max((b.n for b in build_dts), default=1), 1))
-        cap = self._capacity(node, default)
-        out, ok = OP.apply_multi_join(spine_dt, build_dts, node,
-                                      growth=max(1, cap // default))
+        out, ok = OP.apply_multi_join(spine_dt, build_dts, node)
         self._note_ok(node, ok)
         if spine.dist == REPLICATED:
             return DistTable(out, REPLICATED)
@@ -1040,7 +1028,6 @@ def execute_plan_distributed(engine, plan: N.PlanNode,
             meta: dict[str, object] = {}
 
             def traced_fn(*args):
-                from presto_tpu import kernels as K
                 it = iter(args)
                 scans = {}
                 per_scan: dict[int, dict] = {}
@@ -1050,23 +1037,19 @@ def execute_plan_distributed(engine, plan: N.PlanNode,
                     scans[id(scan.node)] = (scan, per_scan[i])
                 interp = ShardedInterpreter(scans, capacities, nshards,
                                             engine.session, node_order)
-                backend = K.resolve(interp.session)
                 if tpl is not None:
                     from presto_tpu.templates import runtime as TR
                     tp = TR.TraceParams(list(it))
-                    with TR.active(tp), K.use_backend(backend):
+                    with TR.active(tp):
                         out = interp.run(plan).dt
                     meta["param_bindings"] = dict(tp.bindings)
                 else:
-                    with K.use_backend(backend):
-                        out = interp.run(plan).dt
+                    out = interp.run(plan).dt
                 meta["out"] = [
                     (sym, v.dtype, v.dictionary, v.valid is not None)
                     for sym, v in out.cols.items()]
                 meta["ok_keys"] = interp.ok_keys
                 meta["used_capacity"] = interp.used_capacity
-                meta["kernel_backend"] = backend
-                meta["kernels"] = dict(interp.kernel_used)
                 meta["count_nodes"] = [
                     (nid, dist) for nid, _, dist in interp.row_counts]
                 res = []

@@ -111,7 +111,7 @@ _QUERY_PAGE = """<!doctype html>
 const QID={qid_js};
 let BOOT={boot_js};
 const OPCOLS=['nodeType','label','inputRows','outputRows','estRows',
-'wallMillis','flops','hbmBytes','intensity','roofline','kernel'];
+'wallMillis','flops','hbmBytes','intensity','roofline'];
 function fmt(v){{if(typeof v==='number'&&!Number.isInteger(v))
 return v.toFixed(3);return v==null||v===-1?'':v}}
 function render(info){{

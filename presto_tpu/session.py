@@ -242,19 +242,6 @@ SYSTEM_SESSION_PROPERTIES: dict[str, tuple[Any, type, str]] = {
                                  "(templates/shapes.py); only "
                                  "consulted when plan_templates is "
                                  "on"),
-    "kernel_backend": ("auto", str,
-                       "operator inner-loop implementation: auto "
-                       "(per kernel: Pallas on a TPU for the "
-                       "kernels in kernels.AUTO_PALLAS, the XLA "
-                       "whole-array twin otherwise) | pallas (force "
-                       "the kernels — on the CPU platform they run "
-                       "under pallas_call(interpret=True), which is "
-                       "how the CPU test tier executes the kernel "
-                       "bodies; elsewhere they compile or the "
-                       "query fails with the compiler's message) | "
-                       "xla (force the fallbacks). Numerically "
-                       "identical results either way "
-                       "(presto_tpu/kernels/)"),
     "task_request_timeout_s": (300.0, float,
                                "HTTP deadline for coordinator->worker "
                                "task POSTs (was hard-coded 300)"),

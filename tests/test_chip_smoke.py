@@ -1,5 +1,5 @@
 """What chip_smoke.py rests on, held on the CPU: its steps run at
-SF 0.01 (Pallas interpreted, the mesh on virtual devices) — but
+SF 0.01 (the mesh on virtual devices) — but
 ``main`` still refuses to pass off the chip — plus the two start-up
 properties a one-process-per-chip device needs: the compile cache goes
 where JAX_COMPILATION_CACHE_DIR says, and importing the program
@@ -28,14 +28,13 @@ def test_served_leg_answers_equal_numpy(smoke_engine, capsys):
     engine, conn = smoke_engine
     # every check inside raises SmokeFailure: exact answers against
     # the NumPy reference, zero compiles for literal variants, a
-    # changed answer after INSERT, kernel tags in operator_stats
+    # changed answer after INSERT
     chip_smoke.served_leg(engine, conn)
     out = capsys.readouterr().out
     for label in ("q06", "q01", "q03", "q06 variant", "q01 variant",
                   "q03 variant", "select after ctas",
                   "select after insert"):
         assert f"[served] {label}: equals the NumPy reference" in out
-    assert "[kernels] q01:" in out and "xla:agg_sum" in out
     assert chip_smoke.pinned_lineitem_bytes(conn) > 0
 
 
@@ -46,38 +45,6 @@ def test_served_leg_fails_on_a_wrong_answer(monkeypatch):
     with pytest.raises(chip_smoke.SmokeFailure,
                        match="q06: answer differs"):
         chip_smoke.served_leg(engine, conn)
-
-
-def test_kernel_leg_covers_the_dispatch_table(smoke_engine):
-    from presto_tpu import kernels as K
-    _engine, conn = smoke_engine
-    outcome = chip_smoke.kernel_leg(conn)
-    assert set(outcome) == set(K.KERNELS)
-    # on the CPU every kernel body runs (interpreted) and equals its
-    # XLA twin; what the TPU compiler says is the chip run's to report
-    for name, line in outcome.items():
-        assert line.startswith("interpreted: equals xla twin"), (name,
-                                                                line)
-        assert f"pallas:{name}" in line, (name, line)
-
-
-def test_kernel_leg_raises_for_a_refused_auto_kernel(smoke_engine,
-                                                     monkeypatch):
-    from presto_tpu import kernels as K
-    _engine, conn = smoke_engine
-
-    def refuse(*_a, **_k):
-        raise ValueError("Cannot store scalars to VMEM")
-
-    monkeypatch.setitem(K.KERNELS["join_lookup"], "pallas", refuse)
-    # not selected by auto: the refusal is a line, not a failure
-    outcome = chip_smoke.kernel_leg(conn)
-    assert outcome["join_lookup"].startswith(
-        "refused: ValueError: Cannot store scalars to VMEM")
-    # selected by auto: it fails the smoke
-    monkeypatch.setattr(K, "auto_backend", lambda name: "pallas")
-    with pytest.raises(ValueError, match="Cannot store scalars"):
-        chip_smoke.kernel_leg(conn)
 
 
 def test_mesh_leg_on_virtual_devices(smoke_engine, capsys):
@@ -102,7 +69,7 @@ def test_main_refuses_to_pass_without_a_chip(capsys):
 _STARTUP_CHILD = """
 import json
 import presto_tpu.exec.executor, presto_tpu.server.server
-import presto_tpu.kernels, presto_tpu.client, presto_tpu.cli
+import presto_tpu.client, presto_tpu.cli
 import jax
 from jax._src import xla_bridge
 print(json.dumps({
@@ -126,6 +93,6 @@ def test_startup_cache_dir_and_no_backend_on_import(env_dir):
     # in code; unset: a fixed path inside the checkout
     assert out["cache_dir"] == (env_dir
                                 or os.path.join(REPO, ".xla_cache"))
-    # importing the executor, server, kernels, client and CLI claims
+    # importing the executor, server, client and CLI claims
     # no device (a parent that plans and children that execute)
     assert out["backends_initialized"] is False

@@ -1044,7 +1044,7 @@ def test_full_suite_wall_time_budget():
     tracekey provenance pass included, riding the tracer family's
     cached call-graph machinery and per-module unit walks: the
     whole-package run must stay inside an interactive budget (locally
-    ~3-4 s with all thirteen families; the bound leaves headroom for a
+    ~3-4 s with all twelve families; the bound leaves headroom for a
     loaded CI container but catches the per-rule re-walk regression
     class, which tripled it)."""
     import time
@@ -1086,70 +1086,6 @@ def test_unparseable_file_is_a_usage_error_not_a_traceback(tmp_path,
     (bad / "scratch.py").write_text("def broken(:\n")
     assert lint_main([str(tmp_path / "presto_tpu")]) == 2
     assert "cannot parse" in capsys.readouterr().err
-
-
-# -- kernel-parity ----------------------------------------------------------
-
-KERNELS_GOOD = {
-    "presto_tpu/kernels/__init__.py": """
-        from presto_tpu.kernels import body as _body
-
-        KERNELS = {
-            "thing": {"pallas": _body.thing_pallas,
-                      "xla": _body.thing_xla},
-        }
-
-        def dispatch(name):
-            return KERNELS[name]["xla"]
-    """,
-    "presto_tpu/kernels/body.py": """
-        def thing_pallas(x):
-            return x
-
-        def thing_xla(x):
-            return x
-    """,
-}
-
-
-def test_kernel_parity_clean_registry(tmp_path):
-    pkg = write_pkg(tmp_path, KERNELS_GOOD)
-    assert run_lint([pkg], rules=["kernel-parity"]) == []
-
-
-def test_kernel_parity_missing_fallback(tmp_path):
-    files = dict(KERNELS_GOOD)
-    files["presto_tpu/kernels/__init__.py"] = """
-        from presto_tpu.kernels import body as _body
-
-        KERNELS = {
-            "thing": {"pallas": _body.thing_pallas},
-        }
-
-        def dispatch(name):
-            return KERNELS[name]["pallas"]
-    """
-    pkg = write_pkg(tmp_path, files)
-    findings = run_lint([pkg], rules=["kernel-parity"])
-    assert any("no 'xla' entry" in f.message for f in findings)
-
-
-def test_kernel_parity_unregistered_pallas_kernel(tmp_path):
-    files = dict(KERNELS_GOOD)
-    files["presto_tpu/kernels/body.py"] = """
-        def thing_pallas(x):
-            return x
-
-        def thing_xla(x):
-            return x
-
-        def rogue_pallas(x):
-            return x
-    """
-    pkg = write_pkg(tmp_path, files)
-    findings = run_lint([pkg], rules=["kernel-parity"])
-    assert any("rogue_pallas" in f.message and
-               "not registered" in f.message for f in findings)
 
 
 # -- trace-key provenance (tracekey) ----------------------------------------
@@ -1332,7 +1268,7 @@ def test_tracekey_stale_key_entry(tmp_path):
 def test_tracekey_exemption_and_staleness(tmp_path):
     """TRACE_KEY_EXEMPT excuses a finding WITH a justification — and
     an exemption that stops matching becomes a finding itself (the
-    kernel-parity staleness discipline), so the registry cannot rot
+    usual staleness discipline), so the registry cannot rot
     into a blanket waiver."""
     files = {
         "presto_tpu/exec/broken.py": TRACEKEY_DIRECT_FIXTURE,
@@ -1736,35 +1672,3 @@ def test_blocking_under_lock_hostsync_by_resolution(tmp_path):
     assert len(findings) == 1, [f.format() for f in findings]
     assert "hostsync" in findings[0].message
     assert "page" in findings[0].message
-
-
-def test_kernel_parity_dangling_reference_and_exemption(tmp_path):
-    files = dict(KERNELS_GOOD)
-    files["presto_tpu/kernels/__init__.py"] = """
-        from presto_tpu.kernels import body as _body
-
-        KERNELS = {
-            "thing": {"pallas": _body.missing_pallas,
-                      "xla": _body.thing_xla},
-        }
-
-        def dispatch(name):
-            return KERNELS[name]["xla"]
-    """
-    files["presto_tpu/kernels/body.py"] = """
-        KERNEL_DISPATCH_EXEMPT = {
-            "thing_pallas": "shared helper, not an entry point",
-            "ghost_pallas": "stale",
-        }
-
-        def thing_pallas(x):
-            return x
-
-        def thing_xla(x):
-            return x
-    """
-    pkg = write_pkg(tmp_path, files)
-    findings = run_lint([pkg], rules=["kernel-parity"])
-    msgs = [f.message for f in findings]
-    assert any("does not exist" in m for m in msgs)
-    assert any("ghost_pallas" in m and "stale" in m for m in msgs)
