@@ -792,7 +792,8 @@ MAX_JOINS_PER_PROGRAM = 2
 
 def _count_joins(node: N.PlanNode) -> int:
     # a MultiJoin counts its fan-in: compile-cost-wise it carries one
-    # sorted probe per build, and counting it whole keeps _find_split
+    # probe per build (direct or sorted, as a Join counts 1 either
+    # way), and counting it whole keeps _find_split
     # from trying to cut inside the fused operator (its children hold
     # no joins, so the splitter materializes the MultiJoin subtree —
     # or, via _find_agg_input_split, the aggregate input above it)

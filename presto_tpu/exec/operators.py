@@ -538,16 +538,19 @@ def _and_key_valid(dt: DTable, keys: list[str], live):
     return live
 
 
-def _direct_probe(left: DTable, right: DTable, node: N.Join,
+def _direct_probe(left: DTable, right: DTable,
+                  criteria: list[tuple[str, str]], dense_key: tuple,
                   probe_live, build_live):
     """Direct-address probe for a dense unique build key (plan/dense.py
-    hint): scatter build row indices into a span-sized table, gather at
-    probe key offsets — no hashing, no sorts (one scatter + one gather
-    vs sort-merge's two full-width sorts; TPU sorts cost ~6ns/row/pass).
+    hint ``dense_key`` = (index into ``criteria``, lo, hi); a Join's or
+    a MultiJoin leg's): scatter build row indices into a span-sized
+    table, gather at probe key offsets — no hashing, no sorts (one
+    scatter + one gather vs sort-merge's two full-width sorts; TPU
+    sorts cost ~6ns/row/pass).
     Returns (build_row int32 [left.n] (-1 = none), found bool)."""
-    ci, lo, hi = node.dense_key
+    ci, lo, hi = dense_key
     span = hi - lo + 1
-    lk, rk = node.criteria[ci]
+    lk, rk = criteria[ci]
     bkey = right.cols[rk].data.astype(jnp.int64)
     slot = (bkey - lo).astype(jnp.int32)
     table = jnp.full((span,), -1, dtype=jnp.int32)
@@ -563,13 +566,14 @@ def _direct_probe(left: DTable, right: DTable, node: N.Join,
     return jnp.where(found, build_row, -1), found
 
 
-def _verify_rest(left: DTable, right: DTable, node: N.Join,
+def _verify_rest(left: DTable, right: DTable,
+                 criteria: list[tuple[str, str]], dense_key: tuple,
                  probe_idx, gather):
     """Value-verify the non-dense criteria (the dense key matched by
     construction; remaining equalities are exact compares against the
     unique candidate row)."""
-    ci = node.dense_key[0]
-    rest = [c for i, c in enumerate(node.criteria) if i != ci]
+    ci = dense_key[0]
+    rest = [c for i, c in enumerate(criteria) if i != ci]
     if not rest:
         return True
     return _verify_keys(left, right, rest, probe_idx, gather)
@@ -589,10 +593,12 @@ def apply_join(left: DTable, right: DTable, node: N.Join,
     probe_live = _and_key_valid(left, lkeys, left.live_mask())
 
     if node.dense_key is not None:
-        build_row, found = _direct_probe(left, right, node,
-                                         probe_live, build_live)
+        build_row, found = _direct_probe(left, right, node.criteria,
+                                         node.dense_key, probe_live,
+                                         build_live)
         gather = jnp.clip(build_row, 0, right.n - 1)
-        verify = _verify_rest(left, right, node, None, gather)
+        verify = _verify_rest(left, right, node.criteria,
+                              node.dense_key, None, gather)
         if verify is not True:
             found = found & verify
     else:
@@ -649,10 +655,13 @@ def apply_multi_join(spine: DTable, builds: list[DTable],
     replaces materialized (and in segmented execution, compacted and
     re-uploaded) an intermediate DTable per join.
 
-    Each step is the sorted lookup of ops/hash.lookup_join, written
-    out here because the probe keys of step k are hashed after step
-    k-1's gather. Nothing can overflow: returns (DTable, ok) with ok
-    always True (kept for the same reason as apply_join's)."""
+    A leg with a dense-key hint (``node.dense_keys``, plan/dense.py)
+    takes apply_join's direct-address body: one scatter, one gather,
+    no hash, no sort, the leg's other criteria verified by value. A
+    leg without one is the sorted lookup of ops/hash.lookup_join,
+    written out here because the probe keys of step k are hashed after
+    step k-1's gather. Nothing can overflow: returns (DTable, ok) with
+    ok always True (kept for the same reason as apply_join's)."""
     # one scope per build under the node's own (MultiJoin#n/build<k>,
     # in plan order), so a device trace splits the probes
     out = dict(spine.cols)
@@ -665,16 +674,24 @@ def apply_multi_join(spine: DTable, builds: list[DTable],
             acc = DTable(out, live, width)
             build_live = _and_key_valid(bdt, rkeys, bdt.live_mask())
             probe_live = _and_key_valid(acc, lkeys, live)
-            rh = _row_hash(bdt, rkeys)
-            _bsh, bsidx = H.sort_build_side(rh, build_live)
-            ph = _row_hash(acc, lkeys)
-            lo, count, found = H.probe_runs(rh, build_live, ph,
-                                            probe_live)
-            build_row = jnp.where(
-                found, bsidx[jnp.clip(lo + count - 1, 0, bdt.n - 1)],
-                -1)
-            gather = jnp.clip(build_row, 0, bdt.n - 1)
-            verify = _verify_keys(acc, bdt, crit, None, gather)
+            dense_key = node.leg_dense_key(k)
+            if dense_key is not None:
+                build_row, found = _direct_probe(
+                    acc, bdt, crit, dense_key, probe_live, build_live)
+                gather = jnp.clip(build_row, 0, bdt.n - 1)
+                verify = _verify_rest(acc, bdt, crit, dense_key, None,
+                                      gather)
+            else:
+                rh = _row_hash(bdt, rkeys)
+                _bsh, bsidx = H.sort_build_side(rh, build_live)
+                ph = _row_hash(acc, lkeys)
+                lo, count, found = H.probe_runs(rh, build_live, ph,
+                                                probe_live)
+                build_row = jnp.where(
+                    found,
+                    bsidx[jnp.clip(lo + count - 1, 0, bdt.n - 1)], -1)
+                gather = jnp.clip(build_row, 0, bdt.n - 1)
+                verify = _verify_keys(acc, bdt, crit, None, gather)
             if verify is not True:
                 found = found & verify
             for sym, v in bdt.cols.items():

@@ -99,7 +99,9 @@ def estimate_plan_memory(plan: N.PlanNode, engine
             resident = cap * 16 + rows * width
         elif isinstance(node, N.MultiJoin):
             # probe-preserving fused chain: output at spine width, one
-            # sorted build side resident per leg (hash + index per row)
+            # build side resident per leg, priced as the sorted lookup
+            # (hash + index per row) whatever probe the leg takes, as
+            # the Join rule above prices a direct-address table
             rows = rows_of(node.spine)
             resident = rows * width + sum(
                 rows_of(b) * 16 for b in node.builds)

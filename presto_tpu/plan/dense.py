@@ -1,6 +1,8 @@
-"""Dense-key annotation pass: mark joins/semijoins whose build keys are
-bounded-range integers so the executor can use direct-address tables
-(one scatter + one gather) instead of sort-merge probes.
+"""Dense-key annotation pass: mark joins, the legs of a fused MultiJoin
+and semijoins whose build keys are bounded-range integers so the
+executor can use direct-address tables (one scatter + one gather)
+instead of sort-merge probes. A Join and a MultiJoin leg are judged by
+one rule (:func:`dense_hint`).
 
 TPC-H/TPC-DS surrogate keys are dense 1..n integers (the reference ships
 the same fact as connector column statistics,
@@ -238,8 +240,36 @@ def _int_typed(types: dict, sym: str) -> bool:
     return isinstance(t, (T.BigintType, T.IntegerType, T.DateType))
 
 
+def dense_hint(build: N.PlanNode, criteria: list[tuple[str, str]],
+               build_rows: int | None, engine) -> tuple | None:
+    """The direct-address hint (criterion index, lo, hi) of a unique
+    build probed on ``criteria``, or None: the first criterion whose
+    build key is an integer with a connector range the span gate
+    admits (cost/model.dense_span_eligible) and, where the build is
+    probed on more than one criterion, is a unique key alone (the
+    others are then verified by value against the one candidate row).
+    Shared by a binary Join and a MultiJoin leg."""
+    ranges = symbol_ranges(build, engine)
+    types = build.output_types()
+    uniques = None
+    for i, (_lk, rk) in enumerate(criteria):
+        if rk not in ranges or not _int_typed(types, rk):
+            continue
+        if not _eligible_span(ranges[rk], build_rows):
+            continue
+        if len(criteria) > 1:
+            if uniques is None:
+                uniques = unique_key_sets(build, engine)
+            if frozenset([rk]) not in uniques:
+                continue
+        lo, hi = ranges[rk]
+        return (i, lo, hi)
+    return None
+
+
 def annotate_dense(plan: N.PlanNode, engine) -> N.PlanNode:
-    """Attach dense_key hints to Join/SemiJoin nodes (bottom-up)."""
+    """Attach dense-key hints to Join, MultiJoin (one a leg) and
+    SemiJoin nodes (bottom-up)."""
 
     def visit(node: N.PlanNode) -> N.PlanNode:
         if isinstance(node, N.Join) and node.criteria \
@@ -260,23 +290,22 @@ def annotate_dense(plan: N.PlanNode, engine) -> N.PlanNode:
         if isinstance(node, N.Join) and node.criteria \
                 and node.join_type != N.JoinType.FULL \
                 and node.build_unique and node.dense_key is None:
-            ranges = symbol_ranges(node.right, engine)
-            types = node.right.output_types()
-            uniques = None
-            for i, (_lk, rk) in enumerate(node.criteria):
-                if rk not in ranges or not _int_typed(types, rk):
-                    continue
-                if not _eligible_span(ranges[rk], node.build_rows):
-                    continue
-                if len(node.criteria) > 1:
-                    if uniques is None:
-                        uniques = unique_key_sets(node.right, engine)
-                    if frozenset([rk]) not in uniques:
-                        continue
-                lo, hi = ranges[rk]
-                node = dataclasses.replace(
-                    node, dense_key=(i, lo, hi))
-                break
+            hint = dense_hint(node.right, node.criteria,
+                              node.build_rows, engine)
+            if hint is not None:
+                node = dataclasses.replace(node, dense_key=hint)
+        elif isinstance(node, N.MultiJoin):
+            # every leg is an INNER unique-build equi-join by
+            # construction; a hint an earlier pass gave stays
+            hints = [
+                node.leg_dense_key(i) or dense_hint(
+                    build, crit,
+                    node.build_rows[i] if i < len(node.build_rows)
+                    else None, engine)
+                for i, (build, crit) in enumerate(
+                    zip(node.builds, node.criteria))]
+            if hints != node.dense_keys:
+                node = dataclasses.replace(node, dense_keys=hints)
         elif isinstance(node, N.Aggregate) \
                 and len(node.group_keys) > 1 and node.fd_keys is None:
             fds = fd_singles(node.source, engine)

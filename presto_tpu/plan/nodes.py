@@ -258,9 +258,11 @@ class MultiJoin(PlanNode):
     (the collapse preserves chain order, so the sequential probe walk
     resolves them). All collapsed joins are INNER, unique-build
     (FK->PK) and residual-free by construction (plan/optimizer.py
-    collapse_multiway), so execution is probe-preserving: one sorted
-    lookup per build over the spine's static width, one fused live
-    mask, no intermediate materialization. Distributed lowering keeps
+    collapse_multiway), so execution is probe-preserving: one lookup
+    per build over the spine's static width (a direct-address probe
+    where ``dense_keys`` holds a hint for the build, the sorted lookup
+    where it does not), one fused live mask, no intermediate
+    materialization. Distributed lowering keeps
     the spine sharded, replicates small builds, and co-partitions AT
     MOST ONE large build — one repartition of the fact table where the
     cascade paid one per large join."""
@@ -273,6 +275,15 @@ class MultiJoin(PlanNode):
     # (pow2-bucketed build rows; broadcast|partitioned distribution)
     build_rows: list = dataclasses.field(default_factory=list)
     distributions: list = dataclasses.field(default_factory=list)
+    # per-build dense-int key hint from plan/dense.py, the triple
+    # Join.dense_key holds: None (sorted lookup) or (criterion index,
+    # lo, hi) of ``criteria[i]`` (direct-address probe); a missing
+    # tail reads as None
+    dense_keys: list = dataclasses.field(default_factory=list)
+
+    def leg_dense_key(self, i: int) -> tuple[int, int, int] | None:
+        """The dense hint of ``builds[i]``, or None."""
+        return self.dense_keys[i] if i < len(self.dense_keys) else None
 
     def sources(self):
         return [self.spine] + list(self.builds)

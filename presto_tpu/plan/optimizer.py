@@ -82,7 +82,9 @@ def optimize(plan: N.PlanNode, engine, nshards: int,
 
 def joins_by_kind(plan: N.PlanNode) -> dict[str, int]:
     """Joins of a finished plan by the physical join the executor will
-    run (cost/model.join_kind); a MultiJoin leg is a sorted lookup."""
+    run (cost/model.join_kind); a MultiJoin leg is a direct-address
+    probe ("dense") where it carries a hint and a sorted lookup where
+    it does not."""
     from presto_tpu.cost.model import join_kind
     kinds = {"dense": 0, "lookup": 0, "expanding": 0}
 
@@ -90,7 +92,9 @@ def joins_by_kind(plan: N.PlanNode) -> dict[str, int]:
         if isinstance(node, N.Join):
             kinds[join_kind(node)] += 1
         elif isinstance(node, N.MultiJoin):
-            kinds["lookup"] += len(node.builds)
+            for i in range(len(node.builds)):
+                kinds["dense" if node.leg_dense_key(i) is not None
+                      else "lookup"] += 1
         for s in node.sources():
             visit(s)
 
@@ -123,8 +127,9 @@ def collapse_multiway(plan: N.PlanNode, engine) -> N.PlanNode:
     :class:`~presto_tpu.plan.nodes.MultiJoin` — the TrieJax-style
     fused multi-way operator. Gated on session ``multiway_join`` and
     AUTOMATIC join reordering; annotations (pow2 build_rows, explicit
-    distributions, skew refinements) carry over per build so the
-    distributed lowering makes the same choices the cascade would."""
+    distributions, skew refinements, dense-key hints) carry over per
+    build so the distributed lowering makes the same choices the
+    cascade would."""
     session = getattr(engine, "session", None)
     if session is None:
         return plan
@@ -150,7 +155,10 @@ def collapse_multiway(plan: N.PlanNode, engine) -> N.PlanNode:
                 builds=mj.builds + [node.right],
                 criteria=mj.criteria + [list(node.criteria)],
                 build_rows=mj.build_rows + [node.build_rows],
-                distributions=mj.distributions + [_leg_dist(node)])
+                distributions=mj.distributions + [_leg_dist(node)],
+                dense_keys=[mj.leg_dense_key(i)
+                            for i in range(len(mj.builds))]
+                + [node.dense_key])
         chain: list[N.Join] = []
         cur: N.PlanNode = node
         while _collapsible(cur):
@@ -164,7 +172,8 @@ def collapse_multiway(plan: N.PlanNode, engine) -> N.PlanNode:
             builds=[j.right for j in chain],
             criteria=[list(j.criteria) for j in chain],
             build_rows=[j.build_rows for j in chain],
-            distributions=[_leg_dist(j) for j in chain])
+            distributions=[_leg_dist(j) for j in chain],
+            dense_keys=[j.dense_key for j in chain])
 
     return N.rewrite_bottom_up(plan, visit)
 
@@ -182,10 +191,11 @@ def _leg_dist(j: N.Join) -> str:
 def unfuse_multijoin(plan: N.PlanNode) -> N.PlanNode:
     """Inverse of :func:`collapse_multiway`: expand every MultiJoin
     back into its left-deep cascade of binary INNER unique-build
-    joins. The memory-pressure spill driver (exec/spill.py) partitions
-    a root-chain ``Join`` by its keys — under an enforced memory
-    budget that machinery outranks fusion, so over-budget fused plans
-    de-fuse and spill instead of failing."""
+    joins, each carrying its leg's dense-key hint. The memory-pressure
+    spill driver (exec/spill.py) partitions a root-chain ``Join`` by
+    its keys — under an enforced memory budget that machinery outranks
+    fusion, so over-budget fused plans de-fuse and spill instead of
+    failing."""
 
     def visit(node: N.PlanNode) -> N.PlanNode:
         if not isinstance(node, N.MultiJoin):
@@ -199,7 +209,8 @@ def unfuse_multijoin(plan: N.PlanNode) -> N.PlanNode:
                               if i < len(node.distributions)
                               else "automatic"),
                 build_rows=(node.build_rows[i]
-                            if i < len(node.build_rows) else None))
+                            if i < len(node.build_rows) else None),
+                dense_key=node.leg_dense_key(i))
         return cur
 
     return N.rewrite_bottom_up(plan, visit)

@@ -55,10 +55,18 @@ def _describe(node: N.PlanNode) -> str:
                 f"{_distribution(node.distribution, node.hot_keys, node.salt_factor)}]"
                 f"({crit}{extra})")
     if isinstance(node, N.MultiJoin):
+        def probe(i: int, crit: list) -> str:
+            # "direct" on the hinted criterion's build key
+            # (plan/dense.py), else the sorted lookup
+            hint = node.leg_dense_key(i)
+            return ("lookup" if hint is None
+                    else f"direct {crit[hint[0]][1]}")
+
         legs = "; ".join(
             ", ".join(f"{a} = {b}" for a, b in crit)
-            + f" [{_distribution(d, None, None)}]"
-            for crit, d in zip(node.criteria, node.distributions))
+            + f" [{_distribution(d, None, None)}, {probe(i, crit)}]"
+            for i, (crit, d) in enumerate(
+                zip(node.criteria, node.distributions)))
         return (f"MultiJoin[inner, {len(node.builds)}-way]"
                 f"({legs})")
     if isinstance(node, N.SemiJoin):

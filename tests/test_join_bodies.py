@@ -7,7 +7,7 @@ through SQL against sqlite and over an 8-shard mesh. (The folds of
 ops/segred.py are held to a scatter reference in tests/test_segred.py.)
 """
 
-import types
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -150,7 +150,7 @@ def chain(nbuilds: int, rng, dead_spine=False, barren: int | None = None):
         builds.append(table(blive, **{f"pk{k}": pk, f"fk{k}": nxt}))
         crit.append([("sk" if k == 0 else f"fk{k - 1}", f"pk{k}")])
     spine = table(spine_live, sk=spine_key)
-    node = types.SimpleNamespace(criteria=crit)
+    node = N.MultiJoin(criteria=crit)  # no hints: every leg sorted
     want = []
     for i in range(n):
         key, rows = spine_key[i], []
@@ -192,6 +192,120 @@ def test_multi_join_one_build_matches_nothing():
 def test_multi_join_dead_spine_rows_stay_dead():
     live = check_multi_join(2, 22, dead_spine=True)
     assert live.any() and not live.all()
+
+
+def hinted_chain(case: str, rng):
+    """spine(sk, sc) -> build0 on sk = pk0 -> build1 on (sc = c1,
+    fk0 = pk1), fk0 gathered by build0 and the dense criterion the
+    SECOND of the leg -> build2 on fk1 = pk2, fk1 gathered by build1.
+    Every build key is unique in [lo, hi] with holes; ``case`` says
+    which hazard the data holds ("all": every one)."""
+    on = (lambda c: case in (c, "all"))
+    n, sizes, lo = 700, (83, 59, 31), (10, -7, 1000)
+    hi = tuple(l + 2 * s - 1 for l, s in zip(lo, sizes))
+
+    def keys(k):  # unique, inside [lo, hi], about half the span used
+        return lo[k] + rng.permutation(2 * sizes[k])[:sizes[k]]
+
+    def ref(k, m):  # foreign keys into build k
+        present = rng.choice(pk[k], m)
+        if on("no_match"):  # in range, but no build row holds them
+            present = np.where(rng.random(m) < 0.3,
+                               rng.integers(lo[k], hi[k] + 1, m), present)
+        if on("out_of_range"):
+            wild = rng.choice([lo[k] - 1, hi[k] + 1, -2 ** 40, 2 ** 40,
+                               lo[k] - 2 ** 32, hi[k] + 2 ** 32], m)
+            present = np.where(rng.random(m) < 0.2, wild, present)
+        return present
+
+    def val(data, null_share):
+        valid = (jnp.asarray(rng.random(len(data)) > null_share)
+                 if on("null_keys") else None)
+        return Val(T.BIGINT, jnp.asarray(data, dtype=jnp.int64), valid)
+
+    def live(m):
+        return (jnp.asarray(rng.random(m) > 0.25) if on("dead_build")
+                else None)
+
+    pk = [keys(k) for k in range(3)]
+    c1 = rng.integers(0, 3, sizes[1])
+    fk0, fk1 = ref(1, sizes[0]), ref(2, sizes[1])
+    sk = ref(0, n)
+    # the spine's second key agrees with the row sk leads to, unless
+    # the case is the one where it must not
+    row0 = dict_join(pk[0], np.ones(sizes[0], bool), sk, np.ones(n, bool))
+    row1 = dict_join(pk[1], np.ones(sizes[1], bool), fk0[row0],
+                     row0 >= 0)
+    sc = np.where(row1 >= 0, c1[row1], 0)
+    if on("composite_disagrees"):
+        sc = np.where(rng.random(n) < 0.4, sc + 1, sc)
+    spine = OP.DTable({"sk": val(sk, 0.1), "sc": val(sc, 0.0)}, None, n)
+    builds = [
+        OP.DTable({"pk0": val(pk[0], 0.1), "fk0": val(fk0, 0.15)},
+                  live(sizes[0]), sizes[0]),
+        OP.DTable({"pk1": val(pk[1], 0.1), "c1": val(c1, 0.0),
+                   "fk1": val(fk1, 0.15)}, live(sizes[1]), sizes[1]),
+        OP.DTable({"pk2": val(pk[2], 0.1)}, live(sizes[2]), sizes[2])]
+    node = N.MultiJoin(
+        criteria=[[("sk", "pk0")], [("sc", "c1"), ("fk0", "pk1")],
+                  [("fk1", "pk2")]],
+        dense_keys=[(0, lo[0], hi[0]), (1, lo[1], hi[1]),
+                    (0, lo[2], hi[2])])
+    return spine, builds, node
+
+
+@pytest.mark.parametrize("case", [
+    "plain", "no_match", "null_keys", "dead_build", "out_of_range",
+    "composite_disagrees", "all"])
+def test_multi_join_direct_legs_equal_the_sorted_walk(case):
+    """A MultiJoin run with its dense hints equals the same node with
+    the hints cleared, row for row ("plain" also holds the later leg
+    keyed on a column an earlier direct leg gathered, as every case
+    does); then one leg at a time, so a mixed walk is held too."""
+    spine, builds, node = hinted_chain(
+        case, np.random.default_rng(sum(map(ord, case))))
+    want, _ = OP.apply_multi_join(
+        spine, builds, dataclasses.replace(node, dense_keys=[]))
+    want_live = np.asarray(want.live_mask())
+    if case in ("plain", "no_match", "dead_build"):
+        assert want_live.any()
+    if case != "plain":
+        assert not want_live.all()
+    mixes = [node.dense_keys] + [
+        [h if i == k else None for i, h in enumerate(node.dense_keys)]
+        for k in range(3)]
+    for hints in mixes:
+        got, ok = OP.apply_multi_join(
+            spine, builds, dataclasses.replace(node, dense_keys=hints))
+        assert bool(np.asarray(ok))
+        np.testing.assert_array_equal(np.asarray(got.live_mask()),
+                                      want_live)
+        assert list(got.cols) == list(want.cols)
+        for sym, w in want.cols.items():
+            g = got.cols[sym]
+            np.testing.assert_array_equal(
+                np.asarray(g.data)[want_live],
+                np.asarray(w.data)[want_live], err_msg=sym)
+            assert (g.valid is None) == (w.valid is None), sym
+            if w.valid is not None:
+                np.testing.assert_array_equal(
+                    np.asarray(g.valid)[want_live],
+                    np.asarray(w.valid)[want_live], err_msg=sym)
+
+
+def test_multi_join_direct_leg_duplicate_build_key_takes_the_last_row():
+    """What the planner promises cannot happen resolves as the sorted
+    walk resolves it: the largest live row index of the key."""
+    spine = table(sk=[5, 9, 3, 4])
+    build = table(live=np.array([1, 1, 1, 1, 0, 1], bool),
+                  pk=[5, 9, 5, 9, 5, 3], pay=[10, 11, 12, 13, 14, 15])
+    for hints in ([], [(0, 0, 15)]):
+        out, _ = OP.apply_multi_join(spine, [build], N.MultiJoin(
+            criteria=[[("sk", "pk")]], dense_keys=hints))
+        np.testing.assert_array_equal(np.asarray(out.live_mask()),
+                                      [1, 1, 1, 0])
+        np.testing.assert_array_equal(
+            np.asarray(out.cols["pay"].data)[:3], [12, 13, 15])
 
 
 # -- (c) compaction ---------------------------------------------------------
@@ -257,7 +371,7 @@ def check_direct(bkeys, blive, pkeys, plive, lo, hi):
     left = table(plive, pk=pkeys)
     right = table(blive, bk=bkeys, payload=np.arange(len(bkeys)) * 3)
     row, found = OP._direct_probe(
-        left, right, join_node((0, lo, hi)),
+        left, right, [("pk", "bk")], (0, lo, hi),
         jnp.asarray(plive), jnp.asarray(blive))
     want = dict_join(bkeys, blive, pkeys, plive)
     np.testing.assert_array_equal(np.asarray(row), want)
@@ -293,7 +407,7 @@ def test_direct_probe_keys_outside_the_hinted_range_match_nothing():
                      np.arange(-20, 220), np.ones(240, bool))
     row, found = OP._direct_probe(
         table(pk=np.arange(-20, 220)), table(bk=bkeys),
-        join_node((0, 50, 149)), jnp.ones(240, bool),
+        [("pk", "bk")], (0, 50, 149), jnp.ones(240, bool),
         jnp.ones(200, bool))
     np.testing.assert_array_equal(np.asarray(row), want)
     assert int(np.asarray(found).sum()) == 100
