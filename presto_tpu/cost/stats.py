@@ -552,3 +552,35 @@ class StatsCalculator:
         return PlanNodeStatsEstimate(
             max(rows, 1.0), {**left.symbols, **right.symbols},
             left.confident and right.confident)
+
+
+class SegmentStats(StatsCalculator):
+    """Row estimates over a plan that the executor is cutting into
+    segments (exec/executor._segment_carriers): a carrier scan stands
+    for the subtree it replaced, so a later segment is priced as if the
+    plan were whole."""
+
+    def __init__(self, engine):
+        super().__init__(engine)
+        self.carriers: dict[str, PlanNodeStatsEstimate] = {}
+
+    def _s_tablescan(self, node: N.TableScan) -> PlanNodeStatsEstimate:
+        est = (self.carriers.get(node.table)
+               if node.catalog == "__segment__" else None)
+        if est is None:
+            return super()._s_tablescan(node)
+        return PlanNodeStatsEstimate(
+            est.row_count,
+            {s: est.symbols[s] for s in node.assignments
+             if s in est.symbols}, est.confident)
+
+    def planned_width(self, mat: N.PlanNode, name: str) -> int:
+        """pow2 of twice the rows ``mat`` is estimated to hand over as
+        carrier ``name``; 0 where nothing can be estimated."""
+        from presto_tpu.ops.hash import next_pow2
+        try:
+            est = self.stats(mat)
+        except Exception:  # noqa: BLE001 - a sizing hint, never an error
+            return 0
+        self.carriers[name] = est
+        return next_pow2(2 * max(int(est.row_count), 1))

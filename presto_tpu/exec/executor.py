@@ -507,8 +507,12 @@ def make_traced(scan_inputs: list[ScanInput], plan: N.PlanNode,
     VARCHAR parameters bound against (templates/runtime.py)."""
     flat_arrays = [
         scan.arrays[sym] for scan in scan_inputs for sym in scan.arrays]
-    meta: dict[str, object] = {}
     node_order = preorder_index(plan)
+    # preorder positions of the grouped Aggregates: their rows in the
+    # per-node counts are group counts (the segment span's ``groups``)
+    meta: dict[str, object] = {"agg_nodes": [
+        node_order[id(node)] for node in N.preorder(plan)
+        if isinstance(node, N.Aggregate) and node.group_keys]}
 
     def traced_fn(*args):
         it = iter(args)
@@ -895,20 +899,22 @@ def _collect_with_carriers(plan: N.PlanNode, engine,
         if isinstance(si.node, N.TableScan)
         and si.node.catalog == "__segment__"}
 
-    def visit(node):
+    # an explicit stack, not a recursive closure: a function that
+    # names itself is a reference cycle, and this one would hold
+    # ``carriers`` (the device buffers of every hand-over) until the
+    # collector next runs, beside the next statement's
+    stack = [plan]
+    while stack:
+        node = stack.pop()
         if id(node) in carriers:
             out.append(carriers[id(node)])
-            return
-        if isinstance(node, N.TableScan):
+        elif isinstance(node, N.TableScan):
             if node.catalog == "__segment__" and node.table in by_name:
                 out.append(_rebind_carrier(by_name[node.table], node))
-                return
-            out.extend(collect_scans(node, engine))
-            return
-        for s in node.sources():
-            visit(s)
-
-    visit(plan)
+            else:
+                out.extend(collect_scans(node, engine))
+        else:
+            stack.extend(reversed(node.sources()))
     return out
 
 
@@ -935,8 +941,41 @@ def _compact_kernel(live, data, cap: int):
 _compact_jit = jax.jit(_compact_kernel, static_argnames=("cap",))
 
 
+# Narrowest carrier a hand-over compacts to. Under it a width would
+# follow the realised count from one pow2 bucket to the next (TPC-H
+# Q18's HAVING leaves 434 to 791 rows at SF10 by its QUANTITY and the
+# seed: 1,024 or 2,048 slots, two shapes of everything downstream),
+# and nothing downstream is cheaper for it.
+MIN_CARRIER_ROWS = 1 << 16
+# A planned width further than this factor above what the rows need is
+# a guess, not a plan (the planner cannot price a HAVING, and reads
+# TPC-H Q3's joins 22 times too wide): the rows then size the carrier.
+PLANNED_WIDTH_SLACK = 2
+
+
+def carrier_width(cnt: int, remembered: int, planned: int) -> int:
+    """Rows of the buffer a segment hands over (templated sizing),
+    before it is held against the program's own width. The
+    ``remembered`` width of this segment of this template where the
+    ``cnt`` live rows fit in it: reusing it exactly keeps every
+    downstream shape. Else pow2 of twice the rows, ``MIN_CARRIER_ROWS``
+    at least; but on first sight the ``planned`` width (pow2 of twice
+    the planner's row estimate: the same for every seed and, by
+    plan/stats, for every literal of a template) where it is that or
+    one step above it, so that counts on both sides of a pow2 boundary
+    (TPC-H Q10's 1.0 to 1.25 million rows by its DATE) land on one
+    width whichever a process meets first."""
+    if remembered and cnt <= remembered:
+        return int(remembered)
+    need = max(MIN_CARRIER_ROWS, next_pow2(2 * max(cnt, 1)))
+    if not remembered and need <= planned <= PLANNED_WIDTH_SLACK * need:
+        return int(planned)
+    return max(need, int(remembered))
+
+
 def device_outputs(meta, res, live, cap_floor: int | None = None,
-                   stats: dict | None = None):
+                   stats: dict | None = None, planned: int = 0,
+                   counts=None):
     """Unpack one program's (meta, res, live) into segment-carrier form
     (arrays incl. $valid/__live__, dicts, types, n). Outputs compact to
     pow2(live count) when that at least halves the buffer, so later
@@ -944,21 +983,18 @@ def device_outputs(meta, res, live, cap_floor: int | None = None,
 
     ``cap_floor`` (plan templates): None = legacy exact compaction;
     an int (0 when no width is remembered yet) switches to templated
-    sizing. Carrier widths are DATA-dependent (pow2 of the live
-    count), so a literal variant whose intermediate crosses a pow2
-    boundary would shift every downstream segment's input shape and
-    miss the template cache. Templated sizing therefore sticks to the
-    remembered per-segment width whenever the live count FITS in it
-    (reusing the width exactly is what keeps downstream shapes — and
-    so the compiled programs — identical across variants), and only
-    when the count overflows the memory does it grow, with a 2x
-    margin (the RETRY_GROWTH idea applied to widths) so nearby
-    variants land in one bucket and outliers converge after a single
-    recompile.
+    sizing (:func:`carrier_width`): a carrier's width is a static
+    shape of every program downstream, so it must not follow the
+    realised count from literal to literal or from seed to seed. A
+    remembered width that the count overflows grows, with a 2x margin,
+    and counts one capacity retry of kind ``segment`` (the programs
+    downstream compile again).
 
     ``stats`` (the ``segment`` span's attributes) gains ``width``, the
-    rows of the buffer handed over, and ``live_rows``, how many of
-    them are live."""
+    rows of the buffer handed over, ``live_rows``, how many of them
+    are live, and, from the program's per-node ``counts`` fetched in
+    the same transfer, ``groups``: the occupied slots of the largest
+    grouped Aggregate the segment ran."""
     arrays: dict = {}
     dicts: dict = {}
     types: dict = {}
@@ -975,15 +1011,22 @@ def device_outputs(meta, res, live, cap_floor: int | None = None,
         dicts[sym] = dictionary
         types[sym] = dtype
     n = int(live.shape[0])
-    cnt = HS.fetch_int(jnp.sum(live), site="segment-width")
+    agg_nodes = meta.get("agg_nodes") or ()
+    if stats is not None and counts is not None and agg_nodes:
+        cnt, node_rows = HS.fetch((jnp.sum(live), counts),
+                                  site="segment-width")
+        cnt = int(cnt)
+        rows = dict(zip(meta["count_nodes"], node_rows.tolist()))
+        stats["groups"] = max(int(rows.get(pos, 0)) for pos in agg_nodes)
+    else:
+        cnt = HS.fetch_int(jnp.sum(live), site="segment-width")
     if cap_floor is None:
         cap = max(128, next_pow2(max(cnt, 1)))
-    elif cap_floor and cnt <= cap_floor:
-        # a remembered width the count fits in: reuse it EXACTLY
-        # (0 = nothing remembered yet — must not compact to zero)
-        cap = int(cap_floor)
     else:
-        cap = max(128, next_pow2(2 * max(cnt, 1)), int(cap_floor))
+        cap = carrier_width(cnt, cap_floor, planned)
+        if cap_floor and cap > cap_floor:
+            from presto_tpu.ops.hash import note_capacity_retry
+            note_capacity_retry("segment")
     if cap <= n // 2:
         arrays, live = _compact_jit(live, arrays, cap=cap)
         n = cap
@@ -996,13 +1039,14 @@ def device_outputs(meta, res, live, cap_floor: int | None = None,
 def run_plan_device(engine, plan: N.PlanNode,
                     scan_inputs: list["ScanInput"],
                     cap_floor: int | None = None,
-                    stats: dict | None = None):
+                    stats: dict | None = None, planned: int = 0):
     """Like run_plan but keeps results as DEVICE arrays (segment
     handoff); see device_outputs. Returns (arrays, dicts, types, n,
     per-node rows=None) — the runner contract of _segment_carriers."""
-    _c, _f, meta, (res, live, _oks, _counts) = prepare_plan(
+    _c, _f, meta, (res, live, _oks, counts) = prepare_plan(
         engine, plan, scan_inputs)
-    return device_outputs(meta, res, live, cap_floor, stats) + (None,)
+    return device_outputs(meta, res, live, cap_floor, stats, planned,
+                          counts) + (None,)
 
 
 def _pool_wait(engine) -> tuple[float, float]:
@@ -1026,6 +1070,44 @@ def _contains_carrier(node: N.PlanNode, names: set[str]) -> bool:
     return any(_contains_carrier(s, names) for s in node.sources())
 
 
+def _cut_segment(plan: N.PlanNode, engine, name: str,
+                 wave_names: set[str] = frozenset()):
+    """The next subtree of ``plan`` to materialize as carrier ``name``:
+    (the subtree, what of it materializes once narrowed to the columns
+    the rest consumes, the carrier scan, ``plan`` with the scan in the
+    subtree's place); None when what is left fits one program or the
+    subtree scans a carrier of ``wave_names`` (it closes the wave)."""
+    from presto_tpu.exec.streaming import _replace_node
+    sub = _find_split(plan, engine)
+    if sub is None or _contains_carrier(sub, wave_names):
+        return None
+    needed = _needed_above(plan, sub)
+    mat = sub  # what actually materializes (possibly narrowed)
+    if needed is not None and needed < set(sub.output_symbols):
+        mat = _prune_subtree(sub, needed)
+    cnode = N.TableScan("__segment__", name,
+                        {s: s for s in mat.output_symbols},
+                        dict(mat.output_types()))
+    return sub, mat, cnode, _replace_node(plan, sub, cnode)
+
+
+def planned_carriers(engine, plan: N.PlanNode) -> list[tuple]:
+    """(materialized subtree, planned width) of every segment
+    ``_segment_carriers`` would cut ``plan`` into, in order, by planning
+    alone: what a statement's hand-overs are sized to before a row is
+    read (``carrier_width``)."""
+    from presto_tpu.cost.stats import SegmentStats
+    estimates = SegmentStats(engine)
+    out: list[tuple] = []
+    while True:
+        name = f"s{len(out)}"
+        cut = _cut_segment(plan, engine, name)
+        if cut is None:
+            return out
+        _sub, mat, _cnode, plan = cut
+        out.append((mat, estimates.planned_width(mat, name)))
+
+
 def _segment_carriers(engine, plan: N.PlanNode, pool_tag: str,
                       observer=None, runner=None):
     """Materialize many-join subtrees as device-resident carrier scans
@@ -1041,8 +1123,9 @@ def _segment_carriers(engine, plan: N.PlanNode, pool_tag: str,
     split that scans a same-wave carrier closes the wave — dependency
     order between waves is preserved exactly as the old serial loop.
 
-    ``runner(engine, mat, scans, cap_floor=None, stats=None) ->
-    (arrays, dicts, types, n, node_rows)`` substitutes the per-segment executor
+    ``runner(engine, mat, scans, cap_floor=None, stats=None,
+    planned=0) -> (arrays, dicts, types, n, node_rows)`` substitutes
+    the per-segment executor
     (EXPLAIN ANALYZE passes a profiling runner); ``observer(seg, mat,
     arrays, n, wall_s, node_rows)`` fires per materialized segment, in
     segment order.
@@ -1051,10 +1134,13 @@ def _segment_carriers(engine, plan: N.PlanNode, pool_tag: str,
     in ``engine._carrier_caps`` and only grow: without the floor, a
     literal variant whose intermediate crosses a pow2 compaction
     boundary would shift every downstream segment's input shape and
-    recompile (see device_outputs)."""
+    recompile. The first width of a segment comes from the planner's
+    row estimate where that is of the rows' order (``carrier_width``),
+    so that a fresh process lands on the shapes the last one compiled
+    whatever literal and data it meets first."""
     from presto_tpu import templates as TPL
+    from presto_tpu.cost.stats import SegmentStats
     from presto_tpu.exec import progcache as PC
-    from presto_tpu.exec.streaming import _replace_node
     from presto_tpu.plan.fingerprint import plan_fingerprint
 
     pool = getattr(engine, "memory_pool", None)
@@ -1075,6 +1161,7 @@ def _segment_carriers(engine, plan: N.PlanNode, pool_tag: str,
         # overshoot the budget by (width-1) intermediates
         width = 1
     carriers: dict[int, ScanInput] = {}
+    estimates = SegmentStats(engine)
     seg = 0
     while True:
         # -- discover one wave of independent segments structurally --
@@ -1082,19 +1169,13 @@ def _segment_carriers(engine, plan: N.PlanNode, pool_tag: str,
         wave_names: set[str] = set()
         probe = plan
         while True:
-            sub = _find_split(probe, engine)
-            if sub is None or _contains_carrier(sub, wave_names):
-                break
-            needed = _needed_above(probe, sub)
-            mat = sub  # what actually materializes (possibly narrowed)
-            if needed is not None and needed < set(sub.output_symbols):
-                mat = _prune_subtree(sub, needed)
             name = f"s{seg + len(wave)}"
-            cnode = N.TableScan("__segment__", name,
-                                {s: s for s in mat.output_symbols},
-                                dict(mat.output_types()))
-            probe = _replace_node(probe, sub, cnode)
-            wave.append((sub, mat, cnode))
+            cut = _cut_segment(probe, engine, name, wave_names)
+            if cut is None:
+                break
+            sub, mat, cnode, probe = cut
+            wave.append((sub, mat, cnode,
+                         estimates.planned_width(mat, name)))
             wave_names.add(name)
         if not wave:
             break
@@ -1116,7 +1197,7 @@ def _segment_carriers(engine, plan: N.PlanNode, pool_tag: str,
         _task_rec = _qs.current_task()
 
         def _materialize(item):
-            idx, mat = item
+            idx, mat, planned = item
             _cancel.install(_tok)
             install_override(_ov)
             # the ambient stats recorder rides along too: segment
@@ -1131,7 +1212,8 @@ def _segment_carriers(engine, plan: N.PlanNode, pool_tag: str,
                 floor = (carrier_caps.get((tfp, seg + idx), 0)
                          if tpl_mode else None)
                 out = run(engine, mat, scans, cap_floor=floor,
-                          stats=None if span is None else span.attrs)
+                          stats=None if span is None else span.attrs,
+                          planned=planned)
             if pool is not None:
                 # reserve inside the job, as the serial loop did: an
                 # over-budget pipeline must raise MemoryLimitExceeded
@@ -1147,10 +1229,11 @@ def _segment_carriers(engine, plan: N.PlanNode, pool_tag: str,
 
         results = PC.map_parallel(
             _materialize,
-            [(i, mat) for i, (_s, mat, _c) in enumerate(wave)], width)
+            [(i, mat, planned)
+             for i, (_s, mat, _c, planned) in enumerate(wave)], width)
 
-        for (_sub, mat, cnode), (arrays, dicts, types, n, node_rows,
-                                 wall_s) in zip(wave, results):
+        for (_sub, mat, cnode, _p), (arrays, dicts, types, n, node_rows,
+                                     wall_s) in zip(wave, results):
             if observer is not None:
                 observer(seg, mat, arrays, n, wall_s, node_rows)
             carriers[id(cnode)] = ScanInput(cnode, arrays, dicts,

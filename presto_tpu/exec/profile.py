@@ -102,15 +102,17 @@ def _profiled_compile_run(engine, plan, scans):
         "hash table capacity retry limit exceeded")
 
 
-def _profiled_runner(engine, mat, scans, cap_floor=None, stats=None):
+def _profiled_runner(engine, mat, scans, cap_floor=None, stats=None,
+                     planned=0):
     """run_plan_device twin for segments: returns (arrays, dicts,
-    types, n, {node id: actual rows}). ``cap_floor`` keeps carrier
-    widths consistent with the production (templated) pipeline."""
+    types, n, {node id: actual rows}). ``cap_floor`` and ``planned``
+    keep carrier widths consistent with the production (templated)
+    pipeline."""
     meta, res, live, counts, _c, _r = _profiled_compile_run(
         engine, mat, scans)
     node_rows = _rows_by_node_id(mat, meta, counts)
-    return device_outputs(meta, res, live, cap_floor,
-                          stats) + (node_rows,)
+    return device_outputs(meta, res, live, cap_floor, stats,
+                          planned) + (node_rows,)
 
 
 def _annotate(mat, node_rows: dict | None, engine) -> dict[int, str]:

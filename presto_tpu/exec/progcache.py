@@ -154,12 +154,13 @@ TRACE_KEY_EXEMPT = {
 # key) -> justification. Stale entries are findings, like
 # TRACE_KEY_EXEMPT above.
 RETRACE_EXEMPT = {
-    "presto_tpu/exec/executor.py:device_outputs:branch":
-        "the branch on the live count IS the bucketing helper: both "
-        "arms produce bucketed carrier widths (the remembered "
-        "template width when the count fits, pow2-with-margin "
-        "regrowth when it overflows), so the data dependence is "
-        "confined to choosing between two cache-stable shapes",
+    "presto_tpu/exec/executor.py:carrier_width:branch":
+        "the branch on the live count IS the bucketing helper: every "
+        "arm produces a bucketed carrier width (the remembered "
+        "template width when the count fits, the planner's pow2 "
+        "width when the count is of its order, pow2-with-margin of "
+        "the count otherwise), so the data dependence is confined to "
+        "choosing between cache-stable shapes",
 }
 
 DEFAULT_MAX_ENTRIES = 64

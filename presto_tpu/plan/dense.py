@@ -217,21 +217,28 @@ def fd_singles(node: N.PlanNode, engine) -> dict[str, set]:
 
 def reduce_group_keys(keys: list[str], fds: dict[str, set]) -> list:
     """Minimal ordered subset of ``keys`` whose FD closure covers all
-    of them (greedy; exact enough for star-schema shapes)."""
-    kept: list[str] = []
-    covered: set = set()
-    for k in keys:
-        if k in covered:
-            continue
-        kept.append(k)
-        # closure expansion from the newly kept key
-        frontier = [k]
+    of them (greedy; exact enough for star-schema shapes). A key kept
+    before its determinant was met goes again once a later key covers
+    it: TPC-H Q18 lists ``c_name, c_custkey`` ahead of ``o_orderkey``,
+    which determines both through ``o_custkey``."""
+    def closure(start: list[str]) -> set:
+        covered: set = set()
+        frontier = list(start)
         while frontier:
-            cur = frontier.pop()
-            for dep in fds.get(cur, ()):  # noqa: B023
+            for dep in fds.get(frontier.pop(), ()):
                 if dep not in covered:
                     covered.add(dep)
                     frontier.append(dep)
+        return covered
+
+    kept: list[str] = []
+    for k in keys:
+        if k not in closure(kept):
+            kept.append(k)
+    for k in list(kept):
+        rest = [o for o in kept if o != k]
+        if k in closure(rest):
+            kept = rest
     return kept
 
 

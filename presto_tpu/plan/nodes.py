@@ -621,6 +621,13 @@ class Output(PlanNode):
         return {s: src[s] for s in self.symbols}
 
 
+def preorder(plan: PlanNode):
+    """Every node of ``plan``, a node before its sources."""
+    yield plan
+    for s in plan.sources():
+        yield from preorder(s)
+
+
 def rewrite_bottom_up(plan: PlanNode, fn) -> PlanNode:
     """Rebuild a plan bottom-up, applying ``fn`` to every node after its
     children (functional: unchanged subtrees keep their identity). The

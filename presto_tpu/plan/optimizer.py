@@ -77,6 +77,11 @@ def optimize(plan: N.PlanNode, engine, nshards: int,
         span.attrs["nshards"] = nshards
         span.attrs["joins"] = ",".join(
             f"{kind}:{n}" for kind, n in kinds.items())
+        # a SemiJoin (IN, EXISTS) marks rows and joins none: it shows
+        # where the plan has one, and no other plan's attribute moves
+        semi = sum(isinstance(n, N.SemiJoin) for n in N.preorder(plan))
+        if semi:
+            span.attrs["joins"] += f",semi:{semi}"
     return plan
 
 

@@ -2326,6 +2326,14 @@ class LogicalPlanner:
             if nd is None:
                 return _next_pow2(2 * max(1024, min(est_rows, 1 << 21)))
             prod = min(prod * max(nd, 1), 1 << 40)
+        if (1 << 21) < prod <= est_rows:
+            # more input rows than the connector says the keys have
+            # distinct values: the table will fill to that bound (TPC-H
+            # Q18 sums lineitem into its 15,000,000 orders), which is a
+            # bound and not a guess, so it is sized to it outright. The
+            # 2^21 cap below would make the first execution overflow,
+            # grow and compile the whole program a second time
+            return _next_pow2(prod)
         return _next_pow2(max(2 * min(prod, est_rows, 1 << 21), 16))
 
     def _range_offset_value(self, bvalue, key_type: T.DataType):

@@ -45,8 +45,12 @@ def _describe(node: N.PlanNode) -> str:
         return f"Project[{items}]"
     if isinstance(node, N.Aggregate):
         aggs = ", ".join(f"{s} := {c}" for s, c in node.aggs.items())
+        # the keys that are the group's identity where the others ride
+        # as payloads (plan/dense.reduce_group_keys)
+        fd = f", identity={node.fd_keys}" if node.fd_keys else ""
         return (f"Aggregate[{node.step.value}]"
-                f"(keys={node.group_keys}, cap={node.capacity}) [{aggs}]")
+                f"(keys={node.group_keys}{fd}, cap={node.capacity}) "
+                f"[{aggs}]")
     if isinstance(node, N.Join):
         crit = ", ".join(f"{a} = {b}" for a, b in node.criteria)
         extra = f", filter={node.filter}" if node.filter is not None else ""
