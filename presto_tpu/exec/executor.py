@@ -317,7 +317,8 @@ class PlanInterpreter:
                             scan.dictionaries[sym],
                             traced.get(f"{sym}$len"),
                             traced.get(f"{sym}$emask"))
-        # block-streamed scans pad the last block; the pad rows are dead
+        # a block of a streamed scan is scan_block_rows wide whatever
+        # scan.nrows says; the rows outside its live range are dead
         nrows = next(iter(traced.values())).shape[0] if traced else scan.nrows
         return DTable(cols, traced.get("__live__"), nrows)
 

@@ -10,6 +10,14 @@ partial-aggregate kernel, accumulate the per-block partial states
 rest of the plan over the merged partials. HBM holds one block at a
 time, so tables larger than device memory stream through.
 
+A block costs the host nothing: it is a view of ``scan_block_rows`` rows
+of every scanned column and two scalars, ``live_lo`` and ``live_hi``,
+from which the block program makes its own live mask (rows of the block
+in ``[live_lo, live_hi)``). The last block is the table's LAST
+``scan_block_rows`` rows, not a padded copy of its tail: the rows it
+shares with the block before are switched off by ``live_lo``, so every
+block has one shape and one program serves them all.
+
 The block program is a plan template like a resident program
 (exec/executor.prepare_plan): with session ``plan_templates`` on, the
 literals of the partial-aggregate sub-plan leave it before the program
@@ -83,6 +91,29 @@ def _replace_node(plan: N.PlanNode, target: N.PlanNode,
     return dataclasses.replace(plan, **updates) if updates else plan
 
 
+# The last component of a block program's cache key. The program's
+# signature is (columns..., live_lo, live_hi, parameters...); over a
+# masked table the last column is the table's own mask.
+STREAM_TAG = "stream-range"
+STREAM_MASKED_TAG = "stream-range-masked"
+
+
+def _ranged(traced_fn, block: int, ncols: int, masked: bool):
+    """The block program: ``make_traced``'s function, which takes
+    ``__live__`` as the scan's last array, behind one that makes it on
+    the device from the block's live range. The two bounds are traced
+    scalars, so the program is the same for every block."""
+    def block_fn(*args):
+        cols, (live_lo, live_hi) = args[:ncols], args[ncols:ncols + 2]
+        row = jax.lax.iota(np.int32, block)
+        live = (row >= live_lo) & (row < live_hi)
+        if masked:
+            live, cols = live & cols[-1], cols[:-1]
+        return traced_fn(*cols, live, *args[ncols + 2:])
+
+    return block_fn
+
+
 def try_execute_streamed(engine, plan: N.PlanNode):
     """Execute ``plan`` block-streamed, or return None if inapplicable."""
     from presto_tpu import templates as TPL
@@ -110,11 +141,19 @@ def try_execute_streamed(engine, plan: N.PlanNode):
     partial_live: list[np.ndarray] = []
     out_schema = None
 
+    # a masked table (executor.collect_scans) brings its own __live__:
+    # one more column of the block, after the others, that the program
+    # ANDs with the range it makes itself
+    masked = "__live__" in scan.arrays
+    columns = [a for sym, a in scan.arrays.items() if sym != "__live__"]
+    if masked:
+        columns.append(scan.arrays["__live__"])
+
     # what the trace needs of a block is its shapes: a cached program
-    # outlives the statement, and a block's arrays are views of (the
-    # last block: a padded copy of) the table's columns
+    # outlives the statement, and a block's arrays are views of the
+    # table's columns, the last block's too
     shapes = {sym: jax.ShapeDtypeStruct((block,) + a.shape[1:], a.dtype)
-              for sym, a in scan.arrays.items()}
+              for sym, a in scan.arrays.items() if sym != "__live__"}
     shapes["__live__"] = jax.ShapeDtypeStruct((block,), np.bool_)
     block_scan = ScanInput(scan.node, shapes, scan.dictionaries,
                            scan.types, block)
@@ -136,22 +175,11 @@ def try_execute_streamed(engine, plan: N.PlanNode):
             if tpl is not None:
                 partial = tpl.plan
             # the block program returns no row counts (collect_rows
-            # off), so it never shares an entry with a resident one
+            # off) and takes its live range as two scalars, so it never
+            # shares an entry with a resident one
             base_key = (*_cache_key(engine, partial, [block_scan], {})[0],
-                        "stream")
+                        STREAM_MASKED_TAG if masked else STREAM_TAG)
             capacities = dict(engine._caps_memory.get(base_key) or {})
-
-    def block_input(i: int) -> dict[str, np.ndarray]:
-        lo, hi = i * block, min((i + 1) * block, scan.nrows)
-        out = {}
-        for sym, a in scan.arrays.items():
-            b = a[lo:hi]
-            if hi - lo < block:
-                b = np.pad(b, [(0, block - (hi - lo))]
-                           + [(0, 0)] * (a.ndim - 1))
-            out[sym] = b
-        out["__live__"] = np.arange(block) < (hi - lo)
-        return out
 
     from presto_tpu.exec.cancel import checkpoint
     compiled = None
@@ -160,11 +188,21 @@ def try_execute_streamed(engine, plan: N.PlanNode):
     pargs: list = []
     for i in range(nblocks):
         checkpoint()
+        # nrows > block (checked above), so the last block can be the
+        # table's last ``block`` rows: full width, and the rows the
+        # block before has counted are dead by live_lo
+        lo = min(i * block, scan.nrows - block)
+        live_lo = i * block - lo
         with TRACER.span("block-input", block=i,
-                         rows=min((i + 1) * block, scan.nrows) - i * block):
-            arrays = block_input(i)
-        host_args = [arrays[sym] for sym in scan.arrays]
-        host_args.append(arrays["__live__"])
+                         rows=block - live_lo) as span:
+            host_args = [a[lo:lo + block] for a in columns]
+            if span is not None:
+                # 0 while every array of the block is a view of its
+                # column (a check of bounds, not of contents)
+                span.attrs["copied_bytes"] = sum(
+                    b.nbytes for a, b in zip(columns, host_args)
+                    if not np.may_share_memory(a, b))
+        host_args += [np.int32(live_lo), np.int32(block)]
         dev_args = None
         for _attempt in range(10):
             fresh = compiled is None
@@ -194,10 +232,11 @@ def try_execute_streamed(engine, plan: N.PlanNode):
                     collect_rows=False)
                 # a template's fingerprint holds no literal, so the
                 # variants share the name as they share the program
-                traced_fn.__name__ = program_name(
+                block_fn = _ranged(traced_fn, block, len(columns), masked)
+                block_fn.__name__ = program_name(
                     partial, base_key[0] if tpl is not None else None,
                     prefix="stream_")
-                compiled = jax.jit(traced_fn)
+                compiled = jax.jit(block_fn)
             if dev_args is None:
                 # the host's share of the copy; what is still in
                 # flight when this returns falls into ``execute``

@@ -10,6 +10,7 @@ import pytest
 from presto_tpu import Engine
 from presto_tpu import types as T
 from presto_tpu.connectors.memory import MemoryConnector
+from presto_tpu.exec import streaming as ST
 from presto_tpu.obs.metrics import REGISTRY
 from presto_tpu.testing.oracle import rows_equal
 
@@ -63,9 +64,19 @@ def _assert_oracle(oracle, sql, got, ordered):
     assert ok, msg
 
 
-def test_streamed_matches_oracle(tpch_tiny, oracle):
-    e = make_engine(tpch_tiny, 7000)
-    _assert_oracle(oracle, Q1, e.execute(Q1), ordered=True)
+@pytest.mark.parametrize("tail", ["mid", "one_row", "all_but_one"])
+@pytest.mark.parametrize("sql", [Q1, Q6], ids=["q1", "q6"])
+def test_streamed_matches_oracle(sql, tail, tpch_tiny, oracle):
+    """The last block is the table's last ``scan_block_rows`` rows, so
+    it overlaps the block before by all but the tail: with a tail of
+    one row, of all but one, and in between, no row of the overlap is
+    counted twice and none of the tail is lost."""
+    nrows = tpch_tiny.table("lineitem").nrows
+    block = {"mid": 7000, "one_row": (nrows - 1) // 3,
+             "all_but_one": -(-(nrows + 1) // 2)}[tail]
+    e = make_engine(tpch_tiny, block)
+    _assert_oracle(oracle, sql, e.execute(sql), ordered=True)
+    assert e.last_streamed_blocks == -(-nrows // block)
 
 
 def test_join_plan_does_not_stream(tpch_tiny):
@@ -142,6 +153,12 @@ def test_absent_string_literal_hits_and_matches_no_row(tpch_tiny):
     assert _COMPILED.value() == c0
 
 
+def _block_programs(engine, tag=ST.STREAM_TAG):
+    """The program cache's entries for block programs."""
+    return [ent for key, ent in engine._program_cache._entries.items()
+            if key[0][-1] == tag]
+
+
 def test_overflowed_capacities_are_remembered(tpch_tiny):
     """HIGH_CARD has no literal to hoist, so its sub-plan keys the
     cache as it is. With a first rung far too small for a block's
@@ -155,12 +172,11 @@ def test_overflowed_capacities_are_remembered(tpch_tiny):
     # two rungs or more of the block program, and the final program
     assert _COMPILED.value() - c0 >= 3
     stream_caps = [caps for key, caps in e._caps_memory.items()
-                   if key[-1] == "stream"]
+                   if key[-1] == ST.STREAM_TAG]
     assert len(stream_caps) == 1
     assert all(cap > 512 for cap in stream_caps[0].values())
     # the rungs that overflowed left the cache with their programs
-    assert sum(key[0][-1] == "stream"
-               for key in e._program_cache._entries) == 1
+    assert len(_block_programs(e)) == 1
     c0 = _COMPILED.value()
     assert e.execute(HIGH_CARD) == got
     assert _COMPILED.value() == c0, "the ladder was climbed again"
@@ -181,8 +197,7 @@ def test_plan_templates_off_compiles_per_statement(tpch_tiny):
         assert off.execute(sql) == rows
         assert _COMPILED.value() > c0
     assert (_TPL_HITS.value(), _TPL_MISSES.value()) == (h0, m0)
-    assert not any(key[0][-1] == "stream"
-                   for key in off._program_cache._entries)
+    assert not _block_programs(off)
 
 
 def test_insert_that_changes_a_dictionary_misses():
@@ -270,16 +285,15 @@ def _reachable_arrays(root):
 
 def test_cached_program_holds_no_block_of_the_table(tpch_tiny):
     """The cache outlives the statement; a block's arrays are views of
-    the table's columns or, for the last block, padded copies of them
-    (0.5-0.75 GB at SF10). The trace gets shapes, so after a hit
-    nothing the cache holds reaches an array of a block's rows or a
-    column of the table."""
+    the table's columns, every block's, so a program that kept one
+    would keep the column (0.5 GB each at SF10). The trace gets shapes,
+    so after a hit nothing the cache holds reaches an array of a
+    block's rows or a column of the table."""
     block = 7000
     e = make_engine(tpch_tiny, block)
     e.execute(Q1)
     e.execute(Q1_VARIANT)  # a hit
-    entries = [ent for key, ent in e._program_cache._entries.items()
-               if key[0][-1] == "stream"]
+    entries = _block_programs(e)
     assert len(entries) == 1
     compiled, meta, _nbytes = entries[0]
     assert getattr(compiled, "__wrapped__", None) is not None
@@ -290,3 +304,168 @@ def test_cached_program_holds_no_block_of_the_table(tpch_tiny):
     held = [a.shape for a in arrays
             if a.ndim and a.shape[0] in (block, nrows)]
     assert not held, held
+
+
+# -- a block is views and two scalars ----------------------------------------
+#
+# Block i is rows [i*block, (i+1)*block) of every column; the last one is
+# the table's last ``block`` rows, and the rows it shares with the block
+# before are dead by ``live_lo``. The live mask is made in the program.
+
+SEAM_BLOCK = 64
+SEAM_QUERIES = {
+    "count": "select count(*) from t",
+    "sum": "select sum(x), sum(y), count(y) from t",
+    "minmax": "select min(x), max(x), min(y), max(y) from t",
+    "grouped": ("select g, count(*), sum(x), min(x), max(y) from t "
+                "group by g order by g"),
+}
+SEAM_FILTER = " where x % 3 <> 1"
+
+
+def _seam_table(nrows: int, mask=None):
+    """A table whose every row changes every aggregate: x a permutation
+    (times 7), y the same with NULLs, g five groups."""
+    rng = np.random.default_rng(nrows)
+    x = rng.permutation(nrows).astype(np.int64) * 7
+    data = {"g": np.arange(nrows, dtype=np.int64) % 5, "x": x,
+            "y": x[::-1].copy()}
+    valid = {"y": np.arange(nrows) % 4 != 2}
+
+    class Conn(MemoryConnector):
+        def table(self, name):
+            return super().table(name).with_mask(mask)
+
+    conn = Conn()
+    conn.create_table("t", {"g": T.BIGINT, "x": T.BIGINT, "y": T.BIGINT},
+                      data, valid)
+    return conn, data, valid
+
+
+def _seam_engine(conn, block_rows: int) -> Engine:
+    e = Engine()
+    e.register_catalog("mem", conn)
+    e.session.catalog = "mem"
+    e.session.set("scan_block_rows", block_rows)
+    return e
+
+
+def _seam_want(kind, data, valid, keep):
+    """The answer by NumPy over the rows ``keep`` selects."""
+    def agg(sel):
+        x, y = data["x"][sel], data["y"][sel & valid["y"]]
+        return {"count": (int(sel.sum()),),
+                "sum": (int(x.sum()), int(y.sum()), len(y)),
+                "minmax": (int(x.min()), int(x.max()),
+                           int(y.min()), int(y.max()))}
+    if kind != "grouped":
+        return [agg(keep)[kind]]
+    out = []
+    for g in range(5):
+        sel = keep & (data["g"] == g)
+        a = agg(sel)
+        out.append((g, a["count"][0], a["sum"][0], a["minmax"][0],
+                    a["minmax"][3]))
+    return out
+
+
+@pytest.mark.parametrize("filtered", [False, True],
+                         ids=["all_rows", "filtered"])
+@pytest.mark.parametrize("kind", list(SEAM_QUERIES))
+@pytest.mark.parametrize("r", [1, SEAM_BLOCK // 2, SEAM_BLOCK - 1])
+@pytest.mark.parametrize("k", [1, 3])
+def test_seam_of_the_last_two_blocks(k, r, kind, filtered):
+    nrows = k * SEAM_BLOCK + r
+    conn, data, valid = _seam_table(nrows)
+    sql = SEAM_QUERIES[kind]
+    keep = np.ones(nrows, dtype=bool)
+    if filtered:
+        head, sep, rest = sql.partition(" group by")
+        sql = head + SEAM_FILTER + sep + rest
+        keep = data["x"] % 3 != 1
+    e = _seam_engine(conn, SEAM_BLOCK)
+    got = e.execute(sql)
+    assert e.last_streamed_blocks == k + 1
+    assert got == _seam_want(kind, data, valid, keep)
+    assert got == _seam_engine(conn, 0).execute(sql)
+
+
+def _spy_block_args(monkeypatch):
+    """Record what the streamed scan hands to ``jax.device_put``: one
+    list of host arguments a block."""
+    calls = []
+    real = ST.jax.device_put
+
+    def spy(x, *args, **kwargs):
+        if isinstance(x, list):
+            calls.append(list(x))
+        return real(x, *args, **kwargs)
+
+    monkeypatch.setattr(ST.jax, "device_put", spy)
+    return calls
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["plain", "masked"])
+def test_a_block_is_views_and_two_scalars(masked, monkeypatch):
+    """No array of a block is made on the host: each shares memory
+    with its column, the last block's too; the live range is two int32
+    scalars, and no ``bool[block]`` goes with the block unless the
+    table has a mask of its own (then that, as a view)."""
+    nrows = 3 * SEAM_BLOCK + 5
+    mask = (np.arange(nrows) % 7 != 3) if masked else None
+    conn, _data, _valid = _seam_table(nrows, mask)
+    e = _seam_engine(conn, SEAM_BLOCK)
+    calls = _spy_block_args(monkeypatch)
+    e.execute(SEAM_QUERIES["grouped"])
+    assert len(calls) == e.last_streamed_blocks == 4
+    tbl = conn.table("t")
+    owners = [np.asarray(c.data) for c in tbl.columns.values()]
+    owners += [np.asarray(c.valid) for c in tbl.columns.values()
+               if c.valid is not None]
+    if masked:
+        owners.append(mask)
+    for i, args in enumerate(calls):
+        *cols, live_lo, live_hi = args
+        assert len(cols) == len(owners)
+        for b in cols:
+            assert b.shape[0] == SEAM_BLOCK
+            assert any(np.shares_memory(b, a) for a in owners), i
+        assert sum(b.dtype == np.bool_ for b in cols) == 1 + masked
+        for bound in (live_lo, live_hi):
+            assert bound.dtype == np.int32 and bound.ndim == 0
+        last = i == len(calls) - 1
+        assert (int(live_lo), int(live_hi)) == (
+            (SEAM_BLOCK - 5) if last else 0, SEAM_BLOCK)
+
+
+def test_one_program_serves_every_block(tpch_tiny):
+    """The live range is traced, not static: the statement's first
+    block builds the block program and the others, the last included,
+    replay it; so does a literal variant."""
+    e = make_engine(tpch_tiny, 7000)
+    c0 = _COMPILED.value()
+    e.execute(Q6)
+    assert e.last_streamed_blocks >= 8
+    assert _COMPILED.value() - c0 == 2  # the block program, the final one
+    e.execute(Q6_VARIANT)
+    assert _COMPILED.value() - c0 == 2
+    (compiled, _meta, _nbytes), = _block_programs(e)
+    assert compiled._cache_size() == 1
+
+
+@pytest.mark.parametrize("kind", list(SEAM_QUERIES))
+def test_masked_table_streams_under_its_own_mask(kind):
+    """A table-level mask (``Table.mask``) is one more column of the
+    block, ANDed in the program with the range: dead rows stay dead in
+    every block and in the overlap of the last two."""
+    nrows = 3 * SEAM_BLOCK + SEAM_BLOCK // 2
+    mask = np.arange(nrows) % 3 != 0
+    mask[-SEAM_BLOCK:-SEAM_BLOCK // 2] ^= True  # the overlap differs
+    conn, data, valid = _seam_table(nrows, mask)
+    e = _seam_engine(conn, SEAM_BLOCK)
+    got = e.execute(SEAM_QUERIES[kind])
+    assert e.last_streamed_blocks == 4
+    assert got == _seam_want(kind, data, valid, mask)
+    assert got == _seam_engine(conn, 0).execute(SEAM_QUERIES[kind])
+    assert len(_block_programs(e, ST.STREAM_MASKED_TAG)) == 1
+    assert not _block_programs(e)
