@@ -14,7 +14,9 @@ presto_tpu.types.DecimalType.
 
 from __future__ import annotations
 
+import os
 from collections.abc import Sequence
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -75,6 +77,11 @@ CURRENTDATE = int(_D("1995-06-17"))
 
 DEC2 = T.DecimalType(12, 2)
 
+# orders' and lineitem's columns are computed from their draws in
+# blocks of this many rows, on this many threads
+_GEN_BLOCK = 1 << 20
+_GEN_THREADS = max(2, min(8, os.cpu_count() or 2))
+
 SCHEMAS: dict[str, dict[str, T.DataType]] = {
     "region": {
         "r_regionkey": T.BIGINT, "r_name": T.VARCHAR, "r_comment": T.VARCHAR,
@@ -123,13 +130,18 @@ SCHEMAS: dict[str, dict[str, T.DataType]] = {
 }
 
 
-def _pick(vocab, idx: np.ndarray) -> EncodedStrings:
-    """Select from a small vocabulary, emitting codes into the sorted
-    vocabulary directly (no per-row object strings)."""
+def _pick_table(vocab) -> tuple[np.ndarray, np.ndarray]:
+    """(code of each vocabulary entry in the sorted vocabulary, the
+    sorted vocabulary): a column picked from a small vocabulary holds
+    codes into the sorted one directly (no per-row object strings)."""
     sorted_dict, inv = np.unique(
         np.array(vocab, dtype="U64"), return_inverse=True)
-    return EncodedStrings(inv.astype(np.int32)[idx],
-                          sorted_dict.astype(object))
+    return inv.astype(np.int32), sorted_dict.astype(object)
+
+
+def _pick(vocab, idx: np.ndarray) -> EncodedStrings:
+    codes, dictionary = _pick_table(vocab)
+    return EncodedStrings(codes[idx], dictionary)
 
 
 _COMMENT_COMBOS: tuple | None = None
@@ -141,6 +153,17 @@ def _comments(rng: np.random.Generator, n: int) -> EncodedStrings:
     (Q13) and '%Customer%Complaints%' (Q16) occur with realistic rarity.
     All |words|^3 combos form one shared sorted dictionary; rows carry
     codes only, so generation is O(n) integer work."""
+    return _comment_codes(_comment_draws(rng, n), n)
+
+
+def _comment_draws(rng: np.random.Generator, n: int) -> np.ndarray:
+    """What ``_comments`` takes from the stream: three words a row."""
+    return rng.integers(0, len(COMMENT_WORDS), size=(n, 3))
+
+
+def _comment_codes(i: np.ndarray, n: int, column=None) -> EncodedStrings:
+    """The comments of the draws ``i``; ``column(dtype, block)`` fills a
+    column block by block (``orders_and_lineitem``'s, on its threads)."""
     global _COMMENT_COMBOS
     w = np.array(COMMENT_WORDS, dtype=object)
     k = len(w)
@@ -154,9 +177,12 @@ def _comments(rng: np.random.Generator, n: int) -> EncodedStrings:
         _COMMENT_COMBOS = (sorted_dict.astype(object),
                            inv.astype(np.int32))
     sorted_dict, inv = _COMMENT_COMBOS
-    i = rng.integers(0, k, size=(n, 3))
-    flat = (i[:, 0] * k + i[:, 1]) * k + i[:, 2]
-    codes = inv[flat]
+
+    def block(lo, hi):
+        j = i[lo:hi]
+        return inv[(j[:, 0] * k + j[:, 1]) * k + j[:, 2]]
+
+    codes = block(0, n) if column is None else column(np.int32, block)
     if n < (1 << 17):
         # small tables: compact to the realized values so host-side
         # dictionary scans (LIKE, unions) don't pay for the full vocab
@@ -231,7 +257,8 @@ class TpchGenerator:
         consecutive low ids (which dense-key direct tables would
         otherwise make artificially cheap)."""
         if not self.zipf:
-            return rng.integers(1, n_keys + 1, size).astype(np.int64)
+            return rng.integers(1, n_keys + 1, size).astype(
+                np.int64, copy=False)
         w = 1.0 / np.power(
             np.arange(1, n_keys + 1, dtype=np.float64), self.zipf)
         cdf = np.cumsum(w)
@@ -342,7 +369,16 @@ class TpchGenerator:
         rng = self._rng(7)
         return rng.integers(1, 8, self.n_orders)
 
-    def orders_and_lineitem(self):
+    def orders_and_lineitem(self, orders: bool = True):
+        """(orders, lineitem) columns; ``orders`` False leaves what only
+        ``orders`` needs undone and gives None for it (a deployment
+        that holds ``lineitem`` alone). The bytes of a (scale, seed)
+        are fixed by the ORDER of the draws from the two streams, which
+        this thread keeps; every column is then computed from its draws
+        in blocks of rows on a few threads (``_by_rows``: NumPy's loops
+        release the GIL), so the temporaries are a block's and a table
+        of hundreds of millions of rows takes little more than its
+        draws."""
         rng = self._rng(8)
         n = self.n_orders
         okeys = np.arange(1, n + 1, dtype=np.int64)
@@ -355,63 +391,118 @@ class TpchGenerator:
         odate = rng.integers(STARTDATE, ENDDATE - 151 + 1, n).astype(np.int32)
 
         counts = self._order_line_counts()
-        total_lines = int(counts.sum())
-        l_orderkey = np.repeat(okeys, counts)
-        l_odate = np.repeat(odate, counts)
-        # line number within its order, vectorized: global position minus
-        # the order's start offset
-        starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
-        ln = (np.arange(total_lines, dtype=np.int64)
-              - np.repeat(starts, counts) + 1)
-
+        # an order's first line, and one past the last order's last
+        starts = np.concatenate([[0], np.cumsum(counts)])
+        total = int(starts[-1])
         lrng = self._rng(9)
-        lpk = self._fk(lrng, self.n_part, total_lines)
-        lsk = _ps_suppkey(
-            lpk, lrng.integers(0, 4, total_lines), self.n_supplier)
-        qty = lrng.integers(1, 51, total_lines).astype(np.int64)
-        eprice = qty * _retailprice(lpk)  # qty * price(cents) -> cents
-        disc = lrng.integers(0, 11, total_lines).astype(np.int64)  # 0.00-0.10
-        tax = lrng.integers(0, 9, total_lines).astype(np.int64)  # 0.00-0.08
-        sdate = (l_odate + lrng.integers(1, 122, total_lines)).astype(np.int32)
-        cdate = (l_odate + lrng.integers(30, 91, total_lines)).astype(np.int32)
-        rdate = (sdate + lrng.integers(1, 31, total_lines)).astype(np.int32)
-        returned = rdate <= CURRENTDATE
-        # dictionaries sorted: ["A","N","R"], ["F","O"]
-        rflag = EncodedStrings(
-            np.where(returned,
-                     np.where(lrng.random(total_lines) < 0.5, 2, 0),
-                     1).astype(np.int32),
-            np.array(["A", "N", "R"], object))
-        open_line = sdate > CURRENTDATE
-        lstatus = EncodedStrings(open_line.astype(np.int32),
-                                 np.array(["F", "O"], object))
+        rows = [(lo, min(lo + _GEN_BLOCK, total))
+                for lo in range(0, total, _GEN_BLOCK)]
+        # blocks of whole orders, about as many lines each
+        cuts = np.searchsorted(starts, [lo for lo, _ in rows] + [total])
+        order_blocks = list(zip(cuts[:-1], cuts[1:]))
 
-        lineitem = {
-            "l_orderkey": l_orderkey,
-            "l_partkey": lpk,
-            "l_suppkey": lsk,
-            "l_linenumber": ln,
-            "l_quantity": qty * 100,  # decimal(12,2) scaled
-            "l_extendedprice": eprice,
-            "l_discount": disc,
-            "l_tax": tax,
-            "l_returnflag": rflag,
-            "l_linestatus": lstatus,
-            "l_shipdate": sdate,
-            "l_commitdate": cdate,
-            "l_receiptdate": rdate,
-            "l_shipinstruct": _pick(
-                INSTRUCTIONS,
-                lrng.integers(0, len(INSTRUCTIONS), total_lines)),
-            "l_shipmode": _pick(
-                SHIPMODES, lrng.integers(0, len(SHIPMODES), total_lines)),
-            "l_comment": _comments(lrng, total_lines),
-        }
+        with ThreadPoolExecutor(max_workers=_GEN_THREADS) as pool:
 
-        # o_totalprice = sum(extendedprice * (1+tax) * (1-discount)), rounded
-        # to cents; o_orderstatus from line statuses.
-        line_total = np.round(
-            eprice * (100 + tax) * (100 - disc) / 10000.0).astype(np.int64)
+            def column(dtype, block, by_orders=False):
+                """out[lo:hi] = block(lo, hi) over blocks of rows, or,
+                ``by_orders``, block(a, b) of whole orders [a, b) into
+                those orders' lines."""
+                out = np.empty(total, dtype=dtype)
+
+                def fill(b):
+                    lo, hi = (starts[b[0]], starts[b[1]]) if by_orders else b
+                    out[lo:hi] = block(*b)
+
+                # pure NumPy over this call's own arrays: no spans,
+                # session reads or checkpoints on the pool's threads
+                list(pool.map(fill, order_blocks if by_orders else rows))
+                return out
+
+            def picked(vocab, idx):
+                codes, dictionary = _pick_table(vocab)
+                return EncodedStrings(
+                    column(np.int32, lambda lo, hi: codes[idx[lo:hi]]),
+                    dictionary)
+
+            l_orderkey = column(
+                np.int64, lambda a, b: np.repeat(okeys[a:b], counts[a:b]),
+                by_orders=True)
+            l_odate = column(
+                np.int32, lambda a, b: np.repeat(odate[a:b], counts[a:b]),
+                by_orders=True)
+            # line number within its order: global position minus the
+            # order's start offset
+            ln = column(
+                np.int64,
+                lambda a, b: (np.arange(starts[a], starts[b], dtype=np.int64)
+                              - np.repeat(starts[a:b], counts[a:b]) + 1),
+                by_orders=True)
+            # lineitem's stream, draw by draw in its fixed order
+            lpk = self._fk(lrng, self.n_part, total)
+            i4 = lrng.integers(0, 4, total)
+            lsk = column(np.int64, lambda lo, hi: _ps_suppkey(
+                lpk[lo:hi], i4[lo:hi], self.n_supplier))
+            del i4
+            qty = lrng.integers(1, 51, total)
+            # qty * price(cents) -> cents
+            eprice = column(np.int64, lambda lo, hi: (
+                qty[lo:hi] * _retailprice(lpk[lo:hi])))
+            quantity = column(  # decimal(12,2) scaled
+                np.int64, lambda lo, hi: qty[lo:hi] * 100)
+            del qty
+            disc = lrng.integers(0, 11, total)  # 0.00-0.10
+            tax = lrng.integers(0, 9, total)  # 0.00-0.08
+            days = lrng.integers(1, 122, total)
+            sdate = column(np.int32,
+                           lambda lo, hi: l_odate[lo:hi] + days[lo:hi])
+            days = lrng.integers(30, 91, total)
+            cdate = column(np.int32,
+                           lambda lo, hi: l_odate[lo:hi] + days[lo:hi])
+            days = lrng.integers(1, 31, total)
+            rdate = column(np.int32,
+                           lambda lo, hi: sdate[lo:hi] + days[lo:hi])
+            coin = lrng.random(total)
+            # dictionaries sorted: ["A","N","R"], ["F","O"]
+            rflag = EncodedStrings(
+                column(np.int32, lambda lo, hi: np.where(
+                    rdate[lo:hi] <= CURRENTDATE,
+                    np.where(coin[lo:hi] < 0.5, 2, 0), 1)),
+                np.array(["A", "N", "R"], object))
+            del days, coin, l_odate
+            open_line = column(
+                np.int32, lambda lo, hi: sdate[lo:hi] > CURRENTDATE)
+            lstatus = EncodedStrings(open_line,
+                                     np.array(["F", "O"], object))
+            lineitem = {
+                "l_orderkey": l_orderkey,
+                "l_partkey": lpk,
+                "l_suppkey": lsk,
+                "l_linenumber": ln,
+                "l_quantity": quantity,
+                "l_extendedprice": eprice,
+                "l_discount": disc,
+                "l_tax": tax,
+                "l_returnflag": rflag,
+                "l_linestatus": lstatus,
+                "l_shipdate": sdate,
+                "l_commitdate": cdate,
+                "l_receiptdate": rdate,
+                "l_shipinstruct": picked(
+                    INSTRUCTIONS,
+                    lrng.integers(0, len(INSTRUCTIONS), total)),
+                "l_shipmode": picked(
+                    SHIPMODES, lrng.integers(0, len(SHIPMODES), total)),
+                "l_comment": _comment_codes(
+                    _comment_draws(lrng, total), total, column),
+            }
+            if not orders:
+                return None, lineitem
+
+            # o_totalprice = sum(extendedprice * (1+tax) * (1-discount)),
+            # rounded to cents; o_orderstatus from line statuses.
+            line_total = column(np.int64, lambda lo, hi: np.round(
+                eprice[lo:hi] * (100 + tax[lo:hi]) * (100 - disc[lo:hi])
+                / 10000.0))
         totalprice = np.zeros(n, dtype=np.int64)
         np.add.at(totalprice, l_orderkey - 1, line_total)
         n_open = np.zeros(n, dtype=np.int64)
@@ -491,11 +582,15 @@ class TpchConnector(Connector):
             if loaded is not None:
                 self._cache[name] = loaded
             elif name in ("orders", "lineitem"):
-                orders, lineitem = self.gen.orders_and_lineitem()
-                self._cache["orders"] = orders
+                # one pass makes both; a deployment without ``orders``
+                # leaves its columns undone
+                orders, lineitem = self.gen.orders_and_lineitem(
+                    orders=name == "orders" or "orders" in self._names)
                 self._cache["lineitem"] = lineitem
-                self._disk_store("orders", orders)
                 self._disk_store("lineitem", lineitem)
+                if orders is not None:
+                    self._cache["orders"] = orders
+                    self._disk_store("orders", orders)
             else:
                 self._cache[name] = getattr(self.gen, name)()
                 self._disk_store(name, self._cache[name])

@@ -56,6 +56,14 @@ class Engine:
         # oldest key is a KeyError)
         import threading as _t
         self._dev_cache_lock = _t.Lock()
+        # a deployment on several chips (session ``mesh_devices``):
+        # its one Mesh, made at first use and kept, and the scanned
+        # columns placed on it once, row-sharded (parallel/pins.py);
+        # dropped with the one-chip pins when a statement changes
+        # table data
+        self._mesh = None
+        from presto_tpu.parallel.pins import ShardPins
+        self.shard_pins = ShardPins()
         # runtime memory ledger: per-program tagged reservations of
         # actual input+output array bytes (memory/MemoryPool.java:44);
         # capacity 0 = unbounded (set memory_pool.capacity to enforce)
@@ -157,6 +165,36 @@ class Engine:
                 self._dev_cache_bytes -= old.nbytes
             return dev
 
+    def session_shards(self) -> int:
+        """Devices a statement under the calling thread's session runs
+        on (``mesh_devices``; 1 = this process's one chip, no mesh)."""
+        return max(1, int(self.session.get("mesh_devices") or 1))
+
+    def session_mesh(self):
+        """The mesh of a statement that was handed none: None for the
+        default ``mesh_devices`` = 1, else the engine's one Mesh over
+        the first N local devices."""
+        n = self.session_shards()
+        if n == 1:
+            return None
+        with self._dev_cache_lock:
+            if self._mesh is None:
+                import jax
+                from jax.sharding import Mesh
+                from presto_tpu.parallel.executor import AXIS
+                devices = jax.local_devices()
+                if len(devices) < n:
+                    raise ValueError(
+                        f"mesh_devices={n}, but this process has "
+                        f"{len(devices)} device(s)")
+                self._mesh = Mesh(np.array(devices[:n]), (AXIS,))
+            elif self._mesh.devices.size != n:
+                raise ValueError(
+                    f"mesh_devices={n}, but this deployment's mesh "
+                    f"has {self._mesh.devices.size} devices: it is the "
+                    "layout of the deployment, one for every statement")
+            return self._mesh
+
     # -- SQL entry points ---------------------------------------------------
 
     def execute(self, sql: str, mesh=None, cancel_token=None
@@ -177,6 +215,8 @@ class Engine:
 
         W.push(WC := W.WarningCollector())
         try:
+            if mesh is None:
+                mesh = self.session_mesh()
             stmt = rewrite_statement(parse_statement(sql), self)
             if isinstance(stmt, A.ExecutePrepared):
                 # EXECUTE name USING ...: splice the literals into the
@@ -210,6 +250,8 @@ class Engine:
 
         W.push(WC := W.WarningCollector())
         try:
+            if mesh is None:
+                mesh = self.session_mesh()
             # the served path has parsed this text before (the server,
             # then plan_sql): the span shows what parsing it again costs
             with TRACER.span("parse"):
@@ -389,6 +431,7 @@ class Engine:
         with self._dev_cache_lock:
             self._dev_cache.clear()
             self._dev_cache_bytes = 0
+        self.shard_pins.clear()
         # the template pad cache is id-keyed the same way and must not
         # serve pre-DML padded copies of in-place-mutated arrays
         from presto_tpu.templates.shapes import invalidate_pad_cache

@@ -27,6 +27,9 @@ the fold onto the systolic array instead of the (emulated) scatter unit.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
+
 import jax
 import jax.numpy as jnp
 
@@ -35,6 +38,40 @@ NLIMBS = 8  # 8-bit limbs of a 64-bit value (bf16-exact: 255 < 2^8)
 MAX_MATMUL_K = 512  # one-hot matmul path bound (flops scale with k)
 MAX_CMP_K = 128  # broadcast-compare min/max path bound
 _CHUNK_BLOCKS = 512  # lax.map granularity: bounds one-hot memory
+# a program traced under ``wide_chunks`` folds in fewer, wider steps
+_chunk_cap = contextvars.ContextVar("segred_chunk_cap",
+                                    default=_CHUNK_BLOCKS)
+
+
+@contextlib.contextmanager
+def wide_chunks(blocks: int):
+    """Segment sums traced inside fold up to ``blocks`` blocks a step
+    of the mapped loop. A loop step is a dozen device operations
+    whatever its width, so a shard of 45 million rows folded 512 blocks
+    at a time is 344 steps a sum and some 20,000 operations a
+    statement: a profiler trace of ten seconds of them took ten minutes
+    to stop. The programs of one chip keep the default (their text and
+    so their cache keys stay what they were)."""
+    token = _chunk_cap.set(blocks)
+    try:
+        yield
+    finally:
+        _chunk_cap.reset(token)
+
+
+def _chunking(nb: int, kk: int) -> tuple[int, int]:
+    """(steps, blocks a step) of the mapped fold over ``nb`` blocks
+    into ``kk`` segments."""
+    cap = _chunk_cap.get()
+    if cap == _CHUNK_BLOCKS:
+        return -(-nb // cap), cap
+    # a step's one-hot no larger than the default's at MAX_MATMUL_K
+    cap = max(_CHUNK_BLOCKS,
+              min(cap, _CHUNK_BLOCKS * (MAX_MATMUL_K + 1) // kk))
+    steps = 1 << (-(-nb // cap) - 1).bit_length()
+    if nb % steps == 0:  # equal steps: no padded copy of the column
+        return steps, nb // steps
+    return -(-nb // cap), cap
 
 
 def _use_fast_path(data, num_segments: int, bound: int) -> bool:
@@ -82,17 +119,17 @@ def _blocked_onehot_sums(u, segment_ids, k: int, nb: int):
                         preferred_element_type=jnp.float32)
         return pb.astype(jnp.float64).sum(axis=0)
 
-    if nb <= _CHUNK_BLOCKS:
+    nchunks, chunk = _chunking(nb, kk)
+    if nchunks == 1:
         return chunk_sum((sid, uu))
-    nchunks = -(-nb // _CHUNK_BLOCKS)
-    pad_b = nchunks * _CHUNK_BLOCKS - nb
+    pad_b = nchunks * chunk - nb
     if pad_b:
         sid = jnp.concatenate(
             [sid, jnp.full((pad_b, BLOCK), kk - 1, sid.dtype)])
         uu = jnp.concatenate(
             [uu, jnp.zeros((pad_b, BLOCK), uu.dtype)])
-    sid = sid.reshape(nchunks, _CHUNK_BLOCKS, BLOCK)
-    uu = uu.reshape(nchunks, _CHUNK_BLOCKS, BLOCK)
+    sid = sid.reshape(nchunks, chunk, BLOCK)
+    uu = uu.reshape(nchunks, chunk, BLOCK)
     per_chunk = jax.lax.map(chunk_sum, (sid, uu))
     return per_chunk.sum(axis=0)
 

@@ -26,6 +26,7 @@ engine's analog of DP; hash repartition is its TP/EP.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 
 import jax
@@ -42,14 +43,26 @@ from presto_tpu.exec.executor import (PlanInterpreter, ScanInput,
                                       collect_scans, preorder_index)
 from presto_tpu.exec.operators import DTable
 from presto_tpu.expr.compile import Val
+from presto_tpu.obs.metrics import REGISTRY
 from presto_tpu.obs.trace import TRACER as _TRACER
 from presto_tpu.ops import hash as H
+from presto_tpu.ops import segred
 from presto_tpu.ops.hash import next_pow2
 from presto_tpu.parallel import exchange as EX
+from presto_tpu.parallel.pins import shard_rows
 from presto_tpu.plan import nodes as N
 from presto_tpu.session import Session
 
 AXIS = "d"
+# blocks of 256 rows a step of a mesh program's segment sums
+# (ops/segred.wide_chunks): a shard of 43 x 2^20 rows folds in 8 steps
+# a sum where the default takes 344
+_FOLD_BLOCKS = 1 << 15
+
+_MESH_STATEMENTS = REGISTRY.counter(
+    "presto_tpu_mesh_statements_total",
+    "plans executed as one shard_map program over a mesh, labeled by "
+    "its device count")
 
 SHARDED = "sharded"
 REPLICATED = "replicated"
@@ -112,6 +125,10 @@ class ShardedInterpreter:
         # psum per node; EXPLAIN ANALYZE reads the same outputs)
         self.collect_counts = True
         self.row_counts: list[tuple[object, object, str]] = []
+        # preorder position of the plan node being traced (None for a
+        # node built during interpretation): what its exchanges are
+        # numbered by in a device trace
+        self._pos: int | None = None
 
     # -- plumbing shared with the local interpreter -------------------------
 
@@ -148,28 +165,53 @@ class ShardedInterpreter:
 
     def _note_ok(self, node, ok, kind: str = "table"):
         # reduce over the mesh so every shard's overflow is reported
-        self.ok_flags.append(
-            jax.lax.pmin(ok.astype(jnp.int32), AXIS) > 0)
+        with self._exchange("psum"):
+            self.ok_flags.append(
+                jax.lax.pmin(ok.astype(jnp.int32), AXIS) > 0)
         self.ok_keys.append(self._node_key(node, kind))
 
+    @contextlib.contextmanager
+    def _exchange(self, kind: str):
+        """The name an exchange's collectives carry in a device trace:
+        ``Exchange#<n>/<kind>`` inside the scope of the plan node
+        (number ``n``) that asked for it, so a trace names collectives
+        as it names operators (``kind``: gather | all_to_all | psum)."""
+        scope = ("Exchange" if self._pos is None
+                 else f"Exchange#{self._pos}")
+        with jax.named_scope(scope), jax.named_scope(kind):
+            yield
+
+    def _gather(self, dt: DTable) -> DTable:
+        with self._exchange("gather"):
+            return _gather(dt, self.nshards)
+
     def run(self, node: N.PlanNode) -> DistTable:
-        m = getattr(self, "_r_" + type(node).__name__.lower())
-        out = m(node)
-        if self.dyn_filters:
-            dt = PlanInterpreter._apply_dyn_filters(self, out.dt)
-            if dt is not out.dt:
-                out = DistTable(dt, out.dist, out.part)
-        if self.collect_counts:
-            # mesh-global live rows out of this node: per-shard count
-            # psum'd so the total is replicated (for a REPLICATED
-            # intermediate every shard holds the same rows — divide)
-            c = jnp.sum(out.dt.live_mask().astype(jnp.int64))
-            total = jax.lax.psum(c, AXIS)
-            if out.dist == REPLICATED:
-                total = total // self.nshards
-            self.row_counts.append(
-                (self.node_order.get(id(node), id(node)), total,
-                 "sharded" if out.dist == SHARDED else "replicated"))
+        kind = type(node).__name__
+        m = getattr(self, "_r_" + kind.lower())
+        # as exec/executor.PlanInterpreter.run: device operations carry
+        # the plan operator's name, and the scopes nest
+        outer, self._pos = self._pos, self.node_order.get(id(node))
+        scope = kind if self._pos is None else f"{kind}#{self._pos}"
+        with jax.named_scope(scope):
+            out = m(node)
+            if self.dyn_filters:
+                dt = PlanInterpreter._apply_dyn_filters(self, out.dt)
+                if dt is not out.dt:
+                    out = DistTable(dt, out.dist, out.part)
+            if self.collect_counts:
+                # mesh-global live rows out of this node: per-shard
+                # count psum'd so the total is replicated (for a
+                # REPLICATED intermediate every shard holds the same
+                # rows — divide)
+                c = jnp.sum(out.dt.live_mask().astype(jnp.int64))
+                with self._exchange("psum"):
+                    total = jax.lax.psum(c, AXIS)
+                if out.dist == REPLICATED:
+                    total = total // self.nshards
+                self.row_counts.append(
+                    (self.node_order.get(id(node), id(node)), total,
+                     "sharded" if out.dist == SHARDED else "replicated"))
+        self._pos = outer
         return out
 
     def _collect_dyn_filters(self, node: N.Join, build: DTable,
@@ -183,14 +225,15 @@ class ShardedInterpreter:
             # (shard-local bits would falsely prune other shards' keys)
             for lk in registered:
                 bits = self.dyn_filters[lk]
-                self.dyn_filters[lk] = jax.lax.pmax(
-                    bits.astype(jnp.int32), AXIS) > 0
+                with self._exchange("psum"):
+                    self.dyn_filters[lk] = jax.lax.pmax(
+                        bits.astype(jnp.int32), AXIS) > 0
 
     def replicated(self, node: N.PlanNode) -> DTable:
         out = self.run(node)
         if out.dist == REPLICATED:
             return out.dt
-        return _gather(out.dt, self.nshards)
+        return self._gather(out.dt)
 
     def _repart(self, dt: DTable, keys: list[str], node, kind: str
                 ) -> DTable:
@@ -212,8 +255,9 @@ class ShardedInterpreter:
                 arrays[f"{sym}$valid"] = v.valid
         cap = self._capacity(
             node, next_pow2(2 * max(dt.n // self.nshards, 16)), kind)
-        ex, valid, ok = EX.repartition(
-            arrays, live, part_id, self.nshards, cap, AXIS)
+        with self._exchange("all_to_all"):
+            ex, valid, ok = EX.repartition(
+                arrays, live, part_id, self.nshards, cap, AXIS)
         self._note_ok(node, ok, kind)
         cols = {sym: Val(v.dtype, ex[sym], ex.get(f"{sym}$valid"),
                          v.dictionary)
@@ -301,15 +345,26 @@ class ShardedInterpreter:
     # -- leaves -------------------------------------------------------------
 
     def _r_tablescan(self, node: N.TableScan) -> DistTable:
-        scan, traced = self.scans[id(node)]
+        scan, traced, rows = self.scans[id(node)]
         cols = {}
         for sym in node.assignments:
             cols[sym] = Val(scan.types[sym], traced[sym],
                             traced.get(f"{sym}$valid"),
                             scan.dictionaries[sym])
-        # traced arrays are the local shard; live mask from row padding
+        # traced arrays are the local shard
         local_n = next(iter(traced.values())).shape[0]
-        live = traced["__live__"]
+        if rows is None:
+            # bucket-placed on the host, which made the mask with it
+            live = traced["__live__"]
+        else:
+            # block-sharded in table order and padded at pin time: the
+            # rows past the table's live count are masked here, from
+            # the count alone (a masked table ANDs its own mask in)
+            row = (jax.lax.axis_index(AXIS).astype(rows.dtype) * local_n
+                   + jax.lax.iota(rows.dtype, local_n))
+            live = row < rows
+            if "__live__" in traced:
+                live = live & traced["__live__"]
         part = (scan.part_cols
                 if getattr(scan, "bucketed", False) else None)
         return DistTable(DTable(cols, live, local_n), SHARDED, part)
@@ -382,7 +437,7 @@ class ShardedInterpreter:
             # property off: ship raw rows and aggregate replicated (the
             # reference's push_partial_aggregation_through_join=false
             # analog; mainly a debugging/testing escape hatch)
-            gathered = _gather(src.dt, self.nshards)
+            gathered = self._gather(src.dt)
             out, ok = OP.apply_aggregate(gathered, node, cap)
             if node.group_keys:
                 self._note_ok(node, ok)
@@ -393,7 +448,7 @@ class ShardedInterpreter:
         if node.group_keys:
             self._note_ok(node, ok1)
         if final_node is None:
-            return DistTable(_gather(partial, self.nshards), REPLICATED)
+            return DistTable(self._gather(partial), REPLICATED)
         est_groups = node.capacity or cap
         if node.group_keys and est_groups >= int(
                 self.session.get("partitioned_agg_min_groups")):
@@ -408,7 +463,7 @@ class ShardedInterpreter:
             out, ok2 = OP.apply_aggregate(ex, final_node, fcap)
             self._note_ok(node, ok2, "final")
             return DistTable(out, SHARDED, tuple(node.group_keys))
-        gathered = _gather(partial, self.nshards)
+        gathered = self._gather(partial)
         fcap = (1 if not node.group_keys else
                 self._capacity(node, next_pow2(2 * cap), "final",
                                override=ov))
@@ -447,9 +502,9 @@ class ShardedInterpreter:
             # co-partitioned by key) keeps the unmatched-tail pass
             # correct, so otherwise gather both sides and join replicated
             probe = (left.dt if left.dist == REPLICATED
-                     else _gather(left.dt, self.nshards))
+                     else self._gather(left.dt))
             build = (right.dt if right.dist == REPLICATED
-                     else _gather(right.dt, self.nshards))
+                     else self._gather(right.dt))
             cap = self._capacity(node, next_pow2(2 * build.n))
             out_cap = self._capacity(
                 node, next_pow2(2 * (probe.n + build.n)), "out")
@@ -505,7 +560,7 @@ class ShardedInterpreter:
             # FIXED_BROADCAST: replicate the build side
             probe = left.dt
             build = (right.dt if right.dist == REPLICATED
-                     else _gather(right.dt, self.nshards))
+                     else self._gather(right.dt))
             tab_kind, out_kind = "table", "out"
             cap = self._capacity(node, next_pow2(2 * build.n))
         if node.build_unique and node.join_type != N.JoinType.FULL:
@@ -545,7 +600,8 @@ class ShardedInterpreter:
         counts = jnp.zeros((SKETCH_BUCKETS,), jnp.int32).at[
             jnp.where(key_valid, bucket, SKETCH_BUCKETS)].add(
             1, mode="drop")
-        gcounts = jax.lax.psum(counts, AXIS)
+        with self._exchange("psum"):
+            gcounts = jax.lax.psum(counts, AXIS)
         # a bucket pools ~rows/SKETCH_BUCKETS cold keys besides any
         # heavy hitter, so compare against the threshold PLUS that
         # uniform background — without it, probes over
@@ -567,7 +623,7 @@ class ShardedInterpreter:
         hot_local, h_ok = OP.compact_dtable(
             DTable(right.dt.cols, build_hot, right.dt.n), hot_cap)
         self._note_ok(node, h_ok, "hot")
-        hot_build = _gather(hot_local, self.nshards)
+        hot_build = self._gather(hot_local)
         hcap = self._capacity(node, next_pow2(2 * hot_build.n), "htab")
         out_hot, ok1 = OP.apply_join(
             DTable(left.dt.cols, probe_live & probe_hot, left.dt.n),
@@ -666,7 +722,7 @@ class ShardedInterpreter:
                 build_dts.append(part_build_dt)
             else:
                 build_dts.append(b.dt if b.dist == REPLICATED
-                                 else _gather(b.dt, self.nshards))
+                                 else self._gather(b.dt))
         out, ok = OP.apply_multi_join(spine_dt, build_dts, node)
         self._note_ok(node, ok)
         if spine.dist == REPLICATED:
@@ -713,7 +769,7 @@ class ShardedInterpreter:
             # local pre-distinct shrinks the exchange, then final distinct
             local, ok1 = OP.apply_distinct(src.dt, cap)
             self._note_ok(node, ok1)
-            gathered = _gather(local, self.nshards)
+            gathered = self._gather(local)
             fcap = self._capacity(node, next_pow2(2 * cap), "final")
             out, ok2 = OP.apply_distinct(gathered, fcap)
             self._note_ok(node, ok2, "final")
@@ -762,7 +818,7 @@ class ShardedInterpreter:
             return DistTable(OP.apply_window(ex, node), SHARDED,
                              tuple(node.partition_by))
         dt = (src.dt if src.dist == REPLICATED
-              else _gather(src.dt, self.nshards))
+              else self._gather(src.dt))
         return DistTable(OP.apply_window(dt, node), REPLICATED)
 
     def _r_sort(self, node: N.Sort) -> DistTable:
@@ -772,12 +828,12 @@ class ShardedInterpreter:
             # sort network runs on n/nshards rows per device in
             # parallel; the replicated stage only merges presorted runs
             local = OP.apply_sort(src.dt, node.orderings)
-            gathered = _gather(local, self.nshards)
+            gathered = self._gather(local)
             merged = OP.merge_sorted_runs(gathered, node.orderings,
                                           self.nshards)
             return DistTable(merged, REPLICATED)
         dt = (src.dt if src.dist == REPLICATED
-              else _gather(src.dt, self.nshards))
+              else self._gather(src.dt))
         return DistTable(OP.apply_sort(dt, node.orderings), REPLICATED)
 
     def _r_topn(self, node: N.TopN) -> DistTable:
@@ -790,7 +846,7 @@ class ShardedInterpreter:
             local = OP.head(
                 OP.apply_topn(src.dt, node.count, node.orderings),
                 node.count)
-            gathered = _gather(local, self.nshards)
+            gathered = self._gather(local)
             return DistTable(
                 OP.apply_topn(gathered, node.count, node.orderings),
                 REPLICATED)
@@ -807,12 +863,12 @@ class ShardedInterpreter:
             # of the whole input (reference LimitNode partial/final)
             local = OP.head(OP.apply_sort(
                 OP.apply_limit(src.dt, take), []), take)
-            gathered = _gather(local, self.nshards)
+            gathered = self._gather(local)
             return DistTable(
                 OP.apply_limit(gathered, node.count, node.offset),
                 REPLICATED)
         dt = (src.dt if src.dist == REPLICATED
-              else _gather(src.dt, self.nshards))
+              else self._gather(src.dt))
         return DistTable(OP.apply_limit(dt, node.count, node.offset),
                          REPLICATED)
 
@@ -822,15 +878,15 @@ class ShardedInterpreter:
             out = OP.apply_union([p.dt for p in parts], node)
             return DistTable(out, SHARDED)
         dts = [p.dt if p.dist == REPLICATED
-               else _gather(p.dt, self.nshards) for p in parts]
+               else self._gather(p.dt) for p in parts]
         return DistTable(OP.apply_union(dts, node), REPLICATED)
 
     def _r_exchange(self, node: N.Exchange) -> DistTable:
         src = self.run(node.source)
         if node.kind == N.ExchangeType.GATHER and src.dist == SHARDED:
-            return DistTable(_gather(src.dt, self.nshards), REPLICATED)
+            return DistTable(self._gather(src.dt), REPLICATED)
         if node.kind == N.ExchangeType.REPLICATE and src.dist == SHARDED:
-            return DistTable(_gather(src.dt, self.nshards), REPLICATED)
+            return DistTable(self._gather(src.dt), REPLICATED)
         if node.kind == N.ExchangeType.REPARTITION and src.dist == SHARDED:
             return DistTable(
                 self._repart(src.dt, node.partition_keys, node, "exch"),
@@ -840,7 +896,7 @@ class ShardedInterpreter:
     def _r_output(self, node: N.Output) -> DistTable:
         src = self.run(node.source)
         dt = (src.dt if src.dist == REPLICATED
-              else _gather(src.dt, self.nshards))
+              else self._gather(src.dt))
         return DistTable(
             DTable({s: dt.cols[s] for s in node.symbols}, dt.live, dt.n),
             REPLICATED)
@@ -877,27 +933,26 @@ def _plan_exploits_partitioning(plan: N.PlanNode,
     return found
 
 
-def _shard_scan_arrays(scan: ScanInput, nshards: int,
-                       bucketed: bool = False):
-    """Rows split over shards; returns arrays + live mask.
+def _pinned_scan_arrays(engine, scan: ScanInput, mesh: Mesh):
+    """The scan's columns as the mesh holds them: contiguous blocks of
+    rows in table order, one a device, placed once per table version
+    and padded then to the devices' bucketed share
+    (``parallel/pins.py``). No live mask comes with them: the program
+    makes it from the scan's row count."""
+    return {sym: engine.shard_pins.get(a, mesh, table=scan.node.table,
+                                       column=sym)
+            for sym, a in scan.arrays.items()}
 
-    Default split is contiguous blocks padded to a multiple of
-    nshards. With ``bucketed`` (connector-defined partitioning), rows
-    place by key-hash bucket — the exact bit pattern of the device
-    FIXED_HASH exchange (partition_id golden-ratio fold, numpy twins in
-    ops/hash.py), so bucket-sharded scans are co-located with each
-    other AND with repartitioned intermediates on the same keys."""
+
+def _bucket_scan_arrays(scan: ScanInput, nshards: int):
+    """Rows placed over shards by key-hash bucket (connector-defined
+    partitioning), on the host, with the live mask of the placement:
+    the exact bit pattern of the device FIXED_HASH exchange
+    (partition_id golden-ratio fold, numpy twins in ops/hash.py), so
+    bucket-sharded scans are co-located with each other AND with
+    repartitioned intermediates on the same keys."""
     from presto_tpu.ops import hash as H
     n = scan.nrows
-    if not bucketed:
-        per = -(-max(n, 1) // nshards)
-        total = per * nshards
-        out = {}
-        for sym, a in scan.arrays.items():
-            out[sym] = np.pad(a, [(0, total - n)] + [(0, 0)] *
-                              (a.ndim - 1))
-        out["__live__"] = np.arange(total) < n
-        return out
     hs = []
     for sym in scan.part_cols:
         valid = scan.arrays.get(f"{sym}$valid")
@@ -958,6 +1013,7 @@ def execute_plan_distributed(engine, plan: N.PlanNode,
     from presto_tpu.plan.fingerprint import plan_fingerprint
 
     nshards = mesh.devices.size
+    _MESH_STATEMENTS.inc(devices=nshards)
     # plan templates (templates/): hoist literals before the plan is
     # fingerprinted so literal variants share the shard_map executable;
     # this query's values ride as trailing REPLICATED scalar args.
@@ -975,18 +1031,33 @@ def execute_plan_distributed(engine, plan: N.PlanNode,
 
     use_part = bool(engine.session.get("use_connector_partitioning"))
     sharded_arrays = []
-    for scan in scan_inputs:
+    # a block-sharded scan's live rows: one replicated scalar a scan,
+    # after the columns (scan number -> its argument)
+    rows_of: dict[int, np.integer] = {}
+    for i, scan in enumerate(scan_inputs):
         # bucket only when some operator can exploit the co-location:
-        # pure block sharding is an O(n) pad, bucketing is a full-table
-        # hash + scatter on host
+        # block sharding is a pin the mesh keeps, bucketing is a
+        # full-table hash + scatter on host, every statement
         bucketed = (use_part and scan.part_cols is not None
                     and _plan_exploits_partitioning(plan, scan.part_cols))
         scan.bucketed = bucketed  # read by ShardedInterpreter scans
-        sharded_arrays.append(
-            _shard_scan_arrays(scan, nshards, bucketed))
+        if bucketed:
+            arrays = _bucket_scan_arrays(scan, nshards)
+        elif scan.arrays:
+            arrays = _pinned_scan_arrays(engine, scan, mesh)
+            total = next(iter(arrays.values())).shape[0]
+            rows_of[i] = (np.int32 if total < 2 ** 31
+                          else np.int64)(scan.nrows)
+        else:
+            # no column to take the shard's length from (count(*)):
+            # the mask is the scan's one array
+            total = shard_rows(scan.nrows, nshards) * nshards
+            arrays = {"__live__": np.arange(total) < scan.nrows}
+        sharded_arrays.append(arrays)
     flat_names = [(i, sym) for i, arrs in enumerate(sharded_arrays)
                   for sym in arrs]
     flat_arrays = [sharded_arrays[i][sym] for i, sym in flat_names]
+    row_args = list(rows_of.values())
 
     use_cache = profile is None
     mesh_key = PC.mesh_key(mesh)
@@ -1000,7 +1071,7 @@ def execute_plan_distributed(engine, plan: N.PlanNode,
         PC.trace_session_key(engine.session),
         tuple((i, scan.part_cols, bool(scan.bucketed))
               for i, scan in enumerate(scan_inputs)),
-        "shard_map", mesh_key)
+        "shard_map", mesh_key, _FOLD_BLOCKS)
     capacities: dict[tuple, int] = {}
     if use_cache:
         cache.configure(engine.session)
@@ -1033,8 +1104,10 @@ def execute_plan_distributed(engine, plan: N.PlanNode,
                 per_scan: dict[int, dict] = {}
                 for (i, sym), a in zip(flat_names, it):
                     per_scan.setdefault(i, {})[sym] = a
+                rows = dict(zip(rows_of, it))
                 for i, scan in enumerate(scan_inputs):
-                    scans[id(scan.node)] = (scan, per_scan[i])
+                    scans[id(scan.node)] = (scan, per_scan[i],
+                                            rows.get(i))
                 interp = ShardedInterpreter(scans, capacities, nshards,
                                             engine.session, node_order)
                 if tpl is not None:
@@ -1072,13 +1145,15 @@ def execute_plan_distributed(engine, plan: N.PlanNode,
             sharded = jax.shard_map(
                 traced_fn, mesh=mesh,
                 in_specs=(tuple(P(AXIS) for _ in flat_arrays)
-                          + tuple(P() for _ in pargs)),
+                          + tuple(P() for _ in row_args + pargs)),
                 out_specs=(P(), P(), P(), P()),
                 check_vma=False)
             t0 = _time.perf_counter()
             with _TRACER.span("compile", devices=nshards,
-                              distributed=True):
-                lowered = jax.jit(sharded).lower(*flat_arrays, *pargs)
+                              distributed=True), \
+                    segred.wide_chunks(_FOLD_BLOCKS):
+                lowered = jax.jit(sharded).lower(
+                    *flat_arrays, *row_args, *pargs)
                 compiled = lowered.compile()
             compile_s = _time.perf_counter() - t0
             _COMPILES.inc()
@@ -1097,7 +1172,7 @@ def execute_plan_distributed(engine, plan: N.PlanNode,
                           distributed=True):
             with mesh:
                 res, live, oks, node_counts = compiled(
-                    *flat_arrays, *pargs)
+                    *flat_arrays, *row_args, *pargs)
             HS.wait(live, site="dist-execute")
         run_s = _time.perf_counter() - t0
         # ONE host sync for every flag (the stacked (k,) array), not

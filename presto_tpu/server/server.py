@@ -590,8 +590,10 @@ class QueryManager:
                 plan, _ = self.engine.plan_sql(
                     sql, enable_latemat=False, nshards=nshards)
             else:
-                nshards = 1  # no mesh: this process's one chip
-                plan, _ = self.engine.plan_sql(sql)
+                # the devices this session's statements run on (1
+                # without ``mesh_devices``: this process's one chip)
+                nshards = self.engine.session_shards()
+                plan, _ = self.engine.plan_sql(sql, nshards=nshards)
             est, _per_node = estimate_plan_memory(plan, self.engine)
         charge = max(int(est), 1)
         with TRACER.span("memory-admission", bytes=charge,

@@ -289,7 +289,8 @@ class ServingLayer:
         if versions is None:
             return None
         return (plan_fingerprint(plan), tuple(sorted(set(versions))),
-                trace_session_key(self.engine.session))
+                trace_session_key(self.engine.session),
+                self.engine.session_shards())
 
     # -- rung 1 fast path: answer hits on the HTTP handler thread ----------
 
@@ -351,7 +352,7 @@ class ServingLayer:
                 return False
             versions.append((catalog, tname, version))
         key = (fingerprint, tuple(sorted(set(versions))),
-               trace_session_key(sess))
+               trace_session_key(sess), engine.session_shards())
         entry = self.cache.lookup(key)
         if entry is None:
             return False
@@ -380,7 +381,8 @@ class ServingLayer:
             if not isinstance(stmt, A.QueryStatement):
                 memo = _MEMO_NEG
             else:
-                plan, _ = self.engine.plan_sql(q.sql)
+                plan, _ = self.engine.plan_sql(
+                    q.sql, nshards=self.engine.session_shards())
                 memo = (plan_fingerprint(plan),
                         tuple(self._scan_tables(plan)))
         except Exception:  # noqa: BLE001 - full path reports it
@@ -413,10 +415,13 @@ class ServingLayer:
         marks ``q.cache_hit`` / ``q.batched`` / ``q.deduped``."""
         engine = self.engine
         sess = engine.session
+        # the devices the statement runs on: the plan priced at
+        # admission is the one a mesh executes, or it is planned again
+        nshards = engine.session_shards()
         with engine._cancel_scope(q.cancel_token):
-            plan = engine.take_preplanned(sql)
+            plan = engine.take_preplanned(sql, nshards)
             if plan is None:
-                plan, _ = engine.plan_sql(sql)
+                plan, _ = engine.plan_sql(sql, nshards=nshards)
         use_cache = bool(sess.get("result_cache"))
         use_dedup = bool(sess.get("subplan_dedup"))
         # one key serves both rungs (dedup shares the cache's
@@ -432,7 +437,7 @@ class ServingLayer:
                 return entry.table
         cache_key = key if use_cache else None
         window_s = float(sess.get("batch_window_ms") or 0.0) / 1000.0
-        if window_s > 0:
+        if window_s > 0 and nshards == 1:  # the vmapped batch is one chip's
             table = self._try_batch(q, plan, window_s)
             if table is not None:
                 self._insert(cache_key, plan, table)
@@ -460,7 +465,10 @@ class ServingLayer:
                           tuple(sorted(set(versions))))
 
     def _serial(self, q, sql: str, plan):
-        self.engine.offer_preplanned(sql, plan)
+        # execute_table takes the session's mesh itself, and the plan
+        # only if it was priced for that many devices
+        self.engine.offer_preplanned(sql, plan,
+                                     self.engine.session_shards())
         return self.engine.execute_table(sql,
                                          cancel_token=q.cancel_token)
 

@@ -97,6 +97,16 @@ SYSTEM_SESSION_PROPERTIES: dict[str, tuple[Any, type, str]] = {
                                    "skip the FIXED_HASH exchange "
                                    "(reference ConnectorNodePartitioning"
                                    "Provider)"),
+    "mesh_devices": (1, int,
+                     "chips a statement of this deployment runs on: "
+                     "with N > 1 it is planned for N shards and "
+                     "executed as one shard_map program over the "
+                     "engine's mesh of the first N local devices, its "
+                     "scanned columns pinned row-sharded across them "
+                     "(parallel/executor.py); 1 = this process's one "
+                     "chip, no mesh. A fact of the deployment's "
+                     "layout, as the reference's hash_partition_count "
+                     "is, not a tuning knob"),
     "allow_local_fallback": (False, bool,
                              "rerun a distributed query locally when "
                              "its shape cannot distribute or a worker "

@@ -1,6 +1,7 @@
 """TPC-H generator sanity: shapes, FK integrity, distributions, oracle load."""
 
 import numpy as np
+import pytest
 
 from presto_tpu import types as T
 from presto_tpu.connectors.tpch import SCHEMAS, TpchConnector, _ps_suppkey
@@ -89,3 +90,46 @@ def test_schemas_cover_all_tables():
     assert set(SCHEMAS) == {
         "region", "nation", "supplier", "part", "partsupp",
         "customer", "orders", "lineitem"}
+
+
+def _digest(col) -> str:
+    """SHA-256 of a raw column: dtype, shape and bytes (a dictionary
+    column's codes, then its dictionary)."""
+    import hashlib
+    h = hashlib.sha256()
+    parts = ([col] if isinstance(col, np.ndarray)
+             else [col.codes, np.asarray([str(s) for s in col.dictionary])])
+    for a in parts:
+        if a.dtype == object:
+            a = np.asarray([str(s) for s in a])
+        h.update(f"{a.dtype.str}{a.shape}".encode())
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("block", [None, 4096])
+@pytest.mark.parametrize("orders", [True, False])
+@pytest.mark.parametrize("seed", [19920101, 2147485301])
+@pytest.mark.parametrize("skew", [None, "zipf:1.1"])
+def test_orders_and_lineitem_bytes_are_pinned(skew, seed, orders, block,
+                                              monkeypatch):
+    """Every cell's data comes from ``orders_and_lineitem``, and the
+    references read the same data, so ``correct`` cannot see a changed
+    distribution: each column is held to the SHA-256 recorded from the
+    generator of commit 3abae69 (before it computed columns in blocks
+    on threads), whole and in blocks of 4,096 rows, with ``orders`` and
+    as a deployment that holds ``lineitem`` alone."""
+    import json
+    from pathlib import Path
+    from presto_tpu.connectors import tpch
+    want = json.loads((Path(__file__).parent
+                       / "tpch_datagen_digests.json").read_text())
+    if block:
+        monkeypatch.setattr(tpch, "_GEN_BLOCK", block)
+    conn = TpchConnector(scale=0.01, seed=seed, skew=skew,
+                         tables=None if orders else ["lineitem"])
+    for table in ("orders", "lineitem") if orders else ("lineitem",):
+        got = {name: _digest(col) for name, col in conn._raw(table).items()}
+        assert got == want[f"{skew or 'uniform'}/{seed}/{table}"], table
+    if not orders:
+        assert "orders" not in conn._cache

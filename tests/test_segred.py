@@ -205,3 +205,36 @@ def test_null_masked_rows_fold_identically():
     ids = rng.integers(0, 11, n).astype(np.int32)
     fast, slow = _sum_both_paths(masked, ids, 11)
     np.testing.assert_array_equal(fast, slow)
+
+
+@pytest.mark.parametrize("blocks,steps", [
+    (4096, (4, 1024)),   # equal steps: nothing padded
+    (4097, (5, 1024)),   # no power of two divides it: padded steps
+    (600, (1, 600)),     # under the cap: one step
+])
+def test_sum_wide_chunks_equal_default(blocks, steps):
+    """A mesh program's wide fold steps (``wide_chunks``) give the
+    default's sums bit for bit, and the default's chunking is what it
+    was."""
+    assert segred._chunking(blocks, 7) == (-(-blocks // 512), 512)
+    rng = np.random.default_rng(11)
+    n = blocks * segred.BLOCK - 3
+    x = jnp.asarray(rng.integers(-(1 << 50), 1 << 50, n).astype(np.int64))
+    ids = _ids(rng, n, 6)
+    want = np.asarray(segred.segment_sum(x, ids, 6))
+    with segred.wide_chunks(1024):
+        assert segred._chunking(blocks, 7) == steps
+        got = np.asarray(jax.jit(
+            lambda a, b: segred.segment_sum(a, b, 6))(x, ids))
+    np.testing.assert_array_equal(got, want)
+    ref = np.zeros(6, np.int64)
+    np.add.at(ref, np.asarray(ids), np.asarray(x))
+    np.testing.assert_array_equal(got, ref)
+
+
+def test_wide_chunks_bound_the_one_hot():
+    # many segments: a step's one-hot stays the default's worst case
+    with segred.wide_chunks(1 << 15):
+        steps, chunk = segred._chunking(1 << 16, segred.MAX_MATMUL_K + 1)
+        assert chunk == 512 and steps == 128
+        assert segred._chunking(43 << 12, 7) == (8, 43 << 9)
