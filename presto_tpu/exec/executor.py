@@ -37,6 +37,13 @@ _COMPILES = REGISTRY.counter(
     "XLA programs compiled (cache misses + capacity-retry recompiles)")
 _COMPILE_SECONDS = REGISTRY.histogram(
     "presto_tpu_compile_seconds", "XLA program compile wall time")
+_DYN_FILTERS = REGISTRY.counter(
+    "presto_tpu_dynamic_filters_total",
+    "INNER join legs of executed programs by what the trace did with "
+    "their dynamic filter (registered | skipped_direct | skipped_wide)")
+# what PlanInterpreter._collect_dyn_filters does with a leg: the keys
+# of an interpreter's df_counts and the counter's ``outcome`` label
+DF_OUTCOMES = ("registered", "skipped_direct", "skipped_wide")
 
 
 # dispatch-exhaustiveness opt-outs (lint/dispatch.py): node types the
@@ -169,6 +176,24 @@ def compiling(**attrs):
     _COMPILE_SECONDS.observe(time.perf_counter() - t0)
 
 
+def note_dyn_filters(meta: dict, span) -> None:
+    """Say what an executed program's trace did with its join legs'
+    dynamic filters (``PlanInterpreter.df_counts``, carried in the
+    program's meta so a cache hit says it too): on the labelled counter
+    and, where the program has such a leg, on its ``execute`` span as
+    ``dynfilters=registered:0,direct:5,wide:0``."""
+    counts = meta.get("dynfilters")
+    if not counts or not any(counts.values()):
+        return
+    for outcome, n in counts.items():
+        if n:
+            _DYN_FILTERS.inc(n, outcome=outcome)
+    if span is not None:
+        span.attrs["dynfilters"] = ",".join(
+            f"{outcome.removeprefix('skipped_')}:{n}"
+            for outcome, n in counts.items())
+
+
 def compile_traced(fn, args: list, **attrs):
     """Explicit AOT lower+compile of ``fn`` for ``args`` (not a first
     jit-wrapper call), so compile and execute attribute separately."""
@@ -220,6 +245,9 @@ class PlanInterpreter:
         # operator/DynamicFilterSourceOperator.java:55)
         self.dyn_filters: dict[str, tuple] = {}
         self._df_applied: set[str] = set()
+        # INNER join legs by what _collect_dyn_filters did with them;
+        # rides the program's meta like used_capacity (make_traced)
+        self.df_counts = dict.fromkeys(DF_OUTCOMES, 0)
 
     def run(self, node: N.PlanNode) -> DTable:
         kind = type(node).__name__
@@ -258,17 +286,36 @@ class PlanInterpreter:
         live = keep if dt.live is None else (dt.live & keep)
         return DTable(dt.cols, live, dt.n)
 
-    def _collect_dyn_filters(self, node: N.Join, build: DTable,
+    def _collect_dyn_filters(self, criteria: list[tuple[str, str]],
+                             dense_key: tuple | None, build: DTable,
                              max_bits: int = 1 << 22) -> list[str]:
         """Build a one-hash bloom mask of the build-side key set per
         equi-key before the probe subtree is traced. False positives
         only cost the pruning (the join re-verifies); false negatives
         are impossible. Returns the registered probe symbols (a symbol
-        may be re-registered by a later join over the same key)."""
-        live = build.live_mask()
+        may be re-registered by a later join over the same key).
+
+        A probe key is tested once, so two kinds of leg register
+        nothing. One whose own probe is a direct address (``dense_key``,
+        the hint apply_join and apply_multi_join take _direct_probe
+        for): that gather answers exactly what the mask's hash, modulus
+        and gather would answer approximately, its other criteria are
+        compared by value, and at a static width a pruned row spares
+        nothing downstream. And one whose build is as wide as the mask
+        after the ``max_bits`` cap: under a bit a build row the mask
+        passes most keys (the reference gives such a filter up too,
+        DynamicFilterSourceOperator's max-distinct-values limit)."""
+        if dense_key is not None:
+            self.df_counts["skipped_direct"] += 1
+            return []
         m = next_pow2(min(4 * max(build.n, 16), max_bits))
+        if build.n >= m:
+            self.df_counts["skipped_wide"] += 1
+            return []
+        self.df_counts["registered"] += 1
+        live = build.live_mask()
         registered = []
-        for lk, rk in node.criteria:
+        for lk, rk in criteria:
             v = build.cols[rk]
             w = live if v.valid is None else (live & v.valid)
             h = (_df_hash(v) % jnp.uint64(m)).astype(jnp.int32)
@@ -363,7 +410,10 @@ class PlanInterpreter:
         right = self.run(node.right)
         if (node.join_type == N.JoinType.INNER
                 and self.session.get("enable_dynamic_filtering")):
-            self._collect_dyn_filters(node, right)
+            # the hint counts where apply_join will take it (below)
+            self._collect_dyn_filters(
+                node.criteria,
+                node.dense_key if node.build_unique else None, right)
         left = self.run(node.left)
         cap = self._capacity(node, next_pow2(2 * right.n))
         if node.build_unique and node.join_type != N.JoinType.FULL:
@@ -382,20 +432,18 @@ class PlanInterpreter:
 
     def _r_multijoin(self, node: N.MultiJoin) -> DTable:
         """Fused star chain (plan/nodes.MultiJoin): trace every build
-        first — registering each build's key set as a dynamic filter,
-        so the spine scan prunes against ALL dimensions at once — then
-        run the probe walk."""
-        import types as _pytypes
+        first — registering the key set of each build whose leg is a
+        sorted lookup as a dynamic filter, so the spine scan prunes
+        against all of them at once — then run the probe walk."""
         builds = []
-        for bnode, crit in zip(node.builds, node.criteria):
+        for k, (bnode, crit) in enumerate(zip(node.builds, node.criteria)):
             bdt = self.run(bnode)
             builds.append(bdt)
             if self.session.get("enable_dynamic_filtering"):
-                # duck-typed shim: _collect_dyn_filters only reads
-                # .criteria; keys referencing earlier builds register
-                # harmlessly (applied wherever the symbol first flows)
-                self._collect_dyn_filters(
-                    _pytypes.SimpleNamespace(criteria=crit), bdt)
+                # keys referencing earlier builds register harmlessly
+                # (applied wherever the symbol first flows)
+                self._collect_dyn_filters(crit, node.leg_dense_key(k),
+                                          bdt)
         spine = self.run(node.spine)
         out, ok = OP.apply_multi_join(spine, builds, node)
         self._note_ok(node, ok)
@@ -537,6 +585,7 @@ def make_traced(scan_inputs: list[ScanInput], plan: N.PlanNode,
             for sym, v in out.cols.items()]
         meta["ok_keys"] = interp.ok_keys
         meta["used_capacity"] = interp.used_capacity
+        meta["dynfilters"] = dict(interp.df_counts)
         res = []
         for sym, v in out.cols.items():
             res.append(v.data)
@@ -743,7 +792,8 @@ def prepare_plan(engine, plan: N.PlanNode, scan_inputs: list[ScanInput]):
             # pattern runs over its dictionary here: span dict-mask)
             pargs = tpl.bind(meta.get("param_bindings"))
         _t1 = time.perf_counter()
-        with TRACER.span("execute", cache_hit=cache_hit):
+        with TRACER.span("execute", cache_hit=cache_hit) as span:
+            note_dyn_filters(meta, span)
             outs = compiled(*flat_arrays, *pargs)
             # stale-format disk entries cannot reach here (the program
             # format version rides the platform fingerprint), but a

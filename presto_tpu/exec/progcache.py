@@ -244,8 +244,12 @@ def scan_dictionary_key(scan_inputs) -> tuple:
 # stacked its ok flags into one (k,) array) miss instead of
 # mis-unpacking. "cost1": meta carries the compile-time device-cost
 # summary (obs/devprof.harvest) — pre-cost entries would report zero
-# flops forever on warm hits, so they miss and recompile once
-PROGRAM_FORMAT = "cost1"
+# flops forever on warm hits, so they miss and recompile once.
+# "dynf1": which join legs register a dynamic filter changed under an
+# unchanged plan fingerprint (PlanInterpreter._collect_dyn_filters),
+# and meta carries the legs' counts — an older entry would go on
+# testing a direct-address leg's key twice and report no counts
+PROGRAM_FORMAT = "dynf1"
 
 
 @functools.lru_cache(maxsize=32)

@@ -39,8 +39,9 @@ from presto_tpu.block import Column, Table
 from presto_tpu.cost.model import decide_join_distribution
 from presto_tpu.exec import hostsync as HS
 from presto_tpu.exec import operators as OP
-from presto_tpu.exec.executor import (PlanInterpreter, ScanInput,
-                                      collect_scans, preorder_index)
+from presto_tpu.exec.executor import (DF_OUTCOMES, PlanInterpreter,
+                                      ScanInput, collect_scans,
+                                      preorder_index)
 from presto_tpu.exec.operators import DTable
 from presto_tpu.expr.compile import Val
 from presto_tpu.obs.metrics import REGISTRY
@@ -118,6 +119,7 @@ class ShardedInterpreter:
         # pruning is consistent across shards
         self.dyn_filters: dict[str, tuple] = {}
         self._df_applied: set[str] = set()
+        self.df_counts = dict.fromkeys(DF_OUTCOMES, 0)
         # always-on runtime stats (obs/qstats.py): (stable preorder
         # position, mesh-global live-row count, distribution) per plan
         # node — part of EVERY compiled shard_map program, so the
@@ -214,11 +216,11 @@ class ShardedInterpreter:
         self._pos = outer
         return out
 
-    def _collect_dyn_filters(self, node: N.Join, build: DTable,
+    def _collect_dyn_filters(self, criteria, dense_key, build: DTable,
                              global_reduce: bool) -> None:
         # smaller bloom under the mesh: the bit array crosses ICI
         registered = PlanInterpreter._collect_dyn_filters(
-            self, node, build, max_bits=1 << 20)
+            self, criteria, dense_key, build, max_bits=1 << 20)
         if global_reduce:
             # union of per-shard key sets — every registration needs it,
             # including re-registrations of a symbol by a later join
@@ -479,7 +481,11 @@ class ShardedInterpreter:
         right = self.run(node.right)
         if (node.join_type == N.JoinType.INNER
                 and self.session.get("enable_dynamic_filtering")):
-            self._collect_dyn_filters(node, right.dt,
+            # a salted exchange drops the hint (_salted_node): there
+            # the probe is a sorted lookup and the filter stays
+            direct = (node.dense_key if node.build_unique
+                      and self._salt_factor(node) <= 1 else None)
+            self._collect_dyn_filters(node.criteria, direct, right.dt,
                                       right.dist == SHARDED)
         left = self.run(node.left)
         lkeys = [lk for lk, _ in node.criteria]
@@ -671,15 +677,14 @@ class ShardedInterpreter:
         the fact table where the cascade paid a shuffle per large
         join — and every other build replicates (``all_gather``). The
         fused sequential probe walk then runs shard-locally."""
-        import types as _pytypes
         builds: list[DistTable] = []
-        for bnode, crit in zip(node.builds, node.criteria):
+        for k, (bnode, crit) in enumerate(zip(node.builds,
+                                              node.criteria)):
             b = self.run(bnode)
             builds.append(b)
             if self.session.get("enable_dynamic_filtering"):
-                self._collect_dyn_filters(
-                    _pytypes.SimpleNamespace(criteria=crit), b.dt,
-                    b.dist == SHARDED)
+                self._collect_dyn_filters(crit, node.leg_dense_key(k),
+                                          b.dt, b.dist == SHARDED)
         spine = self.run(node.spine)
         mode = str(self.session.get("join_distribution_type"))
         thresh = int(self.session.get("broadcast_join_threshold_rows"))
@@ -1009,7 +1014,8 @@ def execute_plan_distributed(engine, plan: N.PlanNode,
     import time as _time
 
     from presto_tpu.exec import progcache as PC
-    from presto_tpu.exec.executor import _COMPILES, _COMPILE_SECONDS
+    from presto_tpu.exec.executor import (_COMPILES, _COMPILE_SECONDS,
+                                          note_dyn_filters)
     from presto_tpu.plan.fingerprint import plan_fingerprint
 
     nshards = mesh.devices.size
@@ -1123,6 +1129,7 @@ def execute_plan_distributed(engine, plan: N.PlanNode,
                     for sym, v in out.cols.items()]
                 meta["ok_keys"] = interp.ok_keys
                 meta["used_capacity"] = interp.used_capacity
+                meta["dynfilters"] = dict(interp.df_counts)
                 meta["count_nodes"] = [
                     (nid, dist) for nid, _, dist in interp.row_counts]
                 res = []
@@ -1169,7 +1176,8 @@ def execute_plan_distributed(engine, plan: N.PlanNode,
             pargs = tpl.bind(meta.get("param_bindings"))
         t0 = _time.perf_counter()
         with _TRACER.span("execute", devices=nshards,
-                          distributed=True):
+                          distributed=True) as span:
+            note_dyn_filters(meta, span)
             with mesh:
                 res, live, oks, node_counts = compiled(
                     *flat_arrays, *row_args, *pargs)

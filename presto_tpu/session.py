@@ -123,9 +123,14 @@ SYSTEM_SESSION_PROPERTIES: dict[str, tuple[Any, type, str]] = {
                                     "fragmenter expects aggregate-"
                                     "rooted shapes"),
     "enable_dynamic_filtering": (True, bool,
-                                 "prune probe scans with build-side "
-                                 "join-key min/max ranges (reference "
-                                 "DynamicFilterService)"),
+                                 "prune probe scans with a bloom mask "
+                                 "of an INNER join's build-side keys "
+                                 "(reference DynamicFilterService). A "
+                                 "leg whose own probe is a direct "
+                                 "address registers none, nor does a "
+                                 "build as wide as the mask (under a "
+                                 "bit a row): the probe key is tested "
+                                 "once; off = no leg registers"),
     "query_max_memory_bytes": (0, int,
                                "plan-time device-memory budget per query "
                                "(0 = unlimited); over-budget plans spill "

@@ -341,18 +341,19 @@ def test_disk_hit_then_corruption_fallback(tmp_path, monkeypatch):
 
 
 def test_old_format_entry_misses_and_recompiles(tmp_path, monkeypatch):
-    """PROGRAM_FORMAT ("cost1": meta carries the device-cost summary)
-    rides the platform fingerprint, so entries persisted by a
-    pre-cost engine land at a DIFFERENT digest — a clean miss, never a
-    mis-unpack. And an old-shape blob that somehow sits at the current
-    digest (hand-copied store, digest collision) degrades to
-    disk_error + miss + live compile, not a crash."""
+    """PROGRAM_FORMAT ("dynf1": which join legs register a dynamic
+    filter, and their counts in meta) rides the platform fingerprint,
+    so entries persisted by an older engine land at a DIFFERENT digest
+    — a clean miss, never a mis-unpack. And an old-shape blob that
+    somehow sits at the current digest (hand-copied store, digest
+    collision) degrades to disk_error + miss + live compile, not a
+    crash."""
     # the format string participates in the digest
     key = ("fp", (), ())
     fp = PC.platform_fingerprint()
-    assert PC.PROGRAM_FORMAT == "cost1"
+    assert PC.PROGRAM_FORMAT == "dynf1"
     assert PC.PROGRAM_FORMAT in fp
-    old_fp = tuple("oks1" if x == PC.PROGRAM_FORMAT else x for x in fp)
+    old_fp = tuple("cost1" if x == PC.PROGRAM_FORMAT else x for x in fp)
     assert PC.entry_digest(key, fp) != PC.entry_digest(key, old_fp)
 
     monkeypatch.setenv(PC.ENV_DIR, str(tmp_path))
