@@ -22,8 +22,18 @@ retry on overflow, and exchanges pad to fixed per-partition capacities.
 """
 
 import os
+import sys
+import time
 
-import jax
+# the process trace's ``import`` span (obs/trace.py) runs from here to
+# this file's last line; the tracer does not exist yet, so the clock is
+# read by hand and the interval handed over at the end
+_T_IMPORT = time.monotonic()
+_JAX_WAS_IMPORTED = "jax" in sys.modules
+
+import jax  # noqa: E402
+
+_JAX_IMPORT_S = 0.0 if _JAX_WAS_IMPORTED else time.monotonic() - _T_IMPORT
 
 # SQL semantics need 64-bit integers (BIGINT, scaled DECIMAL) and float64.
 # This must run before any array is materialised.
@@ -65,6 +75,7 @@ from presto_tpu.types import (  # noqa: E402
 from presto_tpu.block import Column, Table  # noqa: E402
 from presto_tpu.session import Session  # noqa: E402
 from presto_tpu.engine import Engine  # noqa: E402
+from presto_tpu.obs import trace as _trace  # noqa: E402
 
 __version__ = "0.1.0"
 
@@ -82,3 +93,7 @@ __all__ = [
     "Session",
     "Engine",
 ]
+
+_trace.TRACER.add_process_span(
+    "import", _trace.from_monotonic(_T_IMPORT), _trace.now(),
+    jax_s=_JAX_IMPORT_S)

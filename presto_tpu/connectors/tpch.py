@@ -671,7 +671,22 @@ class TpchConnector(Connector):
 
     def table(self, name: str) -> Table:
         if name not in self._tables:
-            self._tables[name] = Table.from_numpy(SCHEMAS[name], self._raw(name))
+            from presto_tpu.obs.trace import TRACER
+            # one span a table: under the statement that first reads it,
+            # or in the process trace when a deployment makes its tables
+            # before it serves (``orders`` and ``lineitem`` come out of
+            # one pass, so the first of them asked for holds both)
+            with TRACER.process_span("datagen", table=name,
+                                     threads=_GEN_THREADS) as span:
+                held = set(self._cache)
+                table = self._tables[name] = Table.from_numpy(
+                    SCHEMAS[name], self._raw(name))
+                if span is not None:
+                    span.attrs.update(
+                        rows=table.nrows,
+                        bytes=sum(np.asarray(c.data).nbytes
+                                  for c in table.columns.values()),
+                        made=",".join(sorted(set(self._cache) - held)))
         return self._tables[name]
 
     _BASE_ROWS = {

@@ -23,102 +23,107 @@ from presto_tpu.session import SYSTEM_SESSION_PROPERTIES, Session
 
 class Engine:
     def __init__(self, session: Session | None = None):
-        from presto_tpu.connectors.information_schema import (
-            InformationSchemaConnector, SystemConnector)
-        from presto_tpu.events import EventListenerManager
+        from presto_tpu.obs.trace import TRACER
+        # a process's first engine imports half the package as it goes
+        # (0.11 s on the chip's host): ``engine-init`` in the process
+        # trace, or under the statement that makes an engine
+        with TRACER.process_span("engine-init"):
+            from presto_tpu.connectors.information_schema import (
+                InformationSchemaConnector, SystemConnector)
+            from presto_tpu.events import EventListenerManager
 
-        self.session = session or Session()
-        self.catalogs: dict[str, Connector] = {}
-        # compiled-program cache: size-bounded LRU fronting an optional
-        # persistent AOT disk store (exec/progcache.py; reference
-        # analog: gen/PageFunctionCompiler.java:101 compiled-artifact
-        # caches). Per-plan successful capacity vectors ride alongside.
-        from presto_tpu.exec.progcache import ProgramCache
-        self._program_cache = ProgramCache(
-            max_entries=int(self.session.get("program_cache_entries")
-                            or 64))
-        self._caps_memory: dict = {}
-        # plan templates: per-(template, segment) carrier-width memory
-        # (grow-only; exec/executor._segment_carriers) so literal
-        # variants keep stable downstream segment shapes
-        self._carrier_caps: dict = {}
-        # host->device transfer cache: id(np array) -> (host ref, dev
-        # array). The strong host ref pins the id; repeat executions of
-        # a query (and bench steady state) reuse HBM-resident inputs
-        # instead of re-uploading every run (the reference keeps pages
-        # pooled in worker memory the same way)
-        self._dev_cache: dict = {}
-        self._dev_cache_bytes = 0
-        self.dev_cache_limit = 8 << 30  # HBM budget for pinned inputs
-        # parallel segment compilation uploads scan arrays from pool
-        # threads concurrently; the pin cache + byte ledger + eviction
-        # loop must not interleave (two threads popping the same
-        # oldest key is a KeyError)
-        import threading as _t
-        self._dev_cache_lock = _t.Lock()
-        # a deployment on several chips (session ``mesh_devices``):
-        # its one Mesh, made at first use and kept, and the scanned
-        # columns placed on it once, row-sharded (parallel/pins.py);
-        # dropped with the one-chip pins when a statement changes
-        # table data
-        self._mesh = None
-        from presto_tpu.parallel.pins import ShardPins
-        self.shard_pins = ShardPins()
-        # runtime memory ledger: per-program tagged reservations of
-        # actual input+output array bytes (memory/MemoryPool.java:44);
-        # capacity 0 = unbounded (set memory_pool.capacity to enforce)
-        from presto_tpu.memory import MemoryPool
-        self.memory_pool = MemoryPool()
-        # table-level authorization consulted by the planner at scans
-        # and by DML (security/AccessControlManager.java analog)
-        from presto_tpu.security import AllowAllAccessControl
-        self.access_control = AllowAllAccessControl()
-        # session-scoped transactions (transaction.py; reference
-        # transaction/InMemoryTransactionManager)
-        from presto_tpu.transaction import TransactionManager
-        self.transactions = TransactionManager()
-        # populated by the spill driver when a query exceeds the memory
-        # budget and runs host-partitioned (exec/spill.py)
-        self.last_spill: dict | None = None
-        # per-THREAD warning handoff: concurrent queries on one engine
-        # (the server's worker pool) must not read each other's
-        # diagnostics
-        import threading as _threading
-        self._warn_tl = _threading.local()
-        # per-THREAD one-shot plan handoff (offer_preplanned /
-        # take_preplanned): the HTTP admission layer plans a query to
-        # size its memory reservation; the execution path on the same
-        # thread reuses that plan instead of planning twice
-        self._preplanned_tl = _threading.local()
-        # data-change listeners: the serving layer's result cache
-        # registers here so DML actively purges entries built on the
-        # pre-write table versions (connector SPI table_version keys
-        # make stale hits impossible even without the purge; the
-        # listener keeps the cache small and the invalidation counter
-        # honest)
-        self._invalidation_listeners: list = []
-        # query lifecycle events + history (events.py)
-        self.events = EventListenerManager()
-        # persisted query history + divergence-ledger persistence
-        # (obs/qstats.py): finished-query profiles append to a bounded
-        # JSONL under PRESTO_TPU_HISTORY_DIR and survive restarts,
-        # backing system.query_history
-        import os as _os
-        self.history = None
-        hist_dir = _os.environ.get("PRESTO_TPU_HISTORY_DIR")
-        if hist_dir:
-            from presto_tpu.obs.qstats import DIVERGENCE, QueryHistory
-            try:
-                self.history = QueryHistory(hist_dir)
-                self.events.add_listener(self.history.on_event)
-                DIVERGENCE.attach_dir(hist_dir)
-            except OSError:
-                self.history = None  # unwritable dir: run without
-        # engine-owned virtual catalogs (reference information_schema +
-        # system connectors are engine-side, not plugins)
-        self.catalogs["information_schema"] = \
-            InformationSchemaConnector(self)
-        self.catalogs["system"] = SystemConnector(self)
+            self.session = session or Session()
+            self.catalogs: dict[str, Connector] = {}
+            # compiled-program cache: size-bounded LRU fronting an optional
+            # persistent AOT disk store (exec/progcache.py; reference
+            # analog: gen/PageFunctionCompiler.java:101 compiled-artifact
+            # caches). Per-plan successful capacity vectors ride alongside.
+            from presto_tpu.exec.progcache import ProgramCache
+            self._program_cache = ProgramCache(
+                max_entries=int(self.session.get("program_cache_entries")
+                                or 64))
+            self._caps_memory: dict = {}
+            # plan templates: per-(template, segment) carrier-width memory
+            # (grow-only; exec/executor._segment_carriers) so literal
+            # variants keep stable downstream segment shapes
+            self._carrier_caps: dict = {}
+            # host->device transfer cache: id(np array) -> (host ref, dev
+            # array). The strong host ref pins the id; repeat executions of
+            # a query (and bench steady state) reuse HBM-resident inputs
+            # instead of re-uploading every run (the reference keeps pages
+            # pooled in worker memory the same way)
+            self._dev_cache: dict = {}
+            self._dev_cache_bytes = 0
+            self.dev_cache_limit = 8 << 30  # HBM budget for pinned inputs
+            # parallel segment compilation uploads scan arrays from pool
+            # threads concurrently; the pin cache + byte ledger + eviction
+            # loop must not interleave (two threads popping the same
+            # oldest key is a KeyError)
+            import threading as _t
+            self._dev_cache_lock = _t.Lock()
+            # a deployment on several chips (session ``mesh_devices``):
+            # its one Mesh, made at first use and kept, and the scanned
+            # columns placed on it once, row-sharded (parallel/pins.py);
+            # dropped with the one-chip pins when a statement changes
+            # table data
+            self._mesh = None
+            from presto_tpu.parallel.pins import ShardPins
+            self.shard_pins = ShardPins()
+            # runtime memory ledger: per-program tagged reservations of
+            # actual input+output array bytes (memory/MemoryPool.java:44);
+            # capacity 0 = unbounded (set memory_pool.capacity to enforce)
+            from presto_tpu.memory import MemoryPool
+            self.memory_pool = MemoryPool()
+            # table-level authorization consulted by the planner at scans
+            # and by DML (security/AccessControlManager.java analog)
+            from presto_tpu.security import AllowAllAccessControl
+            self.access_control = AllowAllAccessControl()
+            # session-scoped transactions (transaction.py; reference
+            # transaction/InMemoryTransactionManager)
+            from presto_tpu.transaction import TransactionManager
+            self.transactions = TransactionManager()
+            # populated by the spill driver when a query exceeds the memory
+            # budget and runs host-partitioned (exec/spill.py)
+            self.last_spill: dict | None = None
+            # per-THREAD warning handoff: concurrent queries on one engine
+            # (the server's worker pool) must not read each other's
+            # diagnostics
+            import threading as _threading
+            self._warn_tl = _threading.local()
+            # per-THREAD one-shot plan handoff (offer_preplanned /
+            # take_preplanned): the HTTP admission layer plans a query to
+            # size its memory reservation; the execution path on the same
+            # thread reuses that plan instead of planning twice
+            self._preplanned_tl = _threading.local()
+            # data-change listeners: the serving layer's result cache
+            # registers here so DML actively purges entries built on the
+            # pre-write table versions (connector SPI table_version keys
+            # make stale hits impossible even without the purge; the
+            # listener keeps the cache small and the invalidation counter
+            # honest)
+            self._invalidation_listeners: list = []
+            # query lifecycle events + history (events.py)
+            self.events = EventListenerManager()
+            # persisted query history + divergence-ledger persistence
+            # (obs/qstats.py): finished-query profiles append to a bounded
+            # JSONL under PRESTO_TPU_HISTORY_DIR and survive restarts,
+            # backing system.query_history
+            import os as _os
+            self.history = None
+            hist_dir = _os.environ.get("PRESTO_TPU_HISTORY_DIR")
+            if hist_dir:
+                from presto_tpu.obs.qstats import DIVERGENCE, QueryHistory
+                try:
+                    self.history = QueryHistory(hist_dir)
+                    self.events.add_listener(self.history.on_event)
+                    DIVERGENCE.attach_dir(hist_dir)
+                except OSError:
+                    self.history = None  # unwritable dir: run without
+            # engine-owned virtual catalogs (reference information_schema +
+            # system connectors are engine-side, not plugins)
+            self.catalogs["information_schema"] = \
+                InformationSchemaConnector(self)
+            self.catalogs["system"] = SystemConnector(self)
 
     def register_catalog(self, name: str, connector: Connector) -> None:
         self.catalogs[name] = connector

@@ -15,6 +15,8 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import logging
+import threading
 import time
 
 import jax
@@ -28,15 +30,22 @@ from presto_tpu.exec import operators as OP
 from presto_tpu.exec.operators import DTable
 from presto_tpu.expr.compile import Val
 from presto_tpu.obs.metrics import REGISTRY
-from presto_tpu.obs.trace import TRACER
+from presto_tpu.obs.trace import TRACER, current_context
 from presto_tpu.ops.hash import next_pow2
 from presto_tpu.plan import nodes as N
 
 _COMPILES = REGISTRY.counter(
     "presto_tpu_programs_compiled_total",
     "XLA programs compiled (cache misses + capacity-retry recompiles)")
-_COMPILE_SECONDS = REGISTRY.histogram(
-    "presto_tpu_compile_seconds", "XLA program compile wall time")
+_JAX_COMPILE_SECONDS = REGISTRY.counter(
+    "presto_tpu_jax_compile_seconds_total",
+    "seconds JAX spent building programs, whichever code asked for "
+    "them, by phase (trace | lower | xla | cache_load), as "
+    "jax.monitoring reports them")
+_JAX_BACKEND_COMPILES = REGISTRY.counter(
+    "presto_tpu_jax_backend_compiles_total",
+    "programs handed to the backend, whichever code built them, by "
+    "outcome (compiled | cache_hit: loaded from JAX's persistent cache)")
 _DYN_FILTERS = REGISTRY.counter(
     "presto_tpu_dynamic_filters_total",
     "INNER join legs of executed programs by what the trace did with "
@@ -163,17 +172,134 @@ def program_name(plan: N.PlanNode, template_fp: str | None = None,
     return f"{name}_{template_fp[:8]}" if template_fp else name
 
 
+# -- what JAX says a build was made of -----------------------------------------
+#
+# jax.monitoring reports each phase of a build as it ends, on the thread
+# that ran it: the Python trace of the function, its lowering to an MLIR
+# module, and the backend's share (XLA's compile, or a load from the
+# persistent cache, which JAX times inside the same event). The
+# listeners below feed two process-wide counters, whichever code built
+# the program (a bare ``jax.jit`` helper too), and the ``compile`` span
+# that is open on the thread. They run only when JAX traces or compiles,
+# never on a call of a program it already holds.
+
+_PHASE_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "xla",
+}
+_CACHE_USED_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_PHASES = ("trace", "lower", "xla", "cache_load")
+
+# a build whose ``compile`` span is longer says so in the log
+SLOW_BUILD_S = 10.0
+_LOG = logging.getLogger("presto_tpu")
+
+
+class _BuildState(threading.local):
+    """What the listeners know on one thread: how many timed events are
+    open (a jitted function traced inside another's trace reports its
+    own duration inside the outer one's, and an operation dispatched
+    eagerly during a trace is lowered and compiled inside it: only the
+    outermost event's seconds count, under its own phase), what the
+    persistent cache said of the backend request in flight, and the
+    ``compile`` span's collector."""
+
+    depth = 0
+    cache_used = False
+    cache_hit = False
+    build: dict | None = None
+
+
+_STATE = _BuildState()
+
+
+def _on_event_start(event: str, _value, **_kw) -> None:
+    # jax records a scalar (the start time) as a timed event opens
+    if event in _PHASE_OF_EVENT:
+        _STATE.depth += 1
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == _CACHE_USED_EVENT:
+        _STATE.cache_used, _STATE.cache_hit = True, False
+    elif event == _CACHE_HIT_EVENT:
+        _STATE.cache_hit = True
+
+
+def _on_event_duration(event: str, seconds: float, **_kw) -> None:
+    phase = _PHASE_OF_EVENT.get(event)
+    if phase is None:
+        return
+    st = _STATE
+    st.depth = max(st.depth - 1, 0)
+    outcome = None
+    if phase == "xla":
+        # a hit's event holds the cache key, the read and the
+        # deserialisation, and no compile: all of it is the load
+        outcome = ("hit" if st.cache_hit
+                   else "miss" if st.cache_used else "off")
+        st.cache_used = st.cache_hit = False
+        _JAX_BACKEND_COMPILES.inc(
+            outcome="cache_hit" if outcome == "hit" else "compiled")
+        if outcome == "hit":
+            phase = "cache_load"
+    if st.depth:
+        return
+    _JAX_COMPILE_SECONDS.inc(seconds, phase=phase)
+    if st.build is not None:
+        st.build[phase] += seconds
+        if outcome is not None:
+            # the last program of the span is the one it was opened for
+            st.build["persistent_cache"] = outcome
+
+
+jax.monitoring.register_scalar_listener(_on_event_start)
+jax.monitoring.register_event_listener(_on_event)
+jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
+
+
 @contextlib.contextmanager
-def compiling(**attrs):
-    """A ``compile`` span around the building of one program (trace,
-    lower, XLA compile), counted in ``programs_compiled_total`` and
-    ``compile_seconds``: the one way every path that builds a program
-    says so."""
+def compiling(program: str = "", **attrs):
+    """A ``compile`` span around the building of one program, counted in
+    ``programs_compiled_total``: the one way every path that builds a
+    program says so. When it closes it carries what JAX reported on
+    this thread meanwhile: ``program`` (the XLA module's name: ``jit_``
+    and the function's), ``trace_s``, ``lower_s``, ``xla_s``,
+    ``cache_load_s`` and ``persistent_cache`` (hit | miss | off, of the
+    span's last program). A build longer than ``SLOW_BUILD_S`` leaves a
+    line in the log, so a process killed while it compiles has said
+    which programs it finished."""
+    from presto_tpu.ft.faults import FAULTS
+    build = dict.fromkeys(_PHASES, 0.0)
+    outer, _STATE.build = _STATE.build, build
     t0 = time.perf_counter()
-    with TRACER.span("compile", **attrs):
-        yield
-    _COMPILES.inc()
-    _COMPILE_SECONDS.observe(time.perf_counter() - t0)
+    span = None
+    try:
+        with TRACER.span("compile", **attrs) as span:
+            # compile-latency chaos point (ft/faults.py): lets the
+            # chaos suite provoke slow compiles deterministically
+            FAULTS.delay("compile-slow", key=attrs.get("root", ""))
+            yield
+        _COMPILES.inc()
+    finally:
+        _STATE.build = outer
+        took = time.perf_counter() - t0
+        name = f"jit_{program}"  # the XLA module's name
+        cache = build.pop("persistent_cache", None)
+        if span is not None:
+            span.attrs.update(
+                {f"{k}_s": v for k, v in build.items()}, program=name,
+                **({"persistent_cache": cache} if cache else {}))
+        if took > SLOW_BUILD_S:
+            ctx = current_context()
+            _LOG.warning(
+                "slow build: program=%s attempt=%s took=%.1fs trace=%.1fs "
+                "lower=%.1fs xla=%.1fs cache_load=%.1fs "
+                "persistent_cache=%s statement=%s", name,
+                attrs.get("attempt"), took, *(build[k] for k in _PHASES),
+                cache, ctx[0] if ctx is not None else None)
 
 
 def note_dyn_filters(meta: dict, span) -> None:
@@ -197,7 +323,7 @@ def note_dyn_filters(meta: dict, span) -> None:
 def compile_traced(fn, args: list, **attrs):
     """Explicit AOT lower+compile of ``fn`` for ``args`` (not a first
     jit-wrapper call), so compile and execute attribute separately."""
-    with compiling(**attrs):
+    with compiling(program=fn.__name__, **attrs):
         return jax.jit(fn).lower(*args).compile()
 
 
@@ -758,10 +884,6 @@ def prepare_plan(engine, plan: N.PlanNode, scan_inputs: list[ScanInput]):
                 params=(pargs if tpl is not None else None))
             traced_fn.__name__ = program_name(
                 plan, base_key[0] if tpl is not None else None)
-            # compile-latency chaos point (ft/faults.py): lets the
-            # chaos suite provoke slow compiles deterministically
-            from presto_tpu.ft.faults import FAULTS
-            FAULTS.delay("compile-slow", key=type(plan).__name__)
             # meta fills during the trace lower() triggers
             _t0 = time.perf_counter()
             compiled = compile_traced(

@@ -80,8 +80,9 @@ def _profiled_compile_run(engine, plan, scans):
         traced_fn, flat, meta = make_traced(
             scans, plan, capacities, engine.session)
         t0 = time.perf_counter()
-        with TRACER.span("compile", analyze=True):
-            compiled = jax.jit(traced_fn).lower(*flat).compile()
+        compiled = EX.compile_traced(
+            traced_fn, flat, attempt=_attempt,
+            root=type(plan).__name__, analyze=True)
         compile_s = time.perf_counter() - t0
         t0 = time.perf_counter()
         with TRACER.span("execute", analyze=True):

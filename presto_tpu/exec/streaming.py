@@ -259,7 +259,8 @@ def try_execute_streamed(engine, plan: N.PlanNode):
                 # string parameter: the dictionary that gives its code
                 # is known only once the trace has recorded it, and
                 # the block then runs again below, bound.
-                with compiling(attempt=_attempt,
+                with compiling(program=block_fn.__name__,
+                               attempt=_attempt,
                                root=type(partial).__name__, streamed=True):
                     outs = compiled(*dev_args, *pargs)
                 if tpl is not None and meta.get("param_bindings"):

@@ -49,8 +49,9 @@ FAULT_POINTS = {
                             "GET with a connection error"),
     "heartbeat-blackout": ("coordinator.py RemoteWorker.ping: report "
                            "the node unreachable"),
-    "compile-slow": ("exec/executor.py prepare_plan: sleep before "
-                     "lower().compile() (compile-latency chaos)"),
+    "compile-slow": ("exec/executor.py compiling: sleep inside the "
+                     "``compile`` span, before the build "
+                     "(compile-latency chaos; key: the plan's root kind)"),
 }
 
 ENV_VAR = "PRESTO_TPU_FAULTS"

@@ -20,6 +20,11 @@ Per-trace spans export as Chrome trace-event JSON
 (``GET /v1/query/{id}/trace`` on the coordinator, ``/v1/trace/{id}``
 on workers for external cross-process collection), loadable in
 Perfetto / ``chrome://tracing``.
+
+What runs before any statement (imports, data generation, the engine
+and the server coming up) has one reserved trace of its own, id
+``process``: its root starts at the process's start, it is never
+evicted, and ``process_span`` opens its children.
 """
 
 from __future__ import annotations
@@ -27,6 +32,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
+import os
 import threading
 import time
 import uuid
@@ -45,8 +51,21 @@ _CURRENT: contextvars.ContextVar[tuple[str, str] | None] = \
 _NODE: contextvars.ContextVar[str | None] = \
     contextvars.ContextVar("presto_tpu_trace_node", default=None)
 
-MAX_TRACES = 256
+# the open ``process_span`` of this context, outside any statement
+_PROCESS_PARENT: contextvars.ContextVar[str | None] = \
+    contextvars.ContextVar("presto_tpu_trace_process", default=None)
+
+# The store holds every traced statement of a benchmark run, set-up's
+# included (the dearest cell traces about 400 statements of 15 spans),
+# so a reader that runs when the window has closed still finds them.
+# It is bounded twice: by traces and by spans over all of them; the
+# oldest trace goes first.
+MAX_TRACES = 4096
 MAX_SPANS_PER_TRACE = 4096
+MAX_SPANS = 65536
+
+# the reserved trace of what runs outside any statement
+PROCESS_TRACE_ID = "process"
 
 # Spans keep epoch seconds (the Chrome export, the worker hand-over and
 # the benchmark read them so), but are STAMPED from the monotonic
@@ -61,8 +80,12 @@ ANNOTATION_PREFIX = "pt:"
 _EVICTIONS = REGISTRY.counter(
     "presto_tpu_trace_evictions_total",
     "whole traces dropped from the span store to admit a new one (the "
-    "store keeps the last MAX_TRACES): zero means a reader of the "
-    "store saw every traced statement")
+    "store keeps MAX_TRACES traces and MAX_SPANS spans): zero means a "
+    "reader of the store saw every traced statement")
+_PROCESS_DROPS = REGISTRY.counter(
+    "presto_tpu_process_trace_dropped_spans_total",
+    "spans the process trace had no room for (it keeps "
+    "MAX_SPANS_PER_TRACE and evicts none)")
 
 
 def now() -> float:
@@ -70,6 +93,34 @@ def now() -> float:
     and ``t1`` are, and what a caller that hands ``add_span`` an
     interval has to measure it with."""
     return _EPOCH + time.monotonic()
+
+
+def to_monotonic(t: float) -> float:
+    """A span's ``t0`` or ``t1`` as a reading of ``time.monotonic()``."""
+    return t - _EPOCH
+
+
+def from_monotonic(m: float) -> float:
+    """A reading of ``time.monotonic()`` on the spans' clock."""
+    return m + _EPOCH
+
+
+def _process_start() -> float:
+    """When this process started, on the spans' clock: the kernel's
+    start time of the process against its uptime, where /proc has both
+    (to a clock tick), else now."""
+    at = now()
+    try:
+        with open("/proc/self/stat", encoding="ascii") as f:
+            # the command may hold spaces and brackets: count from the
+            # last ")" (field 22, starttime, is the 20th after it)
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime", encoding="ascii") as f:
+            uptime = float(f.read().split()[0])
+        age = uptime - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return at
+    return at - max(age, 0.0)
 
 
 def _annotation(name: str):
@@ -140,22 +191,46 @@ class Tracer:
     """Thread-safe per-trace span store + context management."""
 
     def __init__(self, max_traces: int = MAX_TRACES,
-                 max_spans: int = MAX_SPANS_PER_TRACE):
+                 max_spans: int = MAX_SPANS_PER_TRACE,
+                 max_total_spans: int = MAX_SPANS):
         self.max_traces = max_traces
         self.max_spans = max_spans
+        self.max_total_spans = max_total_spans
         self._lock = threading.Lock()
         self._traces: OrderedDict[str, list[Span]] = OrderedDict()
+        self._nspans = 0  # over the statement traces, not the process's
+        # the process trace: outside the bounds above, with one of its
+        # own (max_spans), and its root open for the process's life
+        self._process_root = Span(PROCESS_TRACE_ID, _new_span_id(), None,
+                                  PROCESS_TRACE_ID, {}, _process_start())
+        self._process: list[Span] = [self._process_root]
+
+    def _evict_oldest(self) -> None:
+        _tid, gone = self._traces.popitem(last=False)
+        self._nspans -= len(gone)
+        _EVICTIONS.inc()
 
     def _record(self, span: Span) -> None:
         with self._lock:
+            if span.trace_id == PROCESS_TRACE_ID:
+                if len(self._process) < self.max_spans:
+                    self._process.append(span)
+                else:
+                    _PROCESS_DROPS.inc()
+                return
             spans = self._traces.get(span.trace_id)
             if spans is None:
                 while len(self._traces) >= self.max_traces:
-                    self._traces.popitem(last=False)
-                    _EVICTIONS.inc()
+                    self._evict_oldest()
                 spans = self._traces[span.trace_id] = []
-            if len(spans) < self.max_spans:
-                spans.append(span)
+            if len(spans) >= self.max_spans:
+                return
+            spans.append(span)
+            self._nspans += 1
+            # the oldest traces make room, never the one being written
+            while (self._nspans > self.max_total_spans
+                   and next(iter(self._traces)) != span.trace_id):
+                self._evict_oldest()
 
     # -- span creation ------------------------------------------------------
 
@@ -198,6 +273,42 @@ class Tracer:
         finally:
             span.t1 = now()
             _CURRENT.reset(token)
+
+    @contextlib.contextmanager
+    def process_span(self, name: str, **attrs):
+        """A span of work that may run outside any statement (data
+        generation, the engine and the server coming up): a child of
+        the ambient statement where there is one, else of the process
+        trace. Outside a statement it leaves the statement context
+        empty, so ``span()`` inside it stays the no-op it is, and only
+        another ``process_span`` nests under it."""
+        if _CURRENT.get() is not None:
+            with self.span(name, **attrs) as s:
+                yield s
+            return
+        parent = _PROCESS_PARENT.get() or self._process_root.span_id
+        span = Span(PROCESS_TRACE_ID, _new_span_id(), parent, name,
+                    dict(attrs), now())
+        self._record(span)
+        token = _PROCESS_PARENT.set(span.span_id)
+        try:
+            with _annotation(name):
+                yield span
+        finally:
+            span.t1 = now()
+            _PROCESS_PARENT.reset(token)
+
+    def add_process_span(self, name: str, t0: float, t1: float,
+                         **attrs) -> None:
+        """Hand over an interval of the process trace that ended before
+        the tracer could open a span (the package's own import);
+        ``t0`` and ``t1`` are on :func:`now`'s clock. The process
+        cannot have started after something it ran: an earlier ``t0``
+        moves the root's start back to it."""
+        root = self._process_root
+        root.t0 = min(root.t0, t0)
+        self._record(Span(PROCESS_TRACE_ID, _new_span_id(), root.span_id,
+                          name, dict(attrs), t0, t1))
 
     @contextlib.contextmanager
     def root_or_span(self, trace_id: str, name: str, **attrs):
@@ -276,7 +387,17 @@ class Tracer:
 
     def spans(self, trace_id: str) -> list[Span]:
         with self._lock:
+            if trace_id == PROCESS_TRACE_ID:
+                return list(self._process)
             return list(self._traces.get(trace_id, ()))
+
+    def trace_ids(self) -> list[tuple[str, Span | None]]:
+        """The statement traces the store holds, oldest first, each
+        with its root span (None for a trace of markers alone)."""
+        with self._lock:
+            return [(tid, next((s for s in spans if s.parent_id is None
+                                and not s.attrs.get("instant")), None))
+                    for tid, spans in self._traces.items()]
 
     def import_spans(self, dicts: list[dict]) -> None:
         """Merge remote spans (a worker's ``/v1/trace/{id}`` payload)

@@ -1014,8 +1014,7 @@ def execute_plan_distributed(engine, plan: N.PlanNode,
     import time as _time
 
     from presto_tpu.exec import progcache as PC
-    from presto_tpu.exec.executor import (_COMPILES, _COMPILE_SECONDS,
-                                          note_dyn_filters)
+    from presto_tpu.exec.executor import compiling, note_dyn_filters
     from presto_tpu.plan.fingerprint import plan_fingerprint
 
     nshards = mesh.devices.size
@@ -1156,15 +1155,14 @@ def execute_plan_distributed(engine, plan: N.PlanNode,
                 out_specs=(P(), P(), P(), P()),
                 check_vma=False)
             t0 = _time.perf_counter()
-            with _TRACER.span("compile", devices=nshards,
-                              distributed=True), \
+            with compiling(program=traced_fn.__name__, attempt=_attempt,
+                           root=type(plan).__name__, devices=nshards,
+                           distributed=True), \
                     segred.wide_chunks(_FOLD_BLOCKS):
                 lowered = jax.jit(sharded).lower(
                     *flat_arrays, *row_args, *pargs)
                 compiled = lowered.compile()
             compile_s = _time.perf_counter() - t0
-            _COMPILES.inc()
-            _COMPILE_SECONDS.observe(compile_s)
             # harvest the whole-mesh device cost into meta before the
             # success-path cache insert below: warm (disk-tier) hits
             # in a fresh process attribute flops/bytes from here

@@ -33,9 +33,8 @@ _PINS = REGISTRY.counter(
 _PIN_BYTES = REGISTRY.counter(
     "presto_tpu_shard_pin_bytes_total",
     "bytes moved from the host by those placements, padding included")
-# the span store keeps the last 256 statements' traces, and a
-# placement is made by the first statement over a table version: the
-# spans' seconds are kept here as they close
+# the spans' seconds, summed as they close (the benchmark's
+# ``mesh.shard_pin_s`` reads this; ``setup.pin_s`` the spans themselves)
 _PIN_SECONDS = REGISTRY.histogram(
     "presto_tpu_shard_pin_seconds",
     "seconds of one placement (its shard-pin span: slicing, the "
@@ -99,12 +98,15 @@ class ShardPins:
                   * a.itemsize * int(np.prod(a.shape[1:])))
         t0 = time.perf_counter()
         with TRACER.span("shard-pin", table=table, column=column,
-                         bytes=nbytes, devices=len(devices)):
+                         bytes=nbytes, devices=len(devices)) as span:
             dev = place(a, mesh)
             # the span ends when the rows are on the chips, so set-up
             # sees what a placement costs and not what was enqueued
             HS.wait(dev, site="shard-pin")
-        seconds = time.perf_counter() - t0
+        # the span's own seconds where there is one, so that the
+        # histogram and a sum over the spans are one number
+        seconds = (span.t1 - span.t0 if span is not None
+                   else time.perf_counter() - t0)
         with self._lock:
             hit = self._entries.get(key)
             if hit is not None and hit[0] is a:

@@ -31,7 +31,7 @@ from presto_tpu import types as T
 from presto_tpu.obs import qstats as QS
 from presto_tpu.obs.jsonlog import LOG
 from presto_tpu.obs.metrics import REGISTRY
-from presto_tpu.obs.trace import TRACER
+from presto_tpu.obs.trace import PROCESS_TRACE_ID, TRACER
 from presto_tpu.obs.trace import now as trace_now
 from presto_tpu.server.httpbase import HttpService, JsonHandler
 from presto_tpu.server.results import (ResultAbandoned, ResultQueue,
@@ -795,15 +795,6 @@ class _Handler(JsonHandler):
             "query-level admission pool capacity "
             "(0 = admission disabled)").set(
             qinfo["capacityBytes"], node="coordinator")
-        REGISTRY.gauge(
-            "presto_tpu_compiled_programs",
-            "entries in the compiled-program cache").set(
-            len(self.manager.engine._program_cache),
-            node="coordinator")
-        REGISTRY.gauge(
-            "presto_tpu_uptime_seconds",
-            "seconds since server start").set(
-            time.time() - self.server_start, node="coordinator")
         return REGISTRY.render()
 
     def _query_results(self, q: QueryInfo, token: int) -> dict:
@@ -1076,6 +1067,11 @@ class _Handler(JsonHandler):
             # the other per-query endpoints
             user = self._authenticated_user()
             if user is None:
+                return
+            if parts[2] == PROCESS_TRACE_ID:
+                # the reserved trace of what ran outside any statement:
+                # the import, data generation, the server coming up
+                self._send_json(TRACER.chrome_trace(PROCESS_TRACE_ID))
                 return
             q = self.manager.get(parts[2])
             if q is None or not self._can_view(user, q):

@@ -244,10 +244,17 @@ def test_now_is_monotone_and_spans_nest_on_it(served):
 
 
 def test_the_257th_trace_is_an_eviction_that_is_counted():
+    """Until PR 36 the store kept 256 traces and the 257th evicted one;
+    it now keeps ``MAX_TRACES`` (4,096) of at most ``MAX_SPANS`` spans
+    together, so the 257th evicts nothing and the 4,097th does."""
     evictions = REGISTRY.counter("presto_tpu_trace_evictions_total")
     before = evictions.value()
     tracer = Tracer()
-    for i in range(OT.MAX_TRACES):
+    for i in range(257):
+        with tracer.trace(f"e{i}", "query"):
+            pass
+    assert evictions.value() == before and tracer.spans("e0")
+    for i in range(257, OT.MAX_TRACES):
         with tracer.trace(f"e{i}", "query"):
             pass
     assert evictions.value() == before
@@ -255,6 +262,15 @@ def test_the_257th_trace_is_an_eviction_that_is_counted():
         pass
     assert evictions.value() == before + 1
     assert tracer.spans("e0") == [] and tracer.spans("e1")
+    # a benchmark run's statements fit: 400 of 15 spans, set-up's too
+    tracer = Tracer()
+    for i in range(400):
+        with tracer.trace(f"s{i}", "query"):
+            for _ in range(14):
+                with tracer.span("x"):
+                    pass
+    assert evictions.value() == before + 1
+    assert len(tracer.spans("s0")) == 15
 
 
 # -- annotations --------------------------------------------------------------

@@ -345,6 +345,11 @@ NEW_METRICS = {"mesh.shard_pin_s", "mesh.repinned_bytes_per_query",
                "mesh.q06_roofline", "mesh.q01_roofline"}
 # what of them a run without a device trace can give
 CPU_METRICS = {"mesh.shard_pin_s", "mesh.repinned_bytes_per_query"}
+# PR 36's, listed in every cell: set-up read from the program's spans
+SETUP_METRICS = {"setup.import_s", "setup.trace_lower_s",
+                 "setup.xla_compile_s", "setup.cache_load_s",
+                 "setup.pin_s", "setup.unattributed_s",
+                 "compile.window_backend_compiles"}
 
 
 @pytest.fixture(scope="module")
@@ -383,7 +388,7 @@ def tiny_cell(tmp_path_factory):
         if CELL in m.get("workloads", ()):
             m["workloads"].append("tiny_mesh4.scan4")
             listed.add(m["name"])
-    assert listed == NEW_METRICS
+    assert listed == NEW_METRICS | SETUP_METRICS
     (root / "BENCHMARK.json").write_text(json.dumps(manifest))
     return root
 
@@ -411,6 +416,12 @@ def test_rehearsal_of_the_cell_on_the_cpu(tiny_cell, trace):
         return
     assert got & NEW_METRICS == CPU_METRICS
     assert out["metrics"]["mesh.shard_pin_s"]["value"] > 0
+    # the store still holds set-up when the window has closed, and the
+    # spans' seconds hold what the histogram summed from them
+    assert SETUP_METRICS <= got
+    assert (out["metrics"]["setup.pin_s"]["value"]
+            >= out["metrics"]["mesh.shard_pin_s"]["value"] - 1e-6)
+    assert out["metrics"]["compile.window_backend_compiles"]["value"] == 0
     # the table lives on the mesh: no statement of the window moved it
     assert out["metrics"]["mesh.repinned_bytes_per_query"]["value"] == 0
     assert out["metrics"]["compile.window_compiles"]["value"] == 0

@@ -162,6 +162,22 @@ def test_trace_store_bounded():
             pass
     assert tr.spans("t0") == []
     assert len(tr.spans("t9")) == 1
+    assert [tid for tid, _root in tr.trace_ids()] == ["t6", "t7", "t8", "t9"]
+    # bounded by spans over all traces too, oldest trace out first
+    tr = Tracer(max_traces=4, max_total_spans=6)
+    for i in range(3):
+        with tr.trace(f"s{i}", "query"):
+            with tr.span("a"), tr.span("b"):
+                pass
+    assert tr.spans("s0") == []
+    assert len(tr.spans("s1")) == len(tr.spans("s2")) == 3
+    # one trace holds at most max_spans
+    tr = Tracer(max_spans=5)
+    with tr.trace("wide", "query"):
+        for _ in range(9):
+            with tr.span("x"):
+                pass
+    assert len(tr.spans("wide")) == 5
 
 
 # -- structured JSON logging ------------------------------------------------
