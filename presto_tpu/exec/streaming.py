@@ -7,8 +7,11 @@ single big scan feeding (through filters/projections) one aggregation,
 execute the scan in fixed-size row blocks through ONE compiled
 partial-aggregate kernel, accumulate the per-block partial states
 (bounded by the group-count capacity, not the table size), then run the
-rest of the plan over the merged partials. HBM holds one block at a
-time, so tables larger than device memory stream through.
+rest of the plan over the merged partials. The copy runs one block
+ahead: block i+1's ``jax.device_put`` is issued once block i's program
+is dispatched and before the host waits for it, so the link carries the
+next block while the chip computes this one. HBM holds at most two
+blocks' arguments, so tables larger than device memory stream through.
 
 A block costs the host nothing: it is a view of ``scan_block_rows`` rows
 of every scanned column and two scalars, ``live_lo`` and ``live_hi``,
@@ -41,8 +44,15 @@ import numpy as np
 
 from presto_tpu import types as T
 from presto_tpu.exec import hostsync as HS
+from presto_tpu.obs.metrics import REGISTRY
 from presto_tpu.obs.trace import TRACER
 from presto_tpu.plan import nodes as N
+
+_BLOCK_COPIES = REGISTRY.counter(
+    "presto_tpu_stream_block_copies_total",
+    "Blocks of the streamed scan copied to the device, labeled by when: "
+    "ahead (while the previous block's program was in flight) or inline "
+    "(a statement's first block, or after the previous block's wait)")
 
 
 def _chain_to_scan(node: N.PlanNode) -> N.TableScan | None:
@@ -181,13 +191,10 @@ def try_execute_streamed(engine, plan: N.PlanNode):
                         STREAM_MASKED_TAG if masked else STREAM_TAG)
             capacities = dict(engine._caps_memory.get(base_key) or {})
 
-    from presto_tpu.exec.cancel import checkpoint
-    compiled = None
-    meta = None
-    caps_key = None
-    pargs: list = []
-    for i in range(nblocks):
-        checkpoint()
+    def place(i: int, ahead: bool) -> list:
+        """Block i's device arguments: a view of its rows of every
+        column and its live range, copied to the device. ``ahead``
+        while the block before is still in flight."""
         # nrows > block (checked above), so the last block can be the
         # table's last ``block`` rows: full width, and the rows the
         # block before has counted are dead by live_lo
@@ -203,7 +210,24 @@ def try_execute_streamed(engine, plan: N.PlanNode):
                     b.nbytes for a, b in zip(columns, host_args)
                     if not np.may_share_memory(a, b))
         host_args += [np.int32(live_lo), np.int32(block)]
-        dev_args = None
+        # the host's share of the copy; what is still in flight when
+        # this returns falls into the block's ``execute``
+        with TRACER.span("transfer", block=i, ahead=ahead,
+                         bytes=sum(a.nbytes for a in host_args)):
+            dev = jax.device_put(host_args)
+        _BLOCK_COPIES.inc(when="ahead" if ahead else "inline")
+        return dev
+
+    from presto_tpu.exec.cancel import checkpoint
+    compiled = None
+    meta = None
+    caps_key = None
+    pargs: list = []
+    placed = None  # block i+1's device arguments, once copied
+    for i in range(nblocks):
+        checkpoint()
+        dev_args = placed if placed is not None else place(i, ahead=False)
+        placed = None
         for _attempt in range(10):
             fresh = compiled is None
             if fresh and templated:
@@ -237,12 +261,6 @@ def try_execute_streamed(engine, plan: N.PlanNode):
                     partial, base_key[0] if tpl is not None else None,
                     prefix="stream_")
                 compiled = jax.jit(block_fn)
-            if dev_args is None:
-                # the host's share of the copy; what is still in
-                # flight when this returns falls into ``execute``
-                with TRACER.span("transfer", block=i,
-                                 bytes=sum(a.nbytes for a in host_args)):
-                    dev_args = jax.device_put(host_args)
             outs = None
             if fresh:
                 # The first call of a fresh jit traces, lowers, compiles
@@ -271,9 +289,14 @@ def try_execute_streamed(engine, plan: N.PlanNode):
                     # executables, and this is the jit wrapper
                     cache.insert((base_key, caps_key), compiled, meta,
                                  fpr, persist=False)
+            if outs is None:
+                outs = compiled(*dev_args, *pargs)
+            if placed is None and i + 1 < nblocks:
+                # the next block's copy rides the link while this one
+                # computes; its arrays hold no capacity, so a rerun of
+                # this block on the ladder leaves them valid
+                placed = place(i + 1, ahead=True)
             with TRACER.span("execute", block=i, streamed=True):
-                if outs is None:
-                    outs = compiled(*dev_args, *pargs)
                 res, live, oks = outs
                 oks_np = HS.fetch(oks, site="streaming-ok-ladder")
                 if oks_np.all():

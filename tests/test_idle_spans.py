@@ -81,6 +81,10 @@ def test_streamed_statement_has_a_span_per_block_and_per_program(tpch_tiny):
     rows = [s.attrs["rows"] for s in _named(spans, "block-input")]
     assert sum(rows) == tpch_tiny.table("lineitem").nrows
     assert all(s.attrs["bytes"] > 0 for s in _named(spans, "transfer"))
+    # block 0 is copied before its program runs, every later block
+    # while the block before it computes
+    assert [s.attrs["ahead"] for s in _named(spans, "transfer")] == [
+        i > 0 for i in range(nblocks)]
     assert all(s.attrs.get("streamed")
                for s in _named(spans, "execute") if "block" in s.attrs)
     # one compile span per program built: the block program (streamed)

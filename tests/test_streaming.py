@@ -183,6 +183,119 @@ def test_overflowed_capacities_are_remembered(tpch_tiny):
     assert got == whole.execute(HIGH_CARD)
 
 
+# -- the copy runs one block ahead ------------------------------------------
+#
+# Block i+1's ``jax.device_put`` is issued after block i's program is
+# dispatched and before the host waits for block i's flags, so the copy
+# rides the link while the chip computes. Each block is placed once.
+
+AHEAD_BLOCK = 16384  # four blocks of tiny lineitem's 59,739 rows
+_COPIES = REGISTRY.counter("presto_tpu_stream_block_copies_total")
+
+
+def _spy_copy_order(monkeypatch, conn, block):
+    """Record, in order, the streamed scan's ``jax.device_put`` of a
+    block as ``("put", i, alive)``, ``alive`` the blocks whose device
+    arguments were still referenced just before it, and each
+    ``streaming-ok-ladder`` fetch as ``("ok", all flags set, alive)``."""
+    import gc
+    import weakref
+
+    from presto_tpu.exec import hostsync as HS
+
+    owners = [np.asarray(c.data) for c in conn.table("lineitem")
+              .columns.values()]
+    events, refs = [], {}
+    real_put, real_fetch = ST.jax.device_put, HS.fetch
+
+    def alive():
+        gc.collect()
+        return sorted(i for i, rs in refs.items()
+                      if any(r() is not None for r in rs))
+
+    def put(x, *args, **kwargs):
+        if not isinstance(x, list):
+            return real_put(x, *args, **kwargs)
+        col = x[0]
+        owner = next(a for a in owners if np.shares_memory(a, col))
+        lo = ((col.__array_interface__["data"][0]
+               - owner.__array_interface__["data"][0]) // col.strides[0])
+        i = (lo + int(x[-2])) // block
+        events.append(("put", i, alive()))
+        out = real_put(x, *args, **kwargs)
+        refs.setdefault(i, []).extend(weakref.ref(a) for a in out)
+        return out
+
+    def fetch(x, site=None, **kwargs):
+        got = real_fetch(x, site=site, **kwargs)
+        if site == "streaming-ok-ladder":
+            events.append(("ok", bool(np.all(got)), alive()))
+        return got
+
+    monkeypatch.setattr(ST.jax, "device_put", put)
+    monkeypatch.setattr(HS, "fetch", fetch)
+    return events
+
+
+def _check_copy_order(events, nblocks):
+    """Each block placed once, in order; block i+1's copy before block
+    i's first flags; never more than two blocks' arguments live. The
+    ok-ladder fetches that found an overflow, by block."""
+    puts = [(k, e) for k, e in enumerate(events) if e[0] == "put"]
+    assert [e[1] for _k, e in puts] == list(range(nblocks))
+    overflowed, block, first_ok = [], 0, {}
+    for k, e in enumerate(events):
+        assert len(e[2]) <= (1 if e[0] == "put" else 2), events
+        if e[0] == "ok":
+            first_ok.setdefault(block, k)
+            if e[1]:
+                block += 1
+            else:
+                overflowed.append(block)
+    assert block == nblocks
+    for i in range(nblocks - 1):
+        assert puts[i + 1][0] < first_ok[i], events
+    return overflowed
+
+
+def test_the_next_block_is_copied_before_the_wait(tpch_tiny, monkeypatch):
+    whole = make_engine(tpch_tiny, 0)
+    e = make_engine(tpch_tiny, AHEAD_BLOCK)
+    want = whole.execute(Q1)
+    events = _spy_copy_order(monkeypatch, tpch_tiny, AHEAD_BLOCK)
+    got = e.execute(Q1)
+    assert e.last_streamed_blocks == 4
+    assert not _check_copy_order(events, 4)
+    assert got == want
+
+
+def test_an_overflow_reruns_on_its_block_and_keeps_the_next(
+        tpch_tiny, oracle, monkeypatch):
+    """A block that climbs the ok-ladder reruns on its own device
+    arguments; the next block's, placed before the first run, stay
+    valid and are not copied again. Per statement the counter reads a
+    copy ahead for every block but the first, whether or not the
+    ladder was climbed."""
+    e = make_engine(tpch_tiny, AHEAD_BLOCK)
+    e.session.set("groupby_table_size", 512)
+    for first in (True, False):
+        events = _spy_copy_order(monkeypatch, tpch_tiny, AHEAD_BLOCK)
+        a0, n0 = _COPIES.value(when="ahead"), _COPIES.value(when="inline")
+        got = e.execute(HIGH_CARD)
+        nblocks = e.last_streamed_blocks
+        assert nblocks == 4
+        overflowed = _check_copy_order(events, nblocks)
+        if first:
+            # the first block overflows once its successor is placed
+            assert overflowed and overflowed[0] < nblocks - 1
+        else:
+            assert not overflowed  # the rung that held is remembered
+        assert _COPIES.value(when="ahead") - a0 == nblocks - 1
+        assert _COPIES.value(when="inline") - n0 == 1
+        _assert_oracle(oracle, HIGH_CARD, got, ordered=True)
+        monkeypatch.undo()
+
+
 def test_plan_templates_off_compiles_per_statement(tpch_tiny):
     """The master switch keeps the behaviour from before the template:
     a block program per statement, even for the same text, and none of
