@@ -39,6 +39,10 @@ DEFAULT_BROADCAST_ROWS = 1 << 20
 # plan/dense.py so span eligibility is a cost-model decision)
 MAX_SPAN = 1 << 24
 MAX_SPAN_FACTOR = 16
+# a direct-address table this small is taken whatever the build's rows
+# (half a MiB of slots): TPC-DS date_dim's 73,049 keys probed by one
+# year of it, 365 rows
+MIN_SPAN_ALWAYS = 1 << 17
 
 # relative weight of one hash-table-resident byte vs one CPU row-op in
 # the scalar cost used for join enumeration (reference
@@ -81,9 +85,24 @@ def dense_span_eligible(rng: tuple, build_rows: int | None) -> bool:
     span = hi - lo + 1
     if span <= 0 or span > MAX_SPAN:
         return False
-    if build_rows and span > max(MAX_SPAN_FACTOR * build_rows, 4096):
+    if build_rows and span > max(MAX_SPAN_FACTOR * build_rows,
+                                 MIN_SPAN_ALWAYS):
         return False
     return True
+
+
+def expand_capacity(out_rows: float, probe_rows: float,
+                    build_rows: float) -> int:
+    """Output rows an expanding (many-to-many) join is compiled for:
+    twice its estimated output, and at least twice its smaller input,
+    but at most 16 times its larger one (a self-join of orders of 8-16
+    lines, TPC-DS web_sales on ws_order_number, is 12.5 times its
+    input). An undersized hint costs one RETRY_GROWTH recompile, an
+    oversized one HBM up front; a floor at the larger input would size
+    an expansion probed by 133 million inventory rows at 2^28."""
+    from presto_tpu.ops.hash import next_pow2
+    small, large = sorted((max(int(probe_rows), 1), max(int(build_rows), 1)))
+    return next_pow2(max(min(2 * max(int(out_rows), small), 16 * large), 2))
 
 
 def broadcast_net_bytes(build_bytes: float, nshards: int) -> float:

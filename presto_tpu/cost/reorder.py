@@ -37,7 +37,8 @@ from __future__ import annotations
 import dataclasses
 
 from presto_tpu.cost.model import (CostCalculator,
-                                   decide_join_distribution)
+                                   decide_join_distribution,
+                                   expand_capacity)
 from presto_tpu.cost.skew import decide_skew
 from presto_tpu.cost.stats import StatsCalculator
 from presto_tpu.ops.hash import next_pow2
@@ -150,16 +151,10 @@ class _Ctx:
         raw_edges: list[tuple[str, str]] = []
         self._flatten(root, rels, raw_edges)
 
-        # symbol -> relation index
-        sym_rel: dict[str, int] = {}
-        for i, r in enumerate(rels):
-            for s in r.output_types():
-                sym_rel[s] = i
-        edges = []  # (rel_a, rel_b, sym_a, sym_b)
-        for a, b in raw_edges:
-            if a not in sym_rel or b not in sym_rel:
-                return self._annotate_only(root)
-            edges.append((sym_rel[a], sym_rel[b], a, b))
+        edges = _indexed(rels, raw_edges)
+        if edges is None:
+            return self._annotate_only(root)
+        rels, edges = self._fold_derived_keys(rels, edges, raw_edges)
 
         if len(rels) <= MAX_DP_RELATIONS:
             built = self._dp(rels, edges)
@@ -168,6 +163,73 @@ class _Ctx:
         if built is None:  # disconnected graph: keep planner's shape
             return self._annotate_only(root)
         return built
+
+    def _fold_derived_keys(self, rels: list, edges: list,
+                           raw_edges: list) -> tuple[list, list]:
+        """Join a dimension into the one relation it hangs off (by its
+        own unique key) before the enumeration, where one of its other
+        columns is equated to a relation on the far side of a
+        many-to-many edge of its host. The folded pair is then one
+        relation whose columns key that many-to-many join together:
+        TPC-DS q72's ``d1.d_week_seq = d2.d_week_seq``, with d1 hanging
+        off ``catalog_sales`` and d2 off ``inventory``, makes the
+        catalog_sales x inventory join one on (item, week), where a
+        left-deep order over single relations could key it on the item
+        alone and test the week after the expansion. A dimension whose
+        host joins the far side through a unique key (TPC-H Q5's
+        customer and supplier on their nation) is left alone: nothing
+        there expands."""
+        while True:
+            uniq = [self._unique_sets(r) for r in rels]
+            by_rel = [dict() for _ in rels]
+            for a, b, sa, sb in edges:
+                by_rel[a].setdefault(b, []).append((sa, sb))
+                by_rel[b].setdefault(a, []).append((sb, sa))
+
+            def keyed(i: int, syms) -> bool:
+                return any(k <= frozenset(syms) for k in uniq[i])
+
+            def host(i: int):
+                hosts = [j for j, pairs in by_rel[i].items()
+                         if keyed(i, [s for s, _ in pairs])]
+                return hosts[0] if len(hosts) == 1 else None
+
+            def expanding(f: int, h: int) -> bool:
+                pairs = by_rel[f].get(h, [])
+                return bool(pairs) and not keyed(
+                    f, [s for s, _ in pairs]) and not keyed(
+                    h, [o for _, o in pairs])
+
+            fold = None
+            for i in range(len(rels)):
+                f = host(i)
+                if f is None:
+                    continue
+                for g in by_rel[i]:
+                    h = host(g) if g != f else None
+                    if g != f and expanding(f, g if h is None else h):
+                        fold = (i, f)
+                        break
+                if fold:
+                    break
+            if fold is None:
+                return rels, edges
+            i, f = fold
+            # with it go the host's other filtered dimensions that hang
+            # off it alone (q72's household and customer demographics):
+            # the folded relation is then the host's rows that can
+            # match, whichever side of the expansion it lands on
+            legs = [i] + [k for k in range(len(rels))
+                          if k not in (i, f) and set(by_rel[k]) == {f}
+                          and host(k) == f
+                          and self.stats.stats(rels[k]).selectivity < 1]
+            leaf = rels[f]
+            for k in legs:
+                leaf = self._make_join(
+                    leaf, rels[k], [(o, s) for s, o in by_rel[k][f]])
+            rels = [leaf if k == f else r for k, r in enumerate(rels)
+                    if k not in legs]
+            edges = _indexed(rels, raw_edges)
 
     # -- candidate join construction ----------------------------------------
 
@@ -196,13 +258,9 @@ class _Ctx:
                 dist, p_est, b_est, criteria, build_unique)
         out_cap = None
         if not build_unique:
-            # conservative hint, same bound as the planner: an
-            # undersized guess costs one RETRY_GROWTH recompile, an
-            # oversized one allocates HBM up front
-            cap = min(2 * max(int(out_rows), int(p_est.row_count)),
-                      8 * max(int(p_est.row_count),
-                              int(b_est.row_count)))
-            out_cap = next_pow2(max(cap, 2))
+            # the planner's bound (cost/model.expand_capacity)
+            out_cap = expand_capacity(out_rows, p_est.row_count,
+                                      b_est.row_count)
         return N.Join(
             probe, build, N.JoinType.INNER, list(criteria), None,
             build_unique, distribution=dist, build_rows=build_rows,
@@ -316,6 +374,23 @@ class _Ctx:
             out, build_rows=build_rows,
             capacity=next_pow2(2 * max(int(b_est.row_count), 1)),
             distribution=dist, hot_keys=hot_keys, salt_factor=salt)
+
+
+def _indexed(rels: list, raw_edges: list) -> list | None:
+    """Equi edges as (rel_a, rel_b, sym_a, sym_b); an edge inside one
+    relation (a fold made it a criterion) is dropped; None where a
+    symbol belongs to no relation."""
+    sym_rel: dict[str, int] = {}
+    for i, r in enumerate(rels):
+        for s in r.output_types():
+            sym_rel[s] = i
+    edges = []
+    for a, b in raw_edges:
+        if a not in sym_rel or b not in sym_rel:
+            return None
+        if sym_rel[a] != sym_rel[b]:
+            edges.append((sym_rel[a], sym_rel[b], a, b))
+    return edges
 
 
 def _connecting(edges: list, mask: int, j: int

@@ -477,8 +477,9 @@ class StatsCalculator:
             # RelationPlan.sel rule, plan-wide). The per-criterion NDV
             # quotient would undercount composite keys whose columns
             # correlate (lineitem x partsupp on (partkey, suppkey)).
-            return (max(probe.row_count * min(build.selectivity, 1.0),
-                        1.0), confident)
+            sel = min(build.selectivity * _reach(probe, build, criteria),
+                      1.0)
+            return max(probe.row_count * sel, 1.0), confident
         sel = 1.0
         for pk, bk in criteria:
             np_ = probe.symbol(pk).ndv
@@ -510,7 +511,18 @@ class StatsCalculator:
             rows = max(rows, build.row_count)
         elif node.join_type == N.JoinType.FULL:
             rows = max(rows, probe.row_count + build.row_count)
-        symbols = {**probe.symbols, **build.symbols}
+        build_syms = build.symbols
+        if node.build_unique and len(node.criteria) == 1 \
+                and node.join_type in (N.JoinType.INNER, N.JoinType.LEFT):
+            # a probe row reaches one build row by its key: the build's
+            # columns show at most as many values as that key has
+            # (TPC-DS inventory's 261 snapshot days reach 261 of
+            # date_dim's 10,436 weeks)
+            reach = probe.symbol(node.criteria[0][0]).ndv
+            if reach is not None:
+                build_syms = {s: st.capped(reach)
+                              for s, st in build.symbols.items()}
+        symbols = {**probe.symbols, **build_syms}
         return PlanNodeStatsEstimate(
             rows, symbols, confident,
             probe.selectivity * build.selectivity)
@@ -552,6 +564,25 @@ class StatsCalculator:
         return PlanNodeStatsEstimate(
             max(rows, 1.0), {**left.symbols, **right.symbols},
             left.confident and right.confident)
+
+
+def _reach(probe: PlanNodeStatsEstimate, build: PlanNodeStatsEstimate,
+           criteria) -> float:
+    """How much a unique build's filter fraction understates the probe
+    rows that keep a match, when the probe's key spans only part of the
+    build's key range: the build's span over the probe's (1 where either
+    is unknown or the probe spans it all). A filter on the dimension is
+    taken to select among the rows the probe reaches: TPC-DS' sales fall
+    in 5 of date_dim's 200 years, so ``d_year = 1999`` keeps a fifth of
+    them, not a two-hundredth."""
+    for pk, bk in criteria:
+        p, b = probe.symbol(pk), build.symbol(bk)
+        if None in (p.low, p.high, b.low, b.high):
+            continue
+        p_span, b_span = p.high - p.low + 1, b.high - b.low + 1
+        if 0 < p_span < b_span:
+            return b_span / p_span
+    return 1.0
 
 
 class SegmentStats(StatsCalculator):

@@ -46,6 +46,10 @@ _JAX_BACKEND_COMPILES = REGISTRY.counter(
     "presto_tpu_jax_backend_compiles_total",
     "programs handed to the backend, whichever code built them, by "
     "outcome (compiled | cache_hit: loaded from JAX's persistent cache)")
+_EXPANDED_ROWS = REGISTRY.counter(
+    "presto_tpu_join_expanded_rows_total",
+    "rows the expanding (many-to-many) joins of executed programs "
+    "emitted, from the per-node counts a program's results fetch")
 _DYN_FILTERS = REGISTRY.counter(
     "presto_tpu_dynamic_filters_total",
     "INNER join legs of executed programs by what the trace did with "
@@ -374,6 +378,9 @@ class PlanInterpreter:
         # INNER join legs by what _collect_dyn_filters did with them;
         # rides the program's meta like used_capacity (make_traced)
         self.df_counts = dict.fromkeys(DF_OUTCOMES, 0)
+        # (preorder position, probe rows, output capacity) of every
+        # expanding join the program runs (meta["expand_nodes"])
+        self.expand_nodes: list[tuple[int, int, int]] = []
 
     def run(self, node: N.PlanNode) -> DTable:
         kind = type(node).__name__
@@ -523,9 +530,13 @@ class PlanInterpreter:
         else:
             # bounded default: overflow-retry grows it if the real group
             # count exceeds the guess (reference rehash analog)
+            override = int(self.session.get("groupby_table_size") or 0)
             cap = self._capacity(
-                node, next_pow2(min(2 * src.n, 1 << 22)),
-                override=int(self.session.get("groupby_table_size") or 0))
+                node, next_pow2(min(2 * src.n, 1 << 22)), override=override)
+            if not override:
+                # no more groups than input rows: a wider table would
+                # only pad the output, and every sort after it
+                cap = min(cap, next_pow2(src.n))
         out, ok = OP.apply_aggregate(src, node, cap)
         if node.group_keys:
             self._note_ok(node, ok)
@@ -550,6 +561,8 @@ class PlanInterpreter:
             return out
         out_cap = self._capacity(node, next_pow2(2 * (left.n + right.n)),
                                  "out")
+        self.expand_nodes.append(
+            (self.node_order.get(id(node), id(node)), left.n, out_cap))
         out, t_ok, o_ok = OP.apply_expand_join(left, right, node, cap,
                                                out_cap)
         self._note_ok(node, t_ok)
@@ -712,6 +725,7 @@ def make_traced(scan_inputs: list[ScanInput], plan: N.PlanNode,
         meta["ok_keys"] = interp.ok_keys
         meta["used_capacity"] = interp.used_capacity
         meta["dynfilters"] = dict(interp.df_counts)
+        meta["expand_nodes"] = list(interp.expand_nodes)
         res = []
         for sym, v in out.cols.items():
             res.append(v.data)
@@ -990,10 +1004,15 @@ def _find_split(node: N.PlanNode, engine=None):
         # compacted live width, the same boundary the cascade's
         # aggregate-input split provided); small plans run fused with
         # everything above in one program
-        if engine is None or _subtree_scan_rows(node, engine) \
-                >= AGG_SPLIT_MIN_ROWS:
-            return node
-        return None
+        large = engine is None or _subtree_scan_rows(node, engine) \
+            >= AGG_SPLIT_MIN_ROWS
+        if large and _count_joins(node.spine):
+            # its spine is its input, not part of it: joins there run
+            # first, in programs of their own (TPC-DS q72's expansion
+            # under the MultiJoin of its unique dimension legs)
+            inner = _find_split(node.spine, engine)
+            return inner if inner is not None else node.spine
+        return node if large else None
     kids = node.sources()
     best = max(kids, key=_count_joins)
     c = _count_joins(best)
@@ -1185,12 +1204,17 @@ def device_outputs(meta, res, live, cap_floor: int | None = None,
         types[sym] = dtype
     n = int(live.shape[0])
     agg_nodes = meta.get("agg_nodes") or ()
-    if stats is not None and counts is not None and agg_nodes:
+    expand_nodes = meta.get("expand_nodes") or ()
+    if counts is not None and (expand_nodes
+                               or (stats is not None and agg_nodes)):
         cnt, node_rows = HS.fetch((jnp.sum(live), counts),
                                   site="segment-width")
         cnt = int(cnt)
         rows = dict(zip(meta["count_nodes"], node_rows.tolist()))
-        stats["groups"] = max(int(rows.get(pos, 0)) for pos in agg_nodes)
+        if stats is not None and agg_nodes:
+            stats["groups"] = max(int(rows.get(pos, 0))
+                                  for pos in agg_nodes)
+        note_expansions(meta, rows, stats)
     else:
         cnt = HS.fetch_int(jnp.sum(live), site="segment-width")
     if cap_floor is None:
@@ -1207,6 +1231,23 @@ def device_outputs(meta, res, live, cap_floor: int | None = None,
     if stats is not None:
         stats.update(width=n, live_rows=cnt)
     return arrays, dicts, types, n
+
+
+def note_expansions(meta, rows: dict, stats: dict | None) -> None:
+    """Count the rows a program's expanding joins emitted (the program's
+    per-node counts, already on the host) and hand ``stats`` (a
+    ``segment`` span's attributes) the largest of them: ``expanded``
+    its rows, ``expand_probe_rows`` its probe width and
+    ``expand_capacity`` the output rows it was compiled for."""
+    nodes = meta.get("expand_nodes") or ()
+    if not nodes:
+        return
+    out = [(int(rows.get(pos, 0)), probe, cap) for pos, probe, cap in nodes]
+    _EXPANDED_ROWS.inc(sum(r for r, _p, _c in out))
+    if stats is not None:
+        expanded, probe, cap = max(out)
+        stats.update(expanded=expanded, expand_probe_rows=probe,
+                     expand_capacity=cap)
 
 
 def run_plan_device(engine, plan: N.PlanNode,
@@ -1613,8 +1654,15 @@ def run_plan(engine, plan: N.PlanNode,
                          owner=owner)
 
         # one batched device->host transfer for every output column:
-        # per-array np.asarray pays a device round-trip each
-        live_np, res_np = HS.fetch((live, res), site="result-demux")
+        # per-array np.asarray pays a device round-trip each (and the
+        # per-node counts where an expanding join's rows are counted)
+        if meta.get("expand_nodes") and _counts is not None:
+            live_np, res_np, counts_np = HS.fetch(
+                (live, res, _counts), site="result-demux")
+            note_expansions(meta, dict(zip(meta["count_nodes"],
+                                           counts_np.tolist())), None)
+        else:
+            live_np, res_np = HS.fetch((live, res), site="result-demux")
         cols: dict[str, Column] = {}
         i = 0
         for sym, dtype, dictionary, has_valid in meta["out"]:

@@ -745,7 +745,15 @@ def apply_expand_join(left: DTable, right: DTable, node: N.Join,
     chains, operator/join/JoinProbe.java). Output has static capacity
     ``out_capacity``; overflow reported for host retry.
 
-    Returns (DTable [out_capacity], table_ok, out_ok)."""
+    Returns (DTable [out_capacity], table_ok, out_ok). Runs under the
+    scope ``expand`` inside the join's ``Join#<n>``, so the device time
+    of the expansion reads apart from the rest of the join's."""
+    with jax.named_scope("expand"):
+        return _expand_join(left, right, node, out_capacity)
+
+
+def _expand_join(left: DTable, right: DTable, node: N.Join,
+                 out_capacity: int) -> tuple:
     lkeys = [lk for lk, _ in node.criteria]
     rkeys = [rk for _, rk in node.criteria]
     build_live = _and_key_valid(right, rkeys, right.live_mask())
@@ -1079,6 +1087,8 @@ def _sort_keys(dt: DTable, orderings: list[N.Ordering]) -> list:
 
 def _sort_perm(dt: DTable, orderings: list[N.Ordering]):
     keys = _sort_keys(dt, orderings)
+    if H.narrow_sortable(keys):
+        return H.lexsort_perm(keys)
     operands = tuple(keys) + (jnp.arange(dt.n, dtype=jnp.int32),)
     sorted_ops = jax.lax.sort(operands, num_keys=len(keys), is_stable=True)
     return sorted_ops[-1]

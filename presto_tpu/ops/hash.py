@@ -39,6 +39,61 @@ import numpy as np
 _EMPTY = np.uint64(0xFFFFFFFFFFFFFFFF)
 _NULL_KEY_HASH = np.uint64(0x9E3779B97F4A7C15)
 
+# The TPU's compiler takes far longer over a sort the more operands and
+# keys it carries (a v5e described to the compiler, 2^16 rows: 1.4 s for
+# one 64-bit operand, 9 s for a 64-bit key and a row index, 70 s for 8
+# operands, 110 s for 8 stable; six keys at 2^17 rows 139 s), while a
+# gather compiles in a fraction of a second. Up to this many rows a
+# grouping, compaction or ORDER BY sort of integer keys is therefore made
+# of one-operand sorts (lexsort_perm) and gathers: on a v5e they run a
+# few milliseconds longer than the one wide sort at 2^15-2^16 rows, and
+# save minutes of compile. Above it the payloads ride one wide sort,
+# where a gather over the whole width is a random-access pass of its
+# own. The order is the stable sort's either way.
+NARROW_SORT_MAX_ROWS = 1 << 17
+
+
+def _order_words(key) -> list:
+    """A 1-D integer or bool key as uint32 words, most significant
+    first, whose lexicographic unsigned order is the key's order."""
+    if key.dtype == jnp.bool_ or key.dtype.itemsize < 4:
+        key = key.astype(jnp.int32)
+    signed = jnp.issubdtype(key.dtype, jnp.signedinteger)
+    if key.dtype.itemsize == 4:
+        w = key.astype(jnp.uint32)
+        return [w ^ jnp.uint32(1 << 31) if signed else w]
+    u = key.astype(jnp.uint64)
+    hi = (u >> jnp.uint64(32)).astype(jnp.uint32)
+    lo = u.astype(jnp.uint32)
+    return [hi ^ jnp.uint32(1 << 31) if signed else hi, lo]
+
+
+def narrow_sortable(keys) -> bool:
+    """True when ``lexsort_perm`` may order these keys: at most
+    NARROW_SORT_MAX_ROWS rows of 1-D integer or bool keys."""
+    return (keys[0].shape[0] <= NARROW_SORT_MAX_ROWS
+            and all(k.ndim == 1 and (k.dtype == jnp.bool_ or
+                                     jnp.issubdtype(k.dtype, jnp.integer))
+                    for k in keys))
+
+
+def lexsort_perm(keys):
+    """Permutation ordering rows by ``keys`` lexicographically, ties by
+    row index (a stable sort's order), as one one-operand sort per 32-bit
+    word of the keys, least significant word first: each sorts the word
+    (gathered into the current order) above the row's current position,
+    so equal words keep their order and the passes compose (LSD radix
+    order). ``narrow_sortable(keys)`` must hold."""
+    n = keys[0].shape[0]
+    pos = jnp.arange(n, dtype=jnp.uint64)
+    perm = jnp.arange(n, dtype=jnp.int32)
+    words = [w for k in keys for w in _order_words(k)]
+    for w in reversed(words):
+        packed = (w[perm].astype(jnp.uint64) << jnp.uint64(32)) | pos
+        order = jax.lax.sort(packed).astype(jnp.uint32).astype(jnp.int32)
+        perm = perm[order]
+    return perm
+
 
 class HashChainOverflow(RuntimeError):
     """A hash table gave up LOUDLY: a group or build count exceeded
@@ -203,9 +258,12 @@ class SortedGroups:
     grouping/join kernel; see module docstring).
 
     Extra per-row arrays ride the sort as PAYLOADS — on TPU additional
-    sort operands are nearly free, while gathering a column into sorted
-    order afterwards costs a full random-access pass. Aggregation
-    therefore sorts (hash, idx, key cols..., agg inputs...) in ONE sort.
+    sort operands are nearly free at run time, while gathering a column
+    into sorted order afterwards costs a full random-access pass.
+    Aggregation therefore sorts (hash, key cols..., idx, agg inputs...)
+    in ONE sort; up to NARROW_SORT_MAX_ROWS rows, where the compile of a
+    wide sort costs more than the gathers, the keys are ordered by
+    lexsort_perm and the payloads gathered.
 
     sh:       sorted hashes [N] (dead rows forced to EMPTY, so they sort
               last and form no group)
@@ -233,11 +291,16 @@ class SortedGroups:
         a probabilistic group identity has no place in a SQL engine)."""
         n = row_hash.shape[0]
         h = jnp.where(live, row_hash, _EMPTY)
-        out = jax.lax.sort(
-            (h,) + tuple(payloads[:num_key_payloads])
-            + (jnp.arange(n, dtype=jnp.int32),)
-            + tuple(payloads[num_key_payloads:]),
-            num_keys=1 + num_key_payloads, is_stable=True)
+        keys = (h,) + tuple(payloads[:num_key_payloads])
+        rest = tuple(payloads[num_key_payloads:])
+        if narrow_sortable(keys):
+            sidx = lexsort_perm(keys)
+            out = (tuple(k[sidx] for k in keys) + (sidx,)
+                   + tuple(p[sidx] for p in rest))
+        else:
+            out = jax.lax.sort(
+                keys + (jnp.arange(n, dtype=jnp.int32),) + rest,
+                num_keys=len(keys), is_stable=True)
         sh = out[0]
         sidx = out[1 + num_key_payloads]
         self.payloads = (out[1:1 + num_key_payloads]
@@ -261,10 +324,14 @@ class SortedGroups:
     def _compact(self, keep, columns, capacity: int):
         n = self.sh.shape[0]
         key = jnp.where(keep, self.gidc, n)
-        out = jax.lax.sort((key,) + tuple(columns), num_keys=1,
-                           is_stable=True)
+        if narrow_sortable([key]):
+            perm = lexsort_perm([key])
+            columns = [col[perm] for col in columns]
+        else:
+            columns = jax.lax.sort((key,) + tuple(columns), num_keys=1,
+                                   is_stable=True)[1:]
         res = []
-        for col in out[1:]:
+        for col in columns:
             if capacity <= n:
                 res.append(col[:capacity])
             else:
@@ -364,38 +431,37 @@ def probe_runs(build_hash, build_live, probe_hash, probe_live):
     analog of the reference's PositionLinks chain walk
     (operator/join/JoinHash.java:28).
 
-    Build and probe hashes sort TOGETHER keyed by (hash, side) with
-    builds first, so within a key run every build precedes every probe;
-    a probe row's run bounds then come from running build counts — one
-    combined sort, two scans, one monotone gather and one un-sort, with
-    NO random-access binary search (vectorized searchsorted costs
-    log2(N) random-gather passes; this is ~5x cheaper at 6M probes)."""
+    Build and probe hashes sort TOGETHER keyed by (hash, position in
+    the concatenation) with builds first, so within a key run every
+    build precedes every probe; a probe row's run bounds then come from
+    running build counts — one combined sort, two scans, one monotone
+    gather and one un-sort, with NO random-access binary search
+    (vectorized searchsorted costs log2(N) random-gather passes; this is
+    ~5x cheaper at 6M probes). The position is the tie-break key of the
+    first sort and the only key of the second, so neither carries a
+    side column or an index added for stability (the TPU's compile of a
+    sort grows fast with its operands: NARROW_SORT_MAX_ROWS)."""
     nb = build_hash.shape[0]
     npr = probe_hash.shape[0]
     n = nb + npr
     allh = jnp.concatenate([
         jnp.where(build_live, build_hash, _EMPTY),
         jnp.where(probe_live, probe_hash, _EMPTY)])
-    side = jnp.concatenate([jnp.zeros((nb,), jnp.int32),
-                            jnp.ones((npr,), jnp.int32)])
-    idx = jnp.concatenate([jnp.arange(nb, dtype=jnp.int32),
-                           jnp.arange(npr, dtype=jnp.int32)])
-    sh, sside, sidx = jax.lax.sort((allh, side, idx), num_keys=2,
-                                   is_stable=True)
+    sh, spos = jax.lax.sort((allh, jnp.arange(n, dtype=jnp.int32)),
+                            num_keys=2, is_stable=False)
     i = jnp.arange(n, dtype=jnp.int32)
     is_new = jnp.concatenate(
         [jnp.ones((1,), bool), sh[1:] != sh[:-1]])
     start = jnp.clip(jax.lax.cummax(jnp.where(is_new, i, -1)), 0, None)
-    is_build = (sside == 0) & (sh != _EMPTY)
+    is_build = (spos < nb) & (sh != _EMPTY)
     builds_before = (jnp.cumsum(is_build.astype(jnp.int32))
                      - is_build)  # exclusive running build count
     lo = builds_before[start]  # build rank of each run's first build
     count = builds_before - lo  # for a probe row: all builds in its run
-    # restore probe order: one sort keyed by (side, source index)
-    key = sside.astype(jnp.int64) * n + sidx.astype(jnp.int64)
+    # restore probe order: one sort keyed by the (unique) position
     _, lo_o, cnt_o = jax.lax.sort(
-        (key, lo.astype(jnp.int32), count.astype(jnp.int32)),
-        num_keys=1, is_stable=True)
+        (spos, lo.astype(jnp.int32), count.astype(jnp.int32)),
+        num_keys=1, is_stable=False)
     lo_p, cnt_p = lo_o[nb:], cnt_o[nb:]
     found = probe_live & (cnt_p > 0)
     return lo_p, jnp.where(found, cnt_p, 0), found
@@ -485,10 +551,14 @@ def expand_matches(lo, counts, build_sidx, probe_found,
     """Expand probe rows into one output row per (probe, build) match.
 
     ``lo``/``counts`` are per-PROBE-row run bounds from probe_runs;
-    ``build_sidx`` maps sorted build positions to source rows. For
-    output position k: binary-search the probe row whose match range
-    covers k, then index into its run. Every step is a gather —
-    XLA/TPU friendly; no data-dependent shapes.
+    ``build_sidx`` maps sorted build positions to source rows. Each
+    probe row that emits marks its first output slot with its index and
+    a running max carries it over the rest of its slots: one scatter of
+    the probe's width and one scan of the output's, where a binary
+    search for every output slot costs log2(probe rows) random gathers
+    of the output's width (23 of 2^27 for TPC-DS q95's self-join at
+    SF10). Then a slot's build position is its own offset plus its probe
+    row's ``lo - first slot``: one gather. No data-dependent shapes.
 
     Returns (probe_idx int32 [out_capacity], build_row int32 [out_capacity]
     (-1 = unmatched left row), out_live bool [out_capacity], ok).
@@ -499,21 +569,23 @@ def expand_matches(lo, counts, build_sidx, probe_found,
                               jnp.maximum(matches, 1), 0)
     else:
         per_probe = matches
-    prefix = jnp.concatenate(
-        [jnp.zeros((1,), per_probe.dtype), jnp.cumsum(per_probe)[:-1]])
+    prefix = jnp.cumsum(per_probe) - per_probe
     total = prefix[-1] + per_probe[-1]
     ok = total <= out_capacity
+    n = per_probe.shape[0]
+    first = jnp.where(per_probe > 0, prefix, out_capacity)
+    marks = jnp.full((out_capacity,), -1, jnp.int32).at[first].max(
+        jnp.arange(n, dtype=jnp.int32), mode="drop")
+    probe_idx = jnp.clip(jax.lax.cummax(marks), 0, n - 1)
     k = jnp.arange(out_capacity, dtype=prefix.dtype)
-    probe_idx = (jnp.searchsorted(prefix, k, side="right") - 1
-                 ).astype(jnp.int32)
-    safe_probe = jnp.clip(probe_idx, 0, per_probe.shape[0] - 1)
-    j = (k - prefix[safe_probe]).astype(jnp.int32)
-    matched = probe_found[safe_probe] & (j < matches[safe_probe])
-    build_pos = jnp.clip(lo[safe_probe] + j, 0,
+    out_live = k < total
+    # an emitting slot is a match, but a LEFT row's one slot of no match
+    matched = out_live & (matches[probe_idx] > 0) if left_join \
+        else out_live
+    build_pos = jnp.clip(k + (lo - prefix)[probe_idx], 0,
                          build_sidx.shape[0] - 1)
     build_row = jnp.where(matched, build_sidx[build_pos], -1)
-    out_live = k < total
-    return safe_probe, build_row, out_live, ok
+    return probe_idx, build_row, out_live, ok
 
 
 def next_pow2(x: int) -> int:

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 
+from presto_tpu import types as T
 from presto_tpu.expr import ir
 from presto_tpu.obs.metrics import REGISTRY
 from presto_tpu.plan import nodes as N
@@ -48,6 +49,11 @@ def optimize(plan: N.PlanNode, engine, nshards: int,
     # names, and before dense/latemat so their annotations apply to
     # the final join order
     plan = reorder_joins(plan, engine, nshards)
+    # over the final join order: residuals into the expansions that
+    # make their pairs, and existence-only joins into semi-joins
+    placed = semijoin_existence(sink_join_residuals(plan))
+    if placed is not plan:
+        plan = inline_trivial_projects(prune_columns(placed))
     # star-schema fusion over the reordered spine (session
     # multiway_join; AUTOMATIC reordering only — NONE means "leave
     # plans exactly as planned" and ELIMINATE_CROSS_JOINS promises the
@@ -489,6 +495,115 @@ def inline_trivial_projects(node: N.PlanNode) -> N.PlanNode:
         if identity and list(rebuilt.assignments) == list(src_syms):
             return rebuilt.source
     return rebuilt
+
+
+def _conjuncts(e: ir.Expr) -> list[ir.Expr]:
+    if isinstance(e, ir.Call) and e.fn == "and":
+        return [c for a in e.args for c in _conjuncts(a)]
+    return [e]
+
+
+def _and(conjs: list[ir.Expr]) -> ir.Expr:
+    out = conjs[0]
+    for c in conjs[1:]:
+        out = ir.Call(out.dtype, "and", (out, c))
+    return out
+
+
+def _filtered(node: N.PlanNode, conjs: list[ir.Expr]) -> N.PlanNode:
+    return N.Filter(node, _and(conjs)) if conjs else node
+
+
+def sink_join_residuals(plan: N.PlanNode) -> N.PlanNode:
+    """Hand a conjunct of a filter over a tree of INNER joins to the
+    lowest expanding (many-to-many) join that sees both of its sides, as
+    the join's residual: the expansion then tests it on every pair it
+    makes (exec/operators.apply_expand_join), as the reference's
+    JoinFilterFunction does, rather than a filter above it. A conjunct
+    whose lowest such join is a unique-build one stays where it is (that
+    chain fuses into a MultiJoin, which takes no residual)."""
+
+    def target(node: N.PlanNode, refs: set[str]):
+        if not (isinstance(node, N.Join)
+                and node.join_type == N.JoinType.INNER):
+            return None
+        for side in (node.left, node.right):
+            if refs <= set(side.output_symbols):
+                return target(side, refs)
+        return node if not node.build_unique and node.criteria else None
+
+    def put(node: N.PlanNode, join: N.Join, c: ir.Expr) -> N.PlanNode:
+        if node is join:
+            filt = c if join.filter is None else _and([join.filter, c])
+            return dataclasses.replace(join, filter=filt)
+        return dataclasses.replace(
+            node, left=put(node.left, join, c) if _holds(node.left, join)
+            else node.left,
+            right=put(node.right, join, c) if _holds(node.right, join)
+            else node.right)
+
+    def visit(node: N.PlanNode) -> N.PlanNode:
+        if not isinstance(node, N.Filter):
+            return node
+        src, rest = node.source, []
+        for c in _conjuncts(node.predicate):
+            join = target(src, ir.referenced_columns([c]))
+            if join is None:
+                rest.append(c)
+            else:
+                src = put(src, join, c)
+        if src is node.source:
+            return node
+        return _filtered(src, rest)
+
+    return N.rewrite_bottom_up(plan, visit)
+
+
+def _holds(node: N.PlanNode, target: N.PlanNode) -> bool:
+    return any(n is target for n in N.preorder(node))
+
+
+def semijoin_existence(plan: N.PlanNode) -> N.PlanNode:
+    """An IN whose subquery is an INNER join that returns columns of one
+    side only asks which of that side's rows have a match, not how many
+    (``x IN (SELECT a.k FROM a JOIN b ON a.j = b.j)``): the join becomes
+    a semi-join of that side against the other, and nothing expands.
+    TPC-DS q95's ``wr_order_number IN (... web_returns, ws_wh ...)`` is
+    one; the join it replaces was estimated at the product of a
+    returns table and a self-join."""
+
+    def rewrite(fs: N.PlanNode, keys: set[str]):
+        if isinstance(fs, N.Project):
+            refs = _expr_refs(*fs.assignments.values())
+            inner = rewrite(fs.source, refs)
+            return None if inner is None else dataclasses.replace(
+                fs, source=inner)
+        if not (isinstance(fs, N.Join) and fs.join_type == N.JoinType.INNER
+                and fs.criteria and fs.filter is None):
+            return None
+        if keys <= set(fs.left.output_symbols):
+            side, other = fs.left, fs.right
+            crit = list(fs.criteria)
+        elif keys <= set(fs.right.output_symbols):
+            side, other = fs.right, fs.left
+            crit = [(r, lk) for lk, r in fs.criteria]
+        else:
+            return None
+        mark = f"{crit[0][0]}_exists"
+        semi = N.SemiJoin(side, other, [s for s, _ in crit],
+                          [o for _, o in crit], mark)
+        return N.Project(
+            N.Filter(semi, ir.ColumnRef(T.BOOLEAN, mark)),
+            {s: ir.ColumnRef(t, s) for s, t in side.output_types().items()})
+
+    def visit(node: N.PlanNode) -> N.PlanNode:
+        if not isinstance(node, N.SemiJoin):
+            return node
+        fs = rewrite(node.filter_source, set(node.filter_keys))
+        return node if fs is None else dataclasses.replace(
+            node, filter_source=fs)
+
+    return N.rewrite_bottom_up(plan, visit)
 
 
 def push_scan_filters(plan: N.PlanNode, engine) -> N.PlanNode:

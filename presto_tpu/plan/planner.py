@@ -24,6 +24,7 @@ reference runs as optimizer rules but fit naturally at plan time here:
 from __future__ import annotations
 
 import dataclasses
+import re
 
 import numpy as np
 
@@ -540,7 +541,17 @@ class ExprPlanner:
         return out
 
     def _p_castexpression(self, e: A.CastExpression) -> ir.Expr:
-        return ir.Cast(parse_type_name(e.type_name), self.plan(e.operand))
+        target = parse_type_name(e.type_name)
+        ymd = (re.fullmatch(r"\s*(\d{4})-(\d{1,2})-(\d{1,2})\s*",
+                            e.operand.value)
+               if isinstance(e.operand, A.StringLiteral) else None)
+        if isinstance(target, T.DateType) and ymd is not None:
+            # CAST('1999-2-01' AS DATE) is the literal DATE '1999-02-01'
+            # (TPC-DS q95's window): a literal, so a plan template hoists
+            # it and another date is the same program
+            y, m, d = (int(g) for g in ymd.groups())
+            return ir.Literal(T.DATE, _days(f"{y:04d}-{m:02d}-{d:02d}"))
+        return ir.Cast(target, self.plan(e.operand))
 
     def _p_caseexpression(self, e: A.CaseExpression) -> ir.Expr:
         conds = tuple(self.plan(c) for c, _ in e.whens)
@@ -1036,6 +1047,7 @@ def rewrite_subtrees(e: ir.Expr, mapping: dict[ir.Expr, ir.Expr]) -> ir.Expr:
     return e
 
 
+from presto_tpu.cost.model import expand_capacity  # noqa: E402
 from presto_tpu.ops.hash import next_pow2 as _next_pow2  # noqa: E402
 from presto_tpu.plan.stats import selectivity as _selectivity  # noqa: E402
 
@@ -1472,6 +1484,12 @@ class LogicalPlanner:
                         outer: Scope | None) -> RelationPlan:
         left = self._plan_join_operand(rel.left, ctes, outer)
         right = self._plan_join_operand(rel.right, ctes, outer)
+        return self._outer_join(rel, left, right, outer)
+
+    def _outer_join(self, rel: A.JoinRelation, left: RelationPlan,
+                    right: RelationPlan, outer: Scope | None
+                    ) -> RelationPlan:
+        """The outer join ``rel`` of its planned operands."""
         # RIGHT join: probe the right side, build the left; the declared
         # field order (left columns first) is preserved either way
         if rel.join_type == "right":
@@ -1751,13 +1769,29 @@ class LogicalPlanner:
                 qs.node = N.Filter(qs.node, planned)
             return qs
 
-        flatten(spec.from_relation)
+        # A chain of LEFT joins at the root of FROM: the relations of its
+        # preserved side are legs of the join graph, so the WHERE
+        # clause's conjuncts place on them as on any inner chain (TPC-DS
+        # q72's single-table predicates and d1.d_week_seq = d2.d_week_seq
+        # land below its two LEFT joins); the LEFT joins apply on top of
+        # the graph, and a conjunct that reads a nullable side filters
+        # above them.
+        root, lefts = spec.from_relation, []
+        while (isinstance(root, A.JoinRelation) and root.join_type == "left"
+               and not root.using):
+            lefts.append(root)
+            root = root.left
+        flatten(root)
+        outer_joins = [(rel, self._plan_join_operand(rel.right, ctes, outer))
+                       for rel in reversed(lefts)]
+        nullable = {f.symbol for _, r in outer_joins for f in r.scope.fields}
         if not legs and unnest_legs:
             # FROM UNNEST(...) alone: expand over a one-row dual
             legs.append(RelationPlan(
                 N.Values(["dual"], {"dual": T.BIGINT}, [[1]]),
                 Scope([]), 1, [frozenset()]))
-        combined = Scope([f for leg in legs for f in leg.scope.fields])
+        combined = Scope([f for leg in legs for f in leg.scope.fields]
+                         + [f for _, r in outer_joins for f in r.scope.fields])
         sym_to_leg = {}
         for i, leg in enumerate(legs):
             for f in leg.scope.fields:
@@ -1796,6 +1830,10 @@ class LogicalPlanner:
                 inner_expr, outer_sym = pair
                 # materialise inner side as a symbol on its leg
                 refs = ir.referenced_columns([inner_expr])
+                if refs & nullable:
+                    raise SemanticError(
+                        "correlated predicate on an outer join's "
+                        "nullable side")
                 leg_ids = {sym_to_leg[r] for r in refs}
                 if len(leg_ids) != 1:
                     raise SemanticError(
@@ -1816,6 +1854,9 @@ class LogicalPlanner:
                 corr_pairs.append((outer_sym, inner_sym, inner_expr.dtype))
                 continue
             refs = ir.referenced_columns([planned])
+            if refs & nullable:
+                post.append(planned)
+                continue
             leg_ids = {sym_to_leg[r] for r in refs if r in sym_to_leg}
             if len(leg_ids) <= 1:
                 li = leg_ids.pop() if leg_ids else 0
@@ -1854,7 +1895,22 @@ class LogicalPlanner:
         for c in late_unnest:
             ctx = ExprCtx(qs.scope, self, outer if decorrelate else None)
             post.append(ExprPlanner(ctx).plan(c))
+        above = [p for p in post if ir.referenced_columns([p]) & nullable]
         for p in post:
+            if not any(p is a for a in above):
+                qs.node = N.Filter(qs.node, p)
+        for rel, right in outer_joins:
+            # the preserved side's scope: the nullable sides still to
+            # come would make its ON clause's names ambiguous
+            pending = {f.symbol for f in right.scope.fields}
+            preserved = Scope([f for f in qs.scope.fields
+                               if f.symbol not in nullable])
+            joined = self._outer_join(
+                rel, RelationPlan(qs.node, preserved, qs.est, qs.unique),
+                right, outer)
+            nullable -= pending
+            qs.node, qs.est, qs.unique = joined.node, joined.est, joined.unique
+        for p in above:
             qs.node = N.Filter(qs.node, p)
         for c in deferred:
             ctx = ExprCtx(qs.scope, self, outer if decorrelate else None)
@@ -1967,17 +2023,14 @@ class LogicalPlanner:
             build_syms = frozenset(b for _, b in criteria)
             build_unique = any(k <= build_syms for k in build.unique)
             est_out = out_est(j)
-            # the capacity HINT stays conservative: an undersized first
-            # guess is fixed by one RETRY_GROWTH recompile, an oversized
-            # one allocates est_out-rows of HBM up front (q72's default
-            # ndv once produced a 2^29-row hint)
-            out_cap = min(2 * max(est_out, est), 8 * max(est, build.est))
+            # the capacity HINT stays conservative (q72's default ndv
+            # once produced a 2^29-row hint): cost/model.expand_capacity
             node = N.Join(node, build.node, N.JoinType.INNER, criteria,
                           None, build_unique,
                           build_rows=build.est,
                           capacity=_next_pow2(2 * build.est),
                           output_capacity=None if build_unique else
-                          _next_pow2(max(out_cap, 2)))
+                          expand_capacity(est_out, est, build.est))
             if build_unique:
                 # FK->PK join: a filtered PK side keeps only its
                 # selectivity fraction of probe rows (containment,

@@ -5186,12 +5186,20 @@ def _sqlite_override(qname: str) -> str | None:
     the ratio in full REAL precision, so its rank() sees distinctions
     the scale-2 quotient legitimately collapses. Rounding the oracle's
     ratio to the derived scale makes both sides rank the same values
-    (the query's ORDER BY carries deterministic tie-breakers)."""
-    if qname != "q36":
+    (the query's ORDER BY carries deterministic tie-breakers).
+
+    q72: sqlite stores a DATE as ISO text, so ``d1.d_date + 5`` is an
+    integer and ``d3.d_date > (d1.d_date + 5)`` compares text with a
+    number; the oracle adds the five days with sqlite's ``date()``."""
+    if qname not in ("q36", "q72"):
         return None
     from presto_tpu.sql.parser import parse_statement
     from presto_tpu.sql.sqlite_dialect import to_sqlite
     sq = to_sqlite(parse_statement(QUERIES[qname]))
+    if qname == "q72":
+        plus = "(d3.d_date > (d1.d_date + 5))"
+        assert plus in sq
+        return sq.replace(plus, "(d3.d_date > date(d1.d_date, '+5 day'))")
     div = "(sum(ss_net_profit) / sum(ss_ext_sales_price))"
     assert div in sq
     return sq.replace(div, f"ROUND({div}, 2)")
