@@ -50,6 +50,12 @@ _EXPANDED_ROWS = REGISTRY.counter(
     "presto_tpu_join_expanded_rows_total",
     "rows the expanding (many-to-many) joins of executed programs "
     "emitted, from the per-node counts a program's results fetch")
+_EXPAND_PACK = REGISTRY.counter(
+    "presto_tpu_expand_pack_total",
+    "expanding joins of executed programs by how their payload moved "
+    "(packed: in 32-bit words | unpacked: column by column | "
+    "range_retry: packed, a value fell outside its stated range and "
+    "the program runs again unpacked)")
 _DYN_FILTERS = REGISTRY.counter(
     "presto_tpu_dynamic_filters_total",
     "INNER join legs of executed programs by what the trace did with "
@@ -324,6 +330,21 @@ def note_dyn_filters(meta: dict, span) -> None:
             for outcome, n in counts.items())
 
 
+def note_expand_pack(meta: dict, oks) -> None:
+    """Count each expanding join an executed program ran on
+    ``presto_tpu_expand_pack_total`` by its outcome; ``oks`` are the
+    program's ok flags, fetched (a failed "pack" flag is a
+    range_retry)."""
+    packs = meta.get("expand_pack")
+    if not packs:
+        return
+    failed = {pos for (pos, kind), ok in zip(meta["ok_keys"], oks)
+              if kind == "pack" and not ok}
+    for pos, (packed, *_words) in packs.items():
+        _EXPAND_PACK.inc(outcome="range_retry" if pos in failed
+                         else "packed" if packed else "unpacked")
+
+
 def compile_traced(fn, args: list, **attrs):
     """Explicit AOT lower+compile of ``fn`` for ``args`` (not a first
     jit-wrapper call), so compile and execute attribute separately."""
@@ -381,6 +402,9 @@ class PlanInterpreter:
         # (preorder position, probe rows, output capacity) of every
         # expanding join the program runs (meta["expand_nodes"])
         self.expand_nodes: list[tuple[int, int, int]] = []
+        # preorder position -> (packed, probe words, build words,
+        # unpacked words) of each (meta["expand_pack"])
+        self.expand_pack: dict[int, tuple] = {}
 
     def run(self, node: N.PlanNode) -> DTable:
         kind = type(node).__name__
@@ -561,11 +585,24 @@ class PlanInterpreter:
             return out
         out_cap = self._capacity(node, next_pow2(2 * (left.n + right.n)),
                                  "out")
-        self.expand_nodes.append(
-            (self.node_order.get(id(node), id(node)), left.n, out_cap))
-        out, t_ok, o_ok = OP.apply_expand_join(left, right, node, cap,
-                                               out_cap)
-        self._note_ok(node, t_ok)
+        pos = self.node_order.get(id(node), id(node))
+        self.expand_nodes.append((pos, left.n, out_cap))
+        # packed where a column can pack, unless the range check of an
+        # earlier run failed: that rung of the ok ladder forces the
+        # "pack" key (grow_overflowed)
+        pack_key = self._node_key(node, "pack")
+        packed = (node.expand_layout is not None
+                  and pack_key not in self.capacities
+                  and OP.packs_any(left, right, node.expand_layout))
+        out, r_ok, o_ok = OP.apply_expand_join(
+            left, right, node, cap, out_cap,
+            node.expand_layout if packed else None)
+        self.expand_pack[pos] = (packed, *OP.expand_words(
+            left, right, node.expand_layout, packed))
+        if packed:
+            # a ladder that grows every failed key (exec/spill.py) reads it
+            self.used_capacity[pack_key] = 1
+            self._note_ok(node, r_ok, "pack")
         self._note_ok(node, o_ok, "out")
         return out
 
@@ -726,6 +763,7 @@ def make_traced(scan_inputs: list[ScanInput], plan: N.PlanNode,
         meta["used_capacity"] = interp.used_capacity
         meta["dynfilters"] = dict(interp.df_counts)
         meta["expand_nodes"] = list(interp.expand_nodes)
+        meta["expand_pack"] = dict(interp.expand_pack)
         res = []
         for sym, v in out.cols.items():
             res.append(v.data)
@@ -943,6 +981,7 @@ def prepare_plan(engine, plan: N.PlanNode, scan_inputs: list[ScanInput]):
             # device time, not just call overhead
             oks_np = HS.fetch(oks, site="ok-ladder")
         execute_s = time.perf_counter() - _t1
+        note_expand_pack(meta, oks_np)
         if oks_np.all():
             if not cache_hit:
                 cache.insert((base_key, caps_key), compiled, meta, fpr)
@@ -1242,12 +1281,19 @@ def note_expansions(meta, rows: dict, stats: dict | None) -> None:
     nodes = meta.get("expand_nodes") or ()
     if not nodes:
         return
-    out = [(int(rows.get(pos, 0)), probe, cap) for pos, probe, cap in nodes]
-    _EXPANDED_ROWS.inc(sum(r for r, _p, _c in out))
+    out = [(int(rows.get(pos, 0)), probe, cap, pos)
+           for pos, probe, cap in nodes]
+    _EXPANDED_ROWS.inc(sum(r for r, _p, _c, _pos in out))
     if stats is not None:
-        expanded, probe, cap = max(out)
+        expanded, probe, cap, pos = max(out)
         stats.update(expanded=expanded, expand_probe_rows=probe,
                      expand_capacity=cap)
+        pack = (meta.get("expand_pack") or {}).get(pos)
+        if pack is not None:
+            packed, probe_words, build_words, column_words = pack
+            stats.update(expand_pack="packed" if packed else "unpacked",
+                         expand_words=probe_words + build_words,
+                         expand_column_words=column_words)
 
 
 def run_plan_device(engine, plan: N.PlanNode,

@@ -26,6 +26,7 @@ from presto_tpu.expr import aggregates as A
 from presto_tpu.expr import ir
 from presto_tpu.expr.compile import ExprCompiler, Val, and_valid, cast_val
 from presto_tpu.ops import hash as H
+from presto_tpu.ops import pack as PK
 from presto_tpu.ops import segred
 from presto_tpu.plan import nodes as N
 
@@ -530,6 +531,20 @@ def _verify_keys(left: DTable, right: DTable,
     return eq if eq is not None else True
 
 
+def _verify_gathered(pcols: dict, bcols: dict,
+                     criteria: list[tuple[str, str]]):
+    """_verify_keys over columns already gathered at the same slots
+    (an expansion's, packed or not)."""
+    eq = None
+    for lk, rk in criteria:
+        lv, rv = pcols[lk], bcols[rk]
+        if lv.is_string or rv.is_string:
+            continue
+        e = lv.data == rv.data
+        eq = e if eq is None else (eq & e)
+    return eq if eq is not None else True
+
+
 def _and_key_valid(dt: DTable, keys: list[str], live):
     for k in keys:
         v = dt.cols[k]
@@ -739,21 +754,87 @@ def concat_dtables(parts: list[DTable]) -> DTable:
 
 
 def apply_expand_join(left: DTable, right: DTable, node: N.Join,
-                      capacity: int, out_capacity: int) -> tuple:
+                      capacity: int, out_capacity: int,
+                      layout: tuple | None = None) -> tuple:
     """Expanding (many-to-many) hash join: every (probe, build) match
     becomes one output row (reference LookupJoinOperator + PositionLinks
     chains, operator/join/JoinProbe.java). Output has static capacity
     ``out_capacity``; overflow reported for host retry.
 
-    Returns (DTable [out_capacity], table_ok, out_ok). Runs under the
-    scope ``expand`` inside the join's ``Join#<n>``, so the device time
-    of the expansion reads apart from the rest of the join's."""
+    ``layout`` (plan/dense.expand_layout; None moves every column as it
+    is) packs each side's columns into 32-bit words before the
+    expansion (ops/pack.py): the probe's at the probe's width, the
+    build's at the build's, put in sorted-build order; the output's
+    gathers then move a word, not a column, per pass.
+
+    Returns (DTable [out_capacity], range_ok, out_ok): ``range_ok`` is
+    False where a packed column held a live value outside its stated
+    range (the host then runs the join unpacked). Runs under the scope
+    ``expand`` inside the join's ``Join#<n>``, so the device time of
+    the expansion reads apart from the rest of the join's."""
     with jax.named_scope("expand"):
-        return _expand_join(left, right, node, out_capacity)
+        return _expand_join(left, right, node, out_capacity, layout)
+
+
+def _gathered(v: Val, rows) -> Val:
+    return Val(v.dtype, v.data[rows],
+               None if v.valid is None else v.valid[rows], v.dictionary)
+
+
+def _moved(dt: DTable, side_layout, live, order, at, rows):
+    """``dt``'s columns at output slots: the packable ones of
+    ``side_layout`` packed at ``dt``'s width over its ``live`` rows,
+    put in ``order`` (None: as they are), gathered ``at`` the slots
+    and unpacked; the rest gathered at ``rows``. Returns (symbol ->
+    Val, range ok)."""
+    fields, nwords = PK.assign_words(PK.fields_of(dt.cols, side_layout))
+    words, ok = PK.pack(dt.cols, fields, nwords, live)
+    if order is not None:
+        words = [w[order] for w in words]
+    words = [w[at] for w in words]
+    n = at.shape[0]
+    out = {}
+    for f in fields:
+        v = dt.cols[f.sym]
+        data, valid = PK.unpack(words, f, v.data.dtype, n)
+        out[f.sym] = Val(v.dtype, data, valid, v.dictionary)
+    for sym, v in dt.cols.items():
+        if sym not in out:
+            out[sym] = _gathered(v, rows)
+    return out, ok
+
+
+def packs_any(left: DTable, right: DTable, layout: tuple) -> bool:
+    """Whether ``layout`` packs any column of either side."""
+    return any(PK.fields_of(dt.cols, side)
+               for dt, side in zip((left, right), layout))
+
+
+def expand_words(left: DTable, right: DTable, layout: tuple | None,
+                 packed: bool) -> tuple[int, int, int]:
+    """(probe words, build words, unpacked words): the 32-bit words an
+    output row of the expansion gathers, by side, over the columns
+    ``layout`` hands on (every column where there is none), and what
+    the same columns cost moved unpacked."""
+    counts = []
+    unpacked = 0
+    for i, dt in enumerate((left, right)):
+        syms = ([s for s, _lo, _hi in layout[i] if s in dt.cols]
+                if layout is not None else list(dt.cols))
+        cols = sum(PK.column_words(dt.cols[s]) for s in syms)
+        unpacked += cols
+        if packed:
+            fields, nwords = PK.assign_words(
+                PK.fields_of(dt.cols, layout[i]))
+            cols = nwords + sum(
+                PK.column_words(dt.cols[s]) for s in syms
+                if s not in {f.sym for f in fields})
+        counts.append(cols)
+    return counts[0], counts[1], unpacked
 
 
 def _expand_join(left: DTable, right: DTable, node: N.Join,
-                 out_capacity: int) -> tuple:
+                 out_capacity: int, layout: tuple | None) -> tuple:
     lkeys = [lk for lk, _ in node.criteria]
     rkeys = [rk for _, rk in node.criteria]
     build_live = _and_key_valid(right, rkeys, right.live_mask())
@@ -771,31 +852,36 @@ def _expand_join(left: DTable, right: DTable, node: N.Join,
     _bsh, bsidx = H.sort_build_side(rh, build_live)
     ph = _row_hash(left, lkeys)
     lo, count, found = H.probe_runs(rh, build_live, ph, probe_live)
-    t_ok = jnp.asarray(True)  # sorted build: no table, no overflow
-    probe_idx, build_row, out_live, o_ok = H.expand_matches(
-        lo, count, bsidx, found & probe_live,
+    probe_idx, build_pos, matched, out_live, o_ok = H.expand_matches(
+        lo, count, right.n, found & probe_live,
         probe_rows_live, out_capacity, left_join)
-
-    out: dict[str, Val] = {}
-    for sym, v in left.cols.items():
-        data = v.data[probe_idx]
-        valid = None if v.valid is None else v.valid[probe_idx]
-        out[sym] = Val(v.dtype, data, valid, v.dictionary)
-    matched = build_row >= 0
+    # a slot's build row (-1 = unmatched left row): XLA drops this
+    # gather where nothing reads it (every build column packed, no FULL)
+    build_row = jnp.where(matched, bsidx[build_pos], -1)
     gather = jnp.clip(build_row, 0, right.n - 1)
-    verify = _verify_keys(left, right, node.criteria, probe_idx, gather)
+    if layout is None:
+        pcols = {s: _gathered(v, probe_idx) for s, v in left.cols.items()}
+        bcols = {s: _gathered(v, gather) for s, v in right.cols.items()}
+        r_ok = jnp.asarray(True)
+    else:
+        pcols, p_ok = _moved(left, layout[0], probe_rows_live, None,
+                             probe_idx, probe_idx)
+        bcols, b_ok = _moved(right, layout[1], build_live, bsidx,
+                             build_pos, gather)
+        r_ok = p_ok & b_ok
+
+    out: dict[str, Val] = dict(pcols)
+    verify = _verify_gathered(pcols, bcols, node.criteria)
     if verify is not True and not left_join:
         out_live = out_live & (verify | ~matched)
-    for sym, v in right.cols.items():
-        data = v.data[gather]
+    for sym, v in bcols.items():
         if left_join:
-            valid = matched if v.valid is None \
-                else (matched & v.valid[gather])
+            valid = matched if v.valid is None else (matched & v.valid)
         else:
             # inner expansion emits matched rows only: matched is
             # redundant with out_live (see apply_join)
-            valid = None if v.valid is None else v.valid[gather]
-        out[sym] = Val(v.dtype, data, valid, v.dictionary)
+            valid = v.valid
+        out[sym] = Val(v.dtype, v.data, valid, v.dictionary)
 
     keep = matched
     f_ok = None
@@ -829,11 +915,9 @@ def _expand_join(left: DTable, right: DTable, node: N.Join,
                   & probe_rows_live[probe_idx] & out_live)
         out_live = keep | revert
         # right columns of reverted slots are NULL
-        for sym, v in right.cols.items():
-            data = out[sym].data
-            valid = keep if v.valid is None \
-                else (keep & v.valid[gather])
-            out[sym] = Val(v.dtype, data, valid, v.dictionary)
+        for sym, v in bcols.items():
+            valid = keep if v.valid is None else (keep & v.valid)
+            out[sym] = Val(v.dtype, v.data, valid, v.dictionary)
 
     if full_join:
         # FULL = LEFT + the build rows no probe row matched, appended as
@@ -866,9 +950,9 @@ def _expand_join(left: DTable, right: DTable, node: N.Join,
             out2[sym] = Val(v.dtype, jnp.concatenate([v.data, tdata]),
                             valid, v.dictionary)
         live2 = jnp.concatenate([out_live, tail_live])
-        return DTable(out2, live2, out_capacity + nb), t_ok, o_ok
+        return DTable(out2, live2, out_capacity + nb), r_ok, o_ok
 
-    return DTable(out, out_live, out_capacity), t_ok, o_ok
+    return DTable(out, out_live, out_capacity), r_ok, o_ok
 
 
 def apply_semijoin(dt: DTable, filt: DTable, node: N.SemiJoin,

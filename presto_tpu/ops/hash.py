@@ -123,12 +123,19 @@ def grow_overflowed(capacities: dict, ok_keys, oks,
     inferred from logs. The kind label names the operator role the
     capacity sizes (table/final = hash build or aggregation table,
     out/pout = expanding-join output, probe_exch/build_exch/agg_exch =
-    exchange buckets, hot/htab = hybrid-join hot set, ...).
+    exchange buckets, hot/htab = hybrid-join hot set, ...). A failed
+    ``pack`` key is no capacity but a packed expansion's range check:
+    it is set to 0, which runs that expansion unpacked, and counts
+    under ``presto_tpu_expand_pack_total{outcome=range_retry}``
+    (exec/executor.note_expand_pack) and not here.
     Returns the counted hash-table overflow total."""
     import numpy as np
     overflowed = 0
     for key, okv in zip(ok_keys, oks):
         if not bool(np.asarray(okv)):
+            if key[1] == "pack":
+                capacities[key] = 0
+                continue
             if key[1] in ("table", "final"):
                 overflowed += 1
             note_capacity_retry(str(key[1]))
@@ -546,22 +553,25 @@ def probe_join_slot(table_hash, row_hash, live, max_probes: int = 256):
     return jnp.where(found, pos, -1), found, jnp.asarray(True)
 
 
-def expand_matches(lo, counts, build_sidx, probe_found,
+def expand_matches(lo, counts, build_rows: int, probe_found,
                    probe_live, out_capacity: int, left_join: bool):
     """Expand probe rows into one output row per (probe, build) match.
 
-    ``lo``/``counts`` are per-PROBE-row run bounds from probe_runs;
-    ``build_sidx`` maps sorted build positions to source rows. Each
-    probe row that emits marks its first output slot with its index and
-    a running max carries it over the rest of its slots: one scatter of
+    ``lo``/``counts`` are per-PROBE-row run bounds from probe_runs, in
+    SORTED build positions of a build ``build_rows`` wide. Each probe
+    row that emits marks its first output slot with its index and a
+    running max carries it over the rest of its slots: one scatter of
     the probe's width and one scan of the output's, where a binary
     search for every output slot costs log2(probe rows) random gathers
     of the output's width (23 of 2^27 for TPC-DS q95's self-join at
-    SF10). Then a slot's build position is its own offset plus its probe
-    row's ``lo - first slot``: one gather. No data-dependent shapes.
+    SF10). Then a slot's sorted build position is its own offset plus
+    its probe row's ``lo - first slot``: one gather. No data-dependent
+    shapes.
 
-    Returns (probe_idx int32 [out_capacity], build_row int32 [out_capacity]
-    (-1 = unmatched left row), out_live bool [out_capacity], ok).
+    Returns (probe_idx int32 [out_capacity], build_pos [out_capacity]
+    (a sorted build position: sort_build_side's order maps it to a
+    build row), matched bool [out_capacity] (False on a LEFT row's one
+    slot of no match), out_live bool [out_capacity], ok).
     """
     matches = jnp.where(probe_found & probe_live, counts, 0)
     if left_join:
@@ -582,10 +592,8 @@ def expand_matches(lo, counts, build_sidx, probe_found,
     # an emitting slot is a match, but a LEFT row's one slot of no match
     matched = out_live & (matches[probe_idx] > 0) if left_join \
         else out_live
-    build_pos = jnp.clip(k + (lo - prefix)[probe_idx], 0,
-                         build_sidx.shape[0] - 1)
-    build_row = jnp.where(matched, build_sidx[build_pos], -1)
-    return probe_idx, build_row, out_live, ok
+    build_pos = jnp.clip(k + (lo - prefix)[probe_idx], 0, build_rows - 1)
+    return probe_idx, build_pos, matched, out_live, ok
 
 
 def next_pow2(x: int) -> int:

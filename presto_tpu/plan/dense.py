@@ -274,9 +274,65 @@ def dense_hint(build: N.PlanNode, criteria: list[tuple[str, str]],
     return None
 
 
+def expand_layout(plan: N.PlanNode, join: N.Join, engine) -> tuple:
+    """The payload layout of an expanding ``join`` of ``plan``: per
+    side (probe, build), the (symbol, lo, hi) of each column the
+    expansion hands on (read above the join, a join key, or read by
+    its residual), in the side's column order; lo and hi are the
+    range ``symbol_ranges`` gives an integer column, None for any
+    other. exec/operators.apply_expand_join packs the ranged integers
+    and the dictionary codes of strings into 32-bit words, so its
+    output-width gathers move words, not columns; a column nothing
+    reads is left out (XLA drops its gather)."""
+    from presto_tpu.exec.executor import _needed_above
+    needed = _needed_above(plan, join)
+    if needed is None:
+        needed = set(join.output_symbols)
+    needed |= {s for pair in join.criteria for s in pair}
+    if join.filter is not None:
+        from presto_tpu.expr import ir
+        needed |= ir.referenced_columns([join.filter])
+    sides = []
+    for side in (join.left, join.right):
+        ranges = symbol_ranges(side, engine)
+        types = side.output_types()
+        sides.append(tuple(
+            (s, *(ranges[s] if s in ranges and _int_typed(types, s)
+                  else (None, None)))
+            for s in side.output_symbols if s in needed))
+    return tuple(sides)
+
+
+def _expanding(node: N.PlanNode) -> bool:
+    """A Join the executor runs as an expansion (FULL always is)."""
+    return isinstance(node, N.Join) and bool(node.criteria) and (
+        not node.build_unique or node.join_type == N.JoinType.FULL)
+
+
+def _annotate_expansions(plan: N.PlanNode, engine) -> N.PlanNode:
+    """Give every expanding Join of the finished ``plan`` its
+    :func:`expand_layout` (recomputed on each pass: the sides' columns
+    may have changed since)."""
+    layouts = {tuple(n.criteria): expand_layout(plan, n, engine)
+               for n in N.preorder(plan) if _expanding(n)}
+    if not layouts:
+        return plan
+
+    def visit(node: N.PlanNode) -> N.PlanNode:
+        if _expanding(node):
+            # symbols are unique in a plan: the criteria name the join
+            lay = layouts.get(tuple(node.criteria))
+            if lay != node.expand_layout:
+                node = dataclasses.replace(node, expand_layout=lay)
+        return node
+
+    return N.rewrite_bottom_up(plan, visit)
+
+
 def annotate_dense(plan: N.PlanNode, engine) -> N.PlanNode:
     """Attach dense-key hints to Join, MultiJoin (one a leg) and
-    SemiJoin nodes (bottom-up)."""
+    SemiJoin nodes (bottom-up), and payload layouts to expanding
+    Joins."""
 
     def visit(node: N.PlanNode) -> N.PlanNode:
         if isinstance(node, N.Join) and node.criteria \
@@ -293,7 +349,8 @@ def annotate_dense(plan: N.PlanNode, engine) -> N.PlanNode:
             if any(u <= bsyms
                    for u in unique_key_sets(node.right, engine)):
                 node = dataclasses.replace(node, build_unique=True,
-                                           output_capacity=None)
+                                           output_capacity=None,
+                                           expand_layout=None)
         if isinstance(node, N.Join) and node.criteria \
                 and node.join_type != N.JoinType.FULL \
                 and node.build_unique and node.dense_key is None:
@@ -333,4 +390,4 @@ def annotate_dense(plan: N.PlanNode, engine) -> N.PlanNode:
                 node = dataclasses.replace(node, dense_key=(lo, hi))
         return node
 
-    return N.rewrite_bottom_up(plan, visit)
+    return _annotate_expansions(N.rewrite_bottom_up(plan, visit), engine)

@@ -41,8 +41,11 @@ def _tok(x, emit) -> None:
         emit(b"(")
         emit(type(x).__name__.encode())
         for f in dataclasses.fields(x):
+            v = getattr(x, f.name)
+            if v is None and f.metadata.get("fingerprint_omit_none"):
+                continue  # hashed as if the field did not exist
             emit(f.name.encode())
-            _tok(getattr(x, f.name), emit)
+            _tok(v, emit)
         emit(b")")
     elif isinstance(x, (list, tuple)):
         emit(b"[")

@@ -194,6 +194,13 @@ class Join(PlanNode):
     # plan/dense.py: build rows scatter into a (hi-lo+1)-slot
     # direct-address table; probes become one gather (no sort, no hash)
     dense_key: tuple[int, int, int] | None = None
+    # payload layout of an expanding join (plan/dense.expand_layout):
+    # per side, the (symbol, lo, hi) of each column read above the
+    # join, lo and hi None where no range is known. Unset, it stays out
+    # of the plan fingerprint (plan/fingerprint.py), so plans without
+    # an expansion keep the programs and names they had
+    expand_layout: tuple | None = dataclasses.field(
+        default=None, metadata={"fingerprint_omit_none": True})
 
     def sources(self):
         return [self.left, self.right]

@@ -18,8 +18,10 @@ from presto_tpu import Engine
 from presto_tpu import types as T
 from presto_tpu.cost import model as CM
 from presto_tpu.exec import operators as OP
+from presto_tpu.expr import ir
 from presto_tpu.expr.compile import Val
 from presto_tpu.ops import hash as H
+from presto_tpu.ops import pack as PK
 from presto_tpu.plan import nodes as N
 from presto_tpu.testing.oracle import assert_query
 
@@ -466,6 +468,153 @@ def test_expand_join_duplicate_build_keys_emit_every_pair():
                               np.zeros(4, np.int64), np.ones(4, bool), 31)
     assert len(got) == 31 and set(got) <= {
         (p, b) for p in range(4) for b in range(8)}
+
+
+def _val(dtype, data, valid=None, dictionary=None) -> Val:
+    return Val(dtype, jnp.asarray(data),
+               None if valid is None else jnp.asarray(valid), dictionary)
+
+
+def _packed_case(case: str, rng):
+    """(left, right, node, layout, symbols that pack) of one case of
+    test_expand_join_packed_equals_unpacked: probe and build keyed on
+    a few dozen values, so most keys have several build rows."""
+    np_, nb = 300, 200
+    pk, bk = rng.integers(0, 40, np_), rng.integers(0, 40, nb)
+    pa, ba = rng.integers(100, 5000, np_), rng.integers(0, 21, nb)
+    left = {"pk": _val(T.BIGINT, pk), "pa": _val(T.BIGINT, pa)}
+    right = {"bk": _val(T.BIGINT, bk), "ba": _val(T.BIGINT, ba)}
+    lay_l = [("pk", 0, 39), ("pa", 100, 4999)]
+    lay_r = [("bk", 0, 39), ("ba", 0, 20)]
+    packs = {"pk", "pa", "bk", "ba"}
+    kind, filt = N.JoinType.INNER, None
+    if case in ("left", "full"):
+        kind = N.JoinType.LEFT if case == "left" else N.JoinType.FULL
+        # a LEFT probe row with a NULL key keeps its one slot
+        left["pk"] = _val(T.BIGINT, pk, rng.random(np_) > 0.1)
+    elif case == "nullable-negative-lo":
+        kind = N.JoinType.LEFT
+        left["pa"] = _val(T.BIGINT, pa - 3000, rng.random(np_) > 0.3)
+        right["ba"] = _val(T.BIGINT, -ba - 1, rng.random(nb) > 0.3)
+        lay_l[1], lay_r[1] = ("pa", -2900, 1999), ("ba", -21, -1)
+    elif case in ("32-bit-range", "33-bit-range"):
+        top = (1 << 31) - 1 if case == "32-bit-range" else (1 << 32) - 1
+        wide = rng.integers(-(1 << 31), top, np_, dtype=np.int64)
+        wide[:2] = -(1 << 31), top
+        left["pa"] = _val(T.BIGINT, wide)
+        lay_l[1] = ("pa", -(1 << 31), top)
+        if case == "33-bit-range":
+            packs.discard("pa")
+    elif case == "dictionary-string":
+        words = np.array(["ash", "birch", "cedar", "elm", "fir"], object)
+        right["bs"] = _val(T.VARCHAR, rng.integers(0, 5, nb).astype(
+            np.int32), rng.random(nb) > 0.2, words)
+        lay_r.append(("bs", None, None))
+        packs.add("bs")
+    elif case == "float":
+        right["bf"] = _val(T.DOUBLE, rng.random(nb) * 1e6)
+        lay_r.append(("bf", None, None))
+    elif case == "long-decimal":
+        limbs = np.stack([rng.integers(0, 1 << 40, nb),
+                          rng.integers(-3, 3, nb)], axis=1)
+        right["bd"] = _val(T.DecimalType(30, 2), limbs)
+        lay_r.append(("bd", None, None))
+    elif case == "residual-both-sides":
+        ref = lambda s: ir.ColumnRef(T.BIGINT, s)  # noqa: E731
+        filt = ir.Call(T.BOOLEAN, "lt", (
+            ir.Call(T.BIGINT, "add", (ref("pa"), ref("ba"))),
+            ir.Literal(T.BIGINT, 2600)))
+    elif case == "range-broken":
+        lay_r[1] = ("ba", 0, 9)  # the data reaches 20
+    node = N.Join(criteria=[("pk", "bk")], join_type=kind, filter=filt,
+                  build_unique=False)
+    plive, blive = rng.random(np_) > 0.1, rng.random(nb) > 0.1
+    return (OP.DTable(left, jnp.asarray(plive), np_),
+            OP.DTable(right, jnp.asarray(blive), nb), node,
+            (tuple(lay_l), tuple(lay_r)), packs)
+
+
+def _expand_rows(out) -> tuple:
+    live = np.asarray(out.live_mask())
+    cols = {}
+    for sym, v in out.cols.items():
+        valid = live if v.valid is None else live & np.asarray(v.valid)
+        data = np.asarray(v.data)
+        cols[sym] = (valid, np.where(
+            valid.reshape(valid.shape + (1,) * (data.ndim - 1)), data, 0))
+    return live, cols
+
+
+@pytest.mark.parametrize("case", [
+    "inner", "left", "full", "nullable-negative-lo", "32-bit-range",
+    "33-bit-range", "dictionary-string", "float", "long-decimal",
+    "residual-both-sides", "range-broken"])
+def test_expand_join_packed_equals_unpacked(case):
+    """The expansion with its columns packed into 32-bit words
+    (ops/pack.py) emits the unpacked expansion's rows, slot for slot,
+    value for value and validity for validity; what cannot pack
+    (a float, a LONG decimal, a range wider than a word) moves as a
+    column. A stated range the data breaks clears the flag, and
+    through an engine the ok ladder's next rung runs it unpacked."""
+    left, right, node, layout, packs = _packed_case(
+        case, np.random.default_rng(11))
+    packed = {f.sym for i, dt in enumerate((left, right))
+              for f in PK.fields_of(dt.cols, layout[i])}
+    assert packed == packs
+    plain, ok, o_ok = OP.apply_expand_join(left, right, node, 16, 4096)
+    assert bool(ok) and bool(o_ok)
+    out, r_ok, o_ok = OP.apply_expand_join(left, right, node, 16, 4096,
+                                           layout)
+    assert bool(o_ok)
+    if case == "range-broken":
+        assert not bool(r_ok)
+        _range_retry_through_the_ok_ladder()
+        return
+    assert bool(r_ok)
+    want_live, want = _expand_rows(plain)
+    got_live, got = _expand_rows(out)
+    np.testing.assert_array_equal(got_live, want_live)
+    assert want_live.sum() > (0 if case == "residual-both-sides" else 300)
+    assert set(got) == set(want)
+    for sym, (valid, data) in want.items():
+        assert out.cols[sym].data.dtype == plain.cols[sym].data.dtype, sym
+        np.testing.assert_array_equal(got[sym][0], valid, err_msg=sym)
+        np.testing.assert_array_equal(got[sym][1], data, err_msg=sym)
+    words = OP.expand_words(left, right, layout, True)
+    assert words[0] + words[1] < words[2]
+
+
+def _range_retry_through_the_ok_ladder():
+    from presto_tpu.connectors.memory import MemoryConnector
+    from presto_tpu.obs.metrics import REGISTRY
+
+    class Narrow(MemoryConnector):
+        def column_range_estimates(self, name):
+            return {"k": (0, 39), "w": (1, 3)}  # w reaches 9
+
+    rng = np.random.default_rng(12)
+    k, w = rng.integers(0, 40, 400), rng.integers(1, 10, 400)
+    conn = Narrow()
+    conn.create_table("t", {"k": T.BIGINT, "w": T.BIGINT},
+                      {"k": k, "w": w})
+    e = Engine()
+    e.register_catalog("memory", conn)
+    sql = ("select a.k, a.w, b.w from memory.default.t a "
+           "join memory.default.t b on a.k = b.k where a.w <> b.w "
+           "order by 1, 2, 3")
+    plan, _ = e.plan_sql(sql)
+    (join,) = [n for n in N.preorder(plan) if isinstance(n, N.Join)]
+    assert not join.build_unique and join.expand_layout is not None
+    packs = REGISTRY.counter("presto_tpu_expand_pack_total")
+    before = {o: packs.value(outcome=o)
+              for o in ("packed", "unpacked", "range_retry")}
+    got = [tuple(r) for r in e.execute(sql)]
+    want = sorted((int(k[i]), int(w[i]), int(w[j]))
+                  for i in range(400) for j in range(400)
+                  if k[i] == k[j] and w[i] != w[j])
+    assert got == want and want
+    assert {o: packs.value(outcome=o) - n for o, n in before.items()} \
+        == {"packed": 0, "unpacked": 1, "range_retry": 1}
 
 
 @pytest.mark.parametrize("dense", [False, True],

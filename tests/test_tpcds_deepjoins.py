@@ -304,6 +304,10 @@ def test_served_answers_equal_the_reference(served, conn, cls_name):
             # one self-join a segment, once for each IN
             assert [a["expanded"] for a in expansions] == \
                 [pairs] * len(expansions)
+            # each side's order number and warehouse ride one word
+            assert all(a["expand_words"] == 2
+                       and a["expand_pack"] == "packed"
+                       for a in expansions)
             assert len(expansions) == (2 if shape == "segmented" else 0)
 
 
